@@ -86,4 +86,6 @@ def run_plaid_probe(rng):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -53,4 +53,6 @@ def run(verbose: bool = True, out: str = BENCH_QUALITY_FILE):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

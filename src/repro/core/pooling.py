@@ -11,9 +11,9 @@ No training, no query-time change: this runs between the encoder and the
 index. ``pool_factor=1`` or method ``none`` is the identity (the unpooled
 baseline every paper table is normalized against).
 
-The ward path dispatches through ``kernels/ward_pool`` (Pallas merge-loop
-kernel, bitwise-equal to ``core/ward.py``; ``ward_kernel="ref"`` pins the
-original loop), and ``compact_pooled`` compacts ON DEVICE first — a
+The ward path dispatches through ``kernels/ward_pool`` (the Pallas
+merge-loop kernel on TPU, ``core/ward.py`` elsewhere — bitwise-equal;
+``ward_kernel`` pins either), and ``compact_pooled`` compacts ON DEVICE first — a
 validity-sort moves the pooled rows doc-major to the front so the
 device->host transfer is ``sum(counts)`` rows + a counts vector,
 ~1/factor of the padded ``[B, N, d]`` tensor

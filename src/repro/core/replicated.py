@@ -114,7 +114,6 @@ class _FlatPlan:
     def _fn(self, kk: int):
         if kk in self._fns:
             return self._fns[kk]
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         sd, sm, sl, sb = self._specs
 
@@ -127,10 +126,10 @@ class _FlatPlan:
             return (jax.lax.all_gather(ts, "shard", axis=1, tiled=True),
                     jax.lax.all_gather(gi, "shard", axis=1, tiled=True))
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(sd, sm, sl, sb, P(), P()),
-            out_specs=(P(), P()), check_rep=False))
+            out_specs=(P(), P()), check_vma=False))
         self._fns[kk] = fn
         return fn
 
@@ -211,14 +210,23 @@ class ReplicatedIndex:
         from repro.core.persist import load_artifact
         assert n_replicas >= 1, n_replicas
         reps = []
-        for _ in range(int(n_replicas)):
-            ix = load_artifact(path, mmap=mmap)
+        table = kw.pop("device_table", None)
+        for r in range(int(n_replicas)):
+            # load under the lane's device: arrays made at load time (the
+            # codec tables) must live where that lane computes
+            ctx = (jax.default_device(table[r][0]) if table is not None
+                   else contextlib.nullcontext())
+            with ctx:
+                ix = load_artifact(path, mmap=mmap)
+            if table is None:
+                table = serve_device_table(int(n_replicas),
+                                           max(len(_parts(ix)), 1))
             if (isinstance(ix, ShardedIndex) and n_replicas > 1
                     and ix.probe_threads_cfg == 0):
                 ix.set_probe_threads(
                     max(1, ix.probe_threads // int(n_replicas)))
             reps.append(ix)
-        return cls(reps, own_inner=True, **kw)
+        return cls(reps, own_inner=True, device_table=table, **kw)
 
     def _place_all(self) -> None:
         if not self._multi_device:

@@ -215,9 +215,9 @@ class PoolingSpec(_SpecBase):
     factor: int = field(default=1, metadata={
         "help": "pooling factor (1 = unpooled baseline)"})
     # Ward implementation toggle (kernels/ward_pool): "auto" resolves to
-    # the Pallas kernel — it is bitwise-equal to core/ward.py everywhere
-    # and faster even under the CPU interpreter — "ref" pins the
-    # original loop (A/B parity gates, debugging). Only meaningful for
+    # the Pallas kernel on TPU and to core/ward.py elsewhere (the two are
+    # bitwise-equal); "kernel"/"ref" pin one (A/B parity gates,
+    # debugging). Only meaningful for
     # method="ward"; carried but inert otherwise. RUNTIME-ONLY: never
     # persisted into manifests — both impls produce identical artifacts
     # (the bench gates it), so pinning an impl into an artifact would

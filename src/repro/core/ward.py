@@ -23,15 +23,13 @@ are L2-normalized before clustering (tests pin this equivalence to SciPy).
 Ward is *reducible*, so the greedy merge order reproduces the NN-chain
 dendrogram; cutting at K clusters equals scipy fcluster(criterion="maxclust").
 
-PRODUCTION PATH: this module is now the REFERENCE implementation — its
-full-matrix argmin per merge step is O(N^3) per document. Builds run
-through ``repro.kernels.ward_pool`` (``ward_assign``), a Pallas kernel
-that keeps the distance matrix in VMEM and replaces the global argmin
-with lazy cached row minima (amortized O(N) selection per step),
-bitwise-equal to ``ward_cluster_batch`` and ~5-7x faster per batch even
-under the CPU interpreter. ``PoolingSpec.ward_kernel="ref"`` pins this
-loop for A/B parity gates; tests/test_kernels_ward.py sweeps the
-bitwise pin.
+PRODUCTION PATH: on TPU, builds run through ``repro.kernels.ward_pool``
+(``ward_assign``), a Pallas kernel that keeps the distance matrix in
+VMEM for the whole merge loop and starts from this module's
+``ward_distances``; it is bitwise-equal to ``ward_cluster_batch``. Off
+the TPU this module IS the path (the kernel's interpreter run is slower
+than it). ``PoolingSpec.ward_kernel`` pins either one;
+tests/test_kernels_ward.py sweeps the bitwise pin.
 """
 from __future__ import annotations
 
@@ -43,18 +41,27 @@ import jax.numpy as jnp
 _INF = jnp.float32(jnp.inf)
 
 
-def _init_state(x, mask):
-    """x: [N, d] float32 (pre-normalized); mask: [N] bool."""
+def ward_distances(x, mask):
+    """Initial squared Ward distances of one document: x [N, d] raw
+    token vectors, mask [N] bool -> d2 [N, N] (+inf on masked pairs and
+    on the diagonal). Tokens are L2-normalized first (cosine
+    clustering). The Pallas kernel starts from these same distances
+    (``kernels/ward_pool/ops.py``), so both paths merge identical
+    values."""
     N = x.shape[0]
+    x = x.astype(jnp.float32)
+    nrm = jnp.linalg.norm(x, axis=-1, keepdims=True)
+    x = x / jnp.maximum(nrm, 1e-9)
+    x = jnp.where(mask[:, None], x, 0.0)
     sq = jnp.sum(x * x, axis=-1)
-    d2 = sq[:, None] + sq[None, :] - 2.0 * (x @ x.T)
+    # HIGHEST: the TPU's default f32 matmul rounds its inputs to bf16,
+    # which would reorder near-tied merges; on the CPU it is the f32 dot
+    g = jnp.matmul(x, x.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = sq[:, None] + sq[None, :] - 2.0 * g
     d2 = jnp.maximum(d2, 0.0)
     valid_pair = mask[:, None] & mask[None, :]
     eye = jnp.eye(N, dtype=bool)
-    d2 = jnp.where(valid_pair & ~eye, d2, _INF)
-    sizes = jnp.where(mask, 1, 0).astype(jnp.float32)
-    assign = jnp.arange(N, dtype=jnp.int32)
-    return d2, sizes, assign
+    return jnp.where(valid_pair & ~eye, d2, _INF)
 
 
 def _merge_once(d2, sizes, assign, n_active, k_target):
@@ -102,11 +109,9 @@ def ward_cluster(x, mask, k_target):
       assign: [N] int32 — cluster representative index per token
               (padded tokens keep their own index; mask externally).
     """
-    x = x.astype(jnp.float32)
-    nrm = jnp.linalg.norm(x, axis=-1, keepdims=True)
-    x = x / jnp.maximum(nrm, 1e-9)
-    x = jnp.where(mask[:, None], x, 0.0)
-    d2, sizes, assign = _init_state(x, mask)
+    d2 = ward_distances(x, mask)
+    sizes = jnp.where(mask, 1, 0).astype(jnp.float32)
+    assign = jnp.arange(x.shape[0], dtype=jnp.int32)
     n_active = jnp.sum(mask.astype(jnp.int32))
     k_target = jnp.maximum(jnp.int32(k_target), 1)
 

@@ -12,7 +12,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.maxsim.ops import _on_tpu, _pad_to
+from repro.kernels.maxsim.kernel import pad_slots
+from repro.kernels.maxsim.ops import _on_tpu, _pad_axis_to, _q_mask_col
 from repro.kernels.plaid_probe.kernel import plaid_probe_pallas
 from repro.kernels.plaid_probe.ref import plaid_probe_ref
 
@@ -30,11 +31,14 @@ def plaid_probe_scores(q, q_mask, centroids, codes, code_mask, cand_mask,
         return plaid_probe_ref(q, q_mask, centroids, codes, code_mask,
                                cand_mask, t_cs=t_cs)
     C = codes.shape[1]
-    codes = _pad_to(codes.astype(jnp.int32), 1, block_c)
-    code_mask = _pad_to(code_mask, 1, block_c)
-    cand_mask = _pad_to(cand_mask, 1, block_c)
+    n = pad_slots(C, block_c)
+
+    def pad(x):
+        return _pad_axis_to(x, 1, n).astype(jnp.int32)
+
     out = plaid_probe_pallas(
-        jnp.asarray(q, jnp.float32), jnp.asarray(q_mask, bool),
-        jnp.asarray(centroids, jnp.float32), codes, code_mask, cand_mask,
-        t_cs=float(t_cs), block_c=block_c, interpret=not _on_tpu())
-    return out[:, :C]
+        jnp.asarray(q, jnp.float32), _q_mask_col(q_mask),
+        jnp.asarray(centroids, jnp.float32).T, pad(codes),
+        pad(code_mask), t_cs=float(t_cs), block_c=block_c,
+        interpret=not _on_tpu())
+    return jnp.where(cand_mask, out[:, 0, :C], -jnp.inf)

@@ -27,9 +27,9 @@ def dequant_score(words, centroid_ids, centroids, values, q, *,
     rows = jnp.take(centroids, centroid_ids, axis=0)
     M = words.shape[0]
     pad = (-M) % block_m
-    if pad:
-        words = jnp.pad(words, ((0, pad), (0, 0)))
-        rows = jnp.pad(rows, ((0, pad), (0, 0)))
-    out = dequant_score_pallas(words, rows, values, q, bits=bits,
+    words = jax.lax.bitcast_convert_type(words.astype(jnp.uint32), jnp.int32)
+    words_t = jnp.pad(words, ((0, pad), (0, 0))).T
+    rows_t = jnp.pad(rows.astype(jnp.float32), ((0, pad), (0, 0))).T
+    out = dequant_score_pallas(words_t, rows_t, values, q, bits=bits,
                                block_m=block_m, interpret=not _on_tpu())
-    return out[:M]
+    return out[:, :M].T

@@ -1,42 +1,31 @@
 """Batched agglomerative-Ward Pallas kernel: the indexing fast path.
 
 One program clusters a block of ``block_b`` documents with the whole
-merge loop fused in-register: the per-doc ``[N, N]`` squared-distance
-matrix lives in VMEM for the lifetime of the program (N = doc_maxlen,
-so ~``block_b * N^2 * 4`` bytes — 8 x 256^2 x 4 = 2 MiB at the
-production shape, comfortably under the ~16 MiB/core of TPU v5e), and
-every Lance-Williams row update is a masked elementwise pass over rows
-already resident — no HBM round-trip per merge step.
+merge loop fused: the per-doc ``[N, N]`` squared-distance matrix lives
+in VMEM for the lifetime of the program (N = doc_maxlen, so
+``block_b * N^2 * 4`` bytes — 8 x 256^2 x 4 = 2 MiB at the production
+shape), and every merge step is a handful of masked elementwise passes
+over it — no HBM round-trip per merge.
 
-Why this is fast where ``core/ward.py`` is not: the reference spends
-each of its N-1 steps on a full ``[N, N]`` reshape-argmin (O(N^2) reads
-per merge, O(N^3) per doc). This kernel replaces the global argmin with
-ANDERBERG-STYLE LAZY ROW MINIMA: ``lb[b, i]`` caches a lower bound on
-row i's minimum, and because Ward's linkage is REDUCIBLE (merging A,B
-never decreases d2(AB, C) below min(d2(A,C), d2(B,C)) for the winning
-pair), stale cached minima are always valid lower bounds. Selecting the
-next merge is argmin over the N-vector ``lb`` plus a short
-verify-by-rescan loop (recompute one row's true min until the chosen
-row's bound is tight) — amortized O(N) per step instead of O(N^2),
-with the fp-safety net ``lb = min(lb, new_row)`` after every update so
-a bound can never sit above the true row minimum.
+Each step is the reference's step (``core/ward.py``), expressed in the
+shapes Mosaic lowers: per-doc vectors are rows ``[bb, 1, N]`` (N on
+lanes), per-doc scalars are ``[bb, 1, 1]``, and every gather/scatter
+of ``_merge_once`` becomes a one-hot select over the resident matrix:
 
-Bitwise parity with the reference is load-bearing (index artifacts must
-not depend on which path built them), so the tie-breaking is reproduced
-exactly: the reference takes ``argmin(d2.reshape(-1))`` = the first
-row-major occurrence of the global minimum. Here that is (first row
-whose verified min equals the global min — argmin over ``lb`` returns
-the first — then first column at that min via the
-min-over-masked-iota trick in ``_row_min_first_arg``). Merges the
-reference would skip (k reached, or only +inf distances left) are
-folded through ``do`` by writing the ORIGINAL row values back, so the
-scatters need no full-matrix ``where(do, ...)`` copy and no-op steps
-are bitwise no-ops.
+  * select: row minima -> global minimum -> first row holding it ->
+    first column in that row. That is ``argmin(d2.reshape(-1))``, the
+    reference's row-major tie-break, with no arithmetic, so the merge
+    order is bitwise the reference's;
+  * Lance-Williams: the same expression, op for op, on the extracted
+    rows ``d2[i]`` / ``d2[j]``;
+  * update: rows and columns i, j are rewritten in one select. A row
+    turns into a column through the diagonal (``where(eye, row, inf)``
+    reduced over lanes — an exact copy). Merges the reference skips (k
+    reached, or only +inf distances left) write the original rows back
+    (``do``-folding), so no-op steps are bitwise no-ops.
 
-Everything is plain vector/matrix jnp inside the kernel body, so
-``interpret=True`` (the CPU path ``ops.py`` selects off-TPU) lowers to
-the same fused XLA loop and keeps the ~7x win over the reference on
-CPU as well.
+The loop runs the block's largest merge budget (scalar-prefetched per
+block); steps past a doc's own budget are ``do``-folded no-ops.
 """
 from __future__ import annotations
 
@@ -45,156 +34,120 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Python float, NOT jnp.float32(inf): a module-level device array would
 # be captured as a kernel constant, which pallas_call rejects.
 _INF = float("inf")
 
 
-def _row_min_first_arg(rows, N: int):
-    """Min + FIRST-occurrence argmin over the last axis of [bb, N] rows
-    (matches the reference's row-major flat-argmin tie-break)."""
-    m = jnp.min(rows, axis=-1, keepdims=True)
-    iota = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
-    a = jnp.min(jnp.where(rows == m, iota, N), axis=-1)
-    return m[:, 0], a.astype(jnp.int32)
+def _first(hit, iota, n: int, axis: int):
+    """Index of the first True along ``axis`` (``n`` if none), keepdims."""
+    return jnp.min(jnp.where(hit, iota, n), axis=axis, keepdims=True)
 
 
-def ward_merge_block(x, mask, k_target, n_steps):
-    """Cluster a [bb, N, d] block: assign [bb, N] int32 (representative
-    token index per cluster), bitwise == ``ward_cluster_batch``.
-
-    ``k_target`` is per-doc [bb]; ``n_steps`` is a scalar trip count
-    (max over the block of ``n_valid - k``). Steps past a doc's own
-    merge budget are ``do``-folded no-ops, so a block-level trip count
-    is exact, not approximate.
+def ward_merge_block(d2, mask, k_target, n_steps):
+    """Cluster a block from its initial distances: d2 [bb, N, N] (+inf on
+    masked pairs and the diagonal), mask [bb, 1, N] emit mask, k_target
+    [bb, 1, 1] cluster target, ``n_steps`` a scalar trip count ->
+    assign [bb, 1, N] int32 (representative token index per cluster),
+    bitwise == ``ward_cluster_batch``.
     """
-    bb, N, d = x.shape
-    barange = jnp.arange(bb)
-    sq = jnp.sum(x * x, axis=-1)
-    d2 = sq[:, :, None] + sq[:, None, :] - 2.0 * jnp.einsum(
-        "bnd,bmd->bnm", x, x)
-    d2 = jnp.maximum(d2, 0.0)
-    valid = mask[:, :, None] & mask[:, None, :]
-    eye = jnp.eye(N, dtype=bool)[None]
-    d2 = jnp.where(valid & ~eye, d2, _INF)
-    sizes = jnp.where(mask, 1, 0).astype(jnp.float32)
-    assign = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32)[None],
-                              (bb, N))
-    n_active = jnp.sum(mask.astype(jnp.int32), axis=-1)
-    lb = jnp.min(d2, axis=-1)            # true row minima at init
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bb, N), 1)
+    bb, N, _ = d2.shape
+    sub = jax.lax.broadcasted_iota(jnp.int32, (bb, N, 1), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bb, 1, N), 2)
+    eye = sub == lane                                        # [bb, N, N]
 
-    def select(d2, lb):
-        """Verified global min (i, j, dij, lb') with the reference's
-        tie-breaks: argmin(lb) is the candidate row; rescan its true
-        row min; accept only when bound == truth (reducibility
-        guarantees termination — each rescan tightens one bound)."""
+    def to_col(row):
+        """[bb, 1, N] row -> [bb, N, 1] column, an exact copy."""
+        return jnp.min(jnp.where(eye, row, _INF), axis=2, keepdims=True)
 
-        def cond(state):
-            _, _, _, _, ok = state
-            return ~jnp.all(ok)
+    def row_at(d2, i):
+        """Row i of every doc's matrix: [bb, 1, N]."""
+        return jnp.min(jnp.where(sub == i, d2, _INF), axis=1, keepdims=True)
 
-        def body(state):
-            lb, _, _, _, _ = state
-            i = jnp.argmin(lb, axis=-1).astype(jnp.int32)
-            row = jnp.take_along_axis(d2, i[:, None, None], axis=1)[:, 0]
-            rm, ja = _row_min_first_arg(row, N)
-            cur = jnp.take_along_axis(lb, i[:, None], axis=1)[:, 0]
-            ok = (rm == cur) | (jnp.isinf(rm) & jnp.isinf(cur))
-            lb = lb.at[barange, i].set(rm)
-            return lb, i, ja, rm, ok
+    def at(row, i):
+        """row[i] per doc: [bb, 1, 1] (exact: one value plus zeros)."""
+        return jnp.sum(jnp.where(lane == i, row, 0.0), axis=2, keepdims=True)
 
-        i0 = jnp.zeros(bb, jnp.int32)
-        state = (lb, i0, i0, jnp.zeros(bb, jnp.float32),
-                 jnp.zeros(bb, bool))
-        lb, i, j, dij, _ = jax.lax.while_loop(cond, body, state)
-        return i, j, dij, lb
+    sizes = jnp.where(mask != 0, 1.0, 0.0)                   # [bb, 1, N]
+    assign = lane
+    n_active = jnp.sum(mask, axis=2, keepdims=True)          # [bb, 1, 1]
 
     def step(_, state):
-        d2, lb, sizes, assign, n_active = state
-        i, j, dij, lb = select(d2, lb)
+        d2, sizes, assign, n_active = state
+        # flat row-major argmin: first row holding the global minimum,
+        # then the first column in that row
+        row_min = jnp.min(d2, axis=2, keepdims=True)         # [bb, N, 1]
+        dij = jnp.min(row_min, axis=1, keepdims=True)        # [bb, 1, 1]
+        i = _first(row_min == dij, sub, N, axis=1)
+        j = _first(row_at(d2, i) == dij, lane, N, axis=2)
         i, j = jnp.minimum(i, j), jnp.maximum(i, j)
         do = (n_active > k_target) & jnp.isfinite(dij)
-        d2i = jnp.take_along_axis(d2, i[:, None, None], axis=1)[:, 0]
-        d2j = jnp.take_along_axis(d2, j[:, None, None], axis=1)[:, 0]
-        si = jnp.take_along_axis(sizes, i[:, None], axis=1)
-        sj = jnp.take_along_axis(sizes, j[:, None], axis=1)
+        d2i, d2j = row_at(d2, i), row_at(d2, j)
+        si, sj = at(sizes, i), at(sizes, j)
         sc = sizes
         denom = si + sj + sc
         # Lance-Williams (squared Ward form), same guard as the ref
         new_row = ((si + sc) * d2i + (sj + sc) * d2j
-                   - sc * dij[:, None]) / jnp.maximum(denom, 1e-9)
+                   - sc * dij) / jnp.maximum(denom, 1e-9)
+        oh_i, oh_j = lane == i, lane == j
         was_inf = jnp.isinf(d2i) | jnp.isinf(d2j)
-        oh_i = lane == i[:, None]
-        oh_j = lane == j[:, None]
         new_row = jnp.where(was_inf | oh_i | oh_j, _INF, new_row)
         # do-folding: a skipped merge writes the original rows back
-        row_i = jnp.where(do[:, None], new_row, d2i)
-        row_j = jnp.where(do[:, None], _INF, d2j)
-        d2 = d2.at[barange, i, :].set(row_i)
-        d2 = d2.at[barange, :, i].set(row_i)
-        d2 = d2.at[barange, j, :].set(row_j)
-        d2 = d2.at[barange, :, j].set(row_j)
-        # bounds: other rows may only have gained the new column as
-        # their minimum; row i is recomputed exactly; row j retires
-        lb = jnp.where(do[:, None], jnp.minimum(lb, new_row), lb)
-        lb_i = jnp.where(do, jnp.min(new_row, axis=-1),
-                         jnp.take_along_axis(lb, i[:, None], axis=1)[:, 0])
-        lb_j = jnp.where(do, _INF,
-                         jnp.take_along_axis(lb, j[:, None], axis=1)[:, 0])
-        lb = lb.at[barange, i].set(lb_i)
-        lb = lb.at[barange, j].set(lb_j)
-        sizes = jnp.where(do[:, None],
-                          jnp.where(oh_i, si + sj,
-                                    jnp.where(oh_j, 0.0, sizes)), sizes)
-        assign = jnp.where(do[:, None] & (assign == j[:, None]),
-                           i[:, None], assign)
+        row_i = jnp.where(do, new_row, d2i)
+        row_j = jnp.where(do, _INF, d2j)
+        col_i, col_j = to_col(row_i), to_col(row_j)
+        r_i, r_j = sub == i, sub == j
+        d2 = jnp.where(r_j | (lane == j), jnp.where(r_j, row_j, col_j),
+                       jnp.where(r_i, row_i, jnp.where(oh_i, col_i, d2)))
+        sizes = jnp.where(do, jnp.where(oh_i, si + sj,
+                                        jnp.where(oh_j, 0.0, sizes)), sizes)
+        assign = jnp.where(do & (assign == j), i, assign)
         n_active = jnp.where(do, n_active - 1, n_active)
-        return d2, lb, sizes, assign, n_active
+        return d2, sizes, assign, n_active
 
-    state = (d2, lb, sizes, assign, n_active)
-    state = jax.lax.fori_loop(0, n_steps, step, state)
-    return state[3]
+    state = jax.lax.fori_loop(0, n_steps, step,
+                              (d2, sizes, assign, n_active))
+    return state[2]
 
 
-def _ward_pool_kernel(x_ref, mask_ref, k_ref, steps_ref, o_ref):
+def _ward_pool_kernel(steps_ref, d2_ref, mask_ref, k_ref, o_ref):
     """One program = one block of docs; the whole merge loop runs on
     VMEM-resident state."""
-    x = x_ref[...]
-    mask = mask_ref[...]
-    k = k_ref[...]
-    n_steps = jnp.max(steps_ref[...])
-    o_ref[...] = ward_merge_block(x, mask, k, n_steps)
+    n_steps = steps_ref[pl.program_id(0)]
+    o_ref[...] = ward_merge_block(d2_ref[...], mask_ref[...], k_ref[...],
+                                  n_steps)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
-def ward_pool_pallas(x, mask, k, steps, *, block_b: int = 8,
+def ward_pool_pallas(d2, mask, k, steps, *, block_b: int = 8,
                      interpret: bool = False):
     """Pallas dispatch: grid over doc blocks (B must be a multiple of
     ``block_b`` — ``ops.ward_assign`` pads with masked docs).
 
     Args:
-      x: [B, N, d] f32 unit token vectors (masked rows zero).
-      mask: [B, N] bool emit mask.
-      k: [B] int32 per-doc cluster target (``n_valid // factor + 1``).
-      steps: [B] int32 per-doc merge budget (``max(n_valid - k, 0)``);
-        each program runs its block's max and do-folds the rest.
-    Returns assign [B, N] int32.
+      d2: [B, N, N] f32 initial distances (``core.ward.ward_distances``).
+      mask: [B, 1, N] int32 emit mask (1 = valid).
+      k: [B, 1, 1] int32 per-doc cluster target (``n_valid // factor + 1``).
+      steps: [B // block_b] int32 merge budget per block (the max of
+        ``n_valid - k`` over its docs).
+    Returns assign [B, 1, N] int32.
     """
-    B, N, d = x.shape
+    B, N, _ = d2.shape
     assert B % block_b == 0, (B, block_b)
-    grid = (B // block_b,)
     return pl.pallas_call(
         _ward_pool_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, N, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_b, N), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((block_b, N), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, N), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B // block_b,),
+            in_specs=[
+                pl.BlockSpec((block_b, N, N), lambda i, s: (i, 0, 0)),
+                pl.BlockSpec((block_b, 1, N), lambda i, s: (i, 0, 0)),
+                pl.BlockSpec((block_b, 1, 1), lambda i, s: (i, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((block_b, 1, N), lambda i, s: (i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, 1, N), jnp.int32),
         interpret=interpret,
-    )(x, mask, k, steps)
+    )(steps, d2, mask, k)

@@ -1,12 +1,12 @@
-"""jit'd public wrapper for the Ward-pooling kernel: normalizes inputs
-the same way the reference does, pads the doc batch to a block
-multiple with fully-masked docs, dispatches to the Pallas kernel
-(interpret=True off-TPU), and unpads.
+"""jit'd public wrapper for the Ward-pooling kernel: computes the
+reference's initial distances, pads the doc batch to a block multiple
+with fully-masked docs, dispatches to the Pallas kernel (interpret=True
+off-TPU), and unpads.
 
 ``impl`` resolution (what ``PoolingSpec.ward_kernel`` carries):
-  * ``"auto"``   — the kernel path (it is bitwise-equal to the
-    reference everywhere and faster even under the CPU interpreter, so
-    auto means ON; ``"ref"`` exists for A/B parity gates and debugging).
+  * ``"auto"``   — the kernel on TPU, ``core/ward.py`` elsewhere (the
+    two are bitwise-equal; under the CPU interpreter the kernel is the
+    slower of the two, so it is a correctness tool there).
   * ``"kernel"`` — force the Pallas path.
   * ``"ref"``    — force ``core/ward.py``'s ``ward_cluster_batch``.
 """
@@ -17,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.ward import ward_distances
 from repro.kernels.maxsim.ops import _on_tpu, _pad_to
 from repro.kernels.ward_pool.kernel import ward_pool_pallas
 from repro.kernels.ward_pool.ref import ward_assign_ref
@@ -29,25 +30,26 @@ def resolve_impl(impl: str) -> str:
     if impl not in WARD_IMPLS:
         raise ValueError(f"ward impl must be one of {WARD_IMPLS}, "
                          f"got {impl!r}")
-    return "kernel" if impl == "auto" else impl
+    if impl == "auto":
+        return "kernel" if _on_tpu() else "ref"
+    return impl
 
 
 @functools.partial(jax.jit, static_argnames=("factor", "block_b"))
 def _ward_assign_kernel(x, mask, factor: int, block_b: int = 8):
-    B, N, d = x.shape
-    x = x.astype(jnp.float32)
-    # same per-token normalization as ward_cluster's _init_state
-    nrm = jnp.linalg.norm(x, axis=-1, keepdims=True)
-    x = x / jnp.maximum(nrm, 1e-9)
-    x = jnp.where(mask[..., None], x, 0.0)
-    xp = _pad_to(x, 0, block_b)
-    mp = _pad_to(mask, 0, block_b)        # padded docs are all-masked
+    B = x.shape[0]
+    # the reference's own initial distances, so both paths merge
+    # identical values; padded docs are all-masked (all +inf)
+    d2 = _pad_to(jax.vmap(ward_distances)(x, mask), 0, block_b,
+                 value=jnp.inf)
+    mp = _pad_to(mask, 0, block_b)
     n_valid = jnp.sum(mp.astype(jnp.int32), axis=-1)
     k = jnp.maximum(n_valid // factor + 1, 1)
-    steps = jnp.maximum(n_valid - k, 0)
-    out = ward_pool_pallas(xp, mp, k, steps, block_b=block_b,
+    steps = jnp.maximum(n_valid - k, 0).reshape(-1, block_b).max(axis=1)
+    out = ward_pool_pallas(d2, mp.astype(jnp.int32)[:, None, :],
+                           k[:, None, None], steps, block_b=block_b,
                            interpret=not _on_tpu())
-    return out[:B]
+    return out[:B, 0]
 
 
 def ward_assign(x, mask, factor: int, *, impl: str = "auto",

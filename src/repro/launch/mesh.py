@@ -18,13 +18,19 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh():
     """Whatever devices exist locally, as a 1D 'data' mesh (tests/smoke)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), axis_types=_auto(1))
+
+
+def _auto(n: int):
+    """Auto axis types: shardings are propagated and constrained with
+    ``with_sharding_constraint`` (``jax.make_mesh`` defaults to Explicit)."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_serve_mesh(n_replicas: int, n_shards: int):

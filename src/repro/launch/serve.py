@@ -9,6 +9,11 @@
     python -m repro.launch.serve --dataset scifact --pool-factor 2 \
         --backend plaid --queries 256 --arrival-qps 50,200
 
+``--model`` picks the encoder: ``smoke`` (default, the 2-layer test
+trunk) or ``colbertv2`` (the published 12-layer/768-wide ColBERTv2
+encoder, 128-d vectors, doc_maxlen 256 — seeded random weights). The
+process exits non-zero when any open-loop request fails.
+
 Closed-loop mode replays fixed-size microbatches through the staged
 two-stage engine and reports QPS and p50/p99 *service* time per batch
 size — exactly ``--queries`` queries are served per row (the final
@@ -50,7 +55,7 @@ import jax
 import numpy as np
 
 from repro.api import Retriever
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.core.persist import (MANIFEST_NAME, artifact_bytes,
                                 artifact_generation)
 from repro.core.sharded import ShardedIndex
@@ -58,9 +63,25 @@ from repro.core.spec import (IndexSpec, PoolingSpec, RetrieverSpec,
                              ServeSpec, ShardSpec, add_spec_args,
                              backend_names, spec_from_args)
 from repro.data.corpus import DATASET_SPECS, SyntheticRetrievalCorpus
-from repro.launch.engine import ServingEngine, run_open_loop
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.engine import CompileCounter, ServingEngine, run_open_loop
 from repro.models.colbert import init_colbert
 from repro.retrieval.searcher import Searcher
+
+
+MODELS = {"smoke": get_smoke_config, "colbertv2": get_config}
+
+
+def model_config(name: str):
+    """The ColbertConfig behind ``--model``."""
+    return MODELS[name]("colbertv2")
+
+
+def _microbatch_sizes(batch_size: int, n_queries: int):
+    sizes = [batch_size] * (n_queries // batch_size)
+    if n_queries % batch_size:
+        sizes.append(n_queries % batch_size)
+    return sizes
 
 
 def serve_microbatches(searcher: Searcher, q_tokens: np.ndarray,
@@ -74,9 +95,7 @@ def serve_microbatches(searcher: Searcher, q_tokens: np.ndarray,
     shapes are warmed first so jit compile time never lands in a
     measured batch.
     """
-    sizes = [batch_size] * (n_queries // batch_size)
-    if n_queries % batch_size:
-        sizes.append(n_queries % batch_size)
+    sizes = _microbatch_sizes(batch_size, n_queries)
     searcher.warmup(sorted(set(sizes)), k=k)
     lat = []
     served = 0
@@ -99,45 +118,69 @@ def _print_probe(index) -> None:
         print(f"      per-shard probe (last batch): {per}")
 
 
-def closed_loop(searcher, index, q_all, batch_sizes, n_queries, k) -> None:
+def closed_loop(searcher, index, q_all, batch_sizes, n_queries, k):
+    """Fixed microbatches per batch size; prints and returns one row per
+    size (``compiles`` counts XLA compiles inside the timed window —
+    the warmup compiles every shape first, so it should be 0)."""
     print(f"{'batch':>5s} {'served':>7s} {'QPS':>8s} "
-          f"{'p50(ms)':>8s} {'p99(ms)':>8s}")
+          f"{'p50(ms)':>8s} {'p99(ms)':>8s} {'compiles':>8s}")
+    rows = []
     for bs in batch_sizes:
-        lat, sizes = serve_microbatches(searcher, q_all, bs, n_queries, k=k)
-        qps = sizes.sum() / lat.sum()
+        searcher.warmup(sorted(set(_microbatch_sizes(bs, n_queries))), k=k)
+        with CompileCounter() as cc:
+            lat, sizes = serve_microbatches(searcher, q_all, bs, n_queries,
+                                            k=k)
         lat_ms = lat * 1e3
-        print(f"{bs:5d} {int(sizes.sum()):7d} {qps:8.1f} "
-              f"{np.percentile(lat_ms, 50):8.1f} "
-              f"{np.percentile(lat_ms, 99):8.1f}")
+        row = {"batch": bs, "served": int(sizes.sum()),
+               "qps": float(sizes.sum() / lat.sum()),
+               "latency_p50_ms": float(np.percentile(lat_ms, 50)),
+               "latency_p99_ms": float(np.percentile(lat_ms, 99)),
+               "compiles": cc.count}
+        print(f"{bs:5d} {row['served']:7d} {row['qps']:8.1f} "
+              f"{row['latency_p50_ms']:8.1f} {row['latency_p99_ms']:8.1f} "
+              f"{cc.count:8d}")
         _print_probe(index)
+        rows.append(row)
+    return rows
 
 
 def open_loop(searcher, index, q_all, rates, n_queries,
-              serve_spec: ServeSpec, index_dir, index_generation) -> None:
+              serve_spec: ServeSpec, index_dir, index_generation):
+    """Poisson arrivals through the ServingEngine at each offered rate;
+    prints and returns one ``run_open_loop`` row per rate, with the
+    in-window compile count (``compiles``) added."""
     print(f"{'offered':>8s} {'achieved':>8s} {'p50(ms)':>8s} "
           f"{'p99(ms)':>8s} {'coalesce':>8s} {'flushes(full/ddl)':>18s} "
-          f"{'err':>4s}")
+          f"{'err':>4s} {'compiles':>8s}")
+    rows = []
     for i, rate in enumerate(rates):
         engine = ServingEngine.from_spec(
             searcher, serve_spec.replace(warmup_on_start=(i == 0)),
             index_dir=index_dir, index_generation=index_generation)
         with engine:
-            row = run_open_loop(engine, q_all, rate, n_queries,
-                                k=serve_spec.k)
+            with CompileCounter() as cc:
+                row = run_open_loop(engine, q_all, rate, n_queries,
+                                    k=serve_spec.k)
+        row["compiles"] = cc.count
         snap = engine.stats.snapshot()
         fl = snap["flush_reasons"]
         print(f"{row['arrival_qps']:8.1f} {row['achieved_qps']:8.1f} "
               f"{row['latency_p50_ms']:8.1f} {row['latency_p99_ms']:8.1f} "
               f"{snap['mean_batch_size']:8.1f} "
               f"{fl['full']:8d}/{fl['deadline']:<9d} "
-              f"{row['errors']:4d}")
+              f"{row['errors']:4d} {cc.count:8d}")
         _print_probe(index)
+        rows.append(row)
+    return rows
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="scifact",
                     choices=sorted(DATASET_SPECS))
+    ap.add_argument("--model", default="smoke", choices=sorted(MODELS),
+                    help="encoder: the 2-layer smoke trunk or the "
+                         "full-width ColBERTv2 (random weights)")
     # typed knobs derive their flags from the spec layer (core/spec.py):
     # --pool-method/--pool-factor (PoolingSpec), --max-batch/
     # --max-wait-ms/--k (ServeSpec), --shard-max-vectors (ShardSpec) —
@@ -171,7 +214,7 @@ def main(argv=None):
         ap.error(f"--arrival-qps must be positive, got "
                  f"{args.arrival_qps!r}")
 
-    cfg = get_smoke_config("colbertv2")
+    cfg = model_config(args.model)
     serve_spec = spec_from_args(
         ServeSpec, args,
         only=("max_batch", "max_wait_ms", "k", "n_replicas"))
@@ -227,8 +270,12 @@ def main(argv=None):
     searcher = retriever.searcher
     q_all = corpus.query_token_batch(cfg.query_maxlen - 2)
     if rates:
-        open_loop(searcher, index, q_all, rates, args.queries,
-                  serve_spec, args.index_dir, generation)
+        rows = open_loop(searcher, index, q_all, rates, args.queries,
+                         serve_spec, args.index_dir, generation)
+        failed = sum(r["errors"] for r in rows)
+        if failed:
+            print(f"FAILED: {failed} open-loop request(s) errored")
+            return 1
     else:
         closed_loop(searcher, index, q_all, batch_sizes, args.queries,
                     serve_spec.k)
@@ -236,4 +283,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

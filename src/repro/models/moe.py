@@ -184,7 +184,6 @@ def moe_ep(p, x, cfg, capacity=None):
     Collective cost per layer: 2 all_to_alls of [T_loc*k, d] tokens
     (+ the FSDP weight all-gather), vs all-reduces of [E, C, d].
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.sharding.api import current_ctx
 
@@ -283,12 +282,12 @@ def moe_ep(p, x, cfg, capacity=None):
     x_spec = P(dp[0] if data_axes else None, None, None)
     w1_spec = P(model_axis, dp[0] if data_axes else None, None)
     w2_spec = P(model_axis, None, dp[0] if data_axes else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, None), w1_spec, w2_spec,
                   w1_spec if cfg.gated_mlp else P(None), x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False)
+        check_vma=False)
     w3 = p.get("w3") if cfg.gated_mlp else None
     y, aux = fn(p["router"]["w"], p["w1"], p["w2"], w3, x)
     if cfg.n_shared_experts:

@@ -31,7 +31,7 @@ def test_maxsim_sweep(nq, lq, nd, ld, dim, dtype):
     d = jnp.asarray(rng.normal(size=(nd, ld, dim)), dtype)
     qm = jnp.asarray(rng.random((nq, lq)) > 0.2)
     dm = jnp.asarray(rng.random((nd, ld)) > 0.2)
-    out = maxsim(q, qm, d, dm, block_q=4, block_d=4)
+    out = maxsim(q, qm, d, dm, block_d=4)
     ref = maxsim_ref(q, qm, d, dm)
     np.testing.assert_allclose(out, ref, rtol=tol(dtype), atol=tol(dtype)
                                * np.abs(np.asarray(ref)).max())
@@ -59,7 +59,7 @@ def test_maxsim_all_docs_masked():
     d = jnp.ones((2, 4, 8), jnp.float32)
     qm = jnp.ones((2, 4), bool)
     dm = jnp.zeros((2, 4), bool)
-    out = maxsim(q, qm, d, dm, block_q=2, block_d=2)
+    out = maxsim(q, qm, d, dm, block_d=2)
     assert np.allclose(np.asarray(out), 0.0)
 
 

@@ -462,3 +462,35 @@ def test_serve_microbatches_exact_counts(real_searcher):
     assert sizes.sum() == 19
     assert list(sizes) == [8, 8, 3]
     assert len(lat) == 3
+
+
+def test_serve_cli_exits_nonzero_on_failed_query(monkeypatch, capsys):
+    """An open-loop request whose search raises is counted as an error
+    AND makes the serving CLI exit non-zero (it used to print the error
+    column and return 0)."""
+    from repro.data.corpus import DATASET_SPECS, DatasetSpec
+    from repro.launch import serve
+
+    monkeypatch.setitem(DATASET_SPECS, "tiny", DatasetSpec(
+        "tiny", n_docs=24, n_queries=8, n_topics=4, doc_len_mean=20,
+        doc_len_std=4))
+    armed = {"on": False}
+    warmup, search = ServingEngine.warmup, MultiVectorIndex.search_batch
+
+    def warmup_then_arm(self):
+        warmup(self)
+        armed["on"] = True
+
+    def fail_once(self, *a, **kw):
+        if armed["on"]:
+            armed["on"] = False
+            raise RuntimeError("injected search failure")
+        return search(self, *a, **kw)
+
+    monkeypatch.setattr(ServingEngine, "warmup", warmup_then_arm)
+    monkeypatch.setattr(MultiVectorIndex, "search_batch", fail_once)
+    rc = serve.main(["--dataset", "tiny", "--backend", "flat",
+                     "--queries", "6", "--arrival-qps", "200",
+                     "--max-batch", "2"])
+    assert rc == 1
+    assert "open-loop request(s) errored" in capsys.readouterr().out

@@ -10,6 +10,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.mesh import make_host_mesh
 from repro.models.layers import tree_paths
 from repro.sharding.api import (constrain, lm_decode_rules,
                                 lm_long_decode_rules, lm_rules,
@@ -88,7 +89,7 @@ def test_constrain_noop_without_context():
 
 
 def test_constrain_applies_in_context():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_host_mesh()
     with mesh_context(mesh, {"batch": "data"}):
         y = jax.jit(lambda x: constrain(x, "batch", None))(jnp.ones((4, 4)))
     assert y.shape == (4, 4)

@@ -1,0 +1,100 @@
+"""Mosaic compiles of the serving/indexing kernels for a described TPU v5e.
+
+No chip is needed: the TPU compiler compiles for a topology that is
+described, not attached. Each test lowers one Pallas kernel at the
+widths the full-size ColBERTv2 path uses (dim 128, Lq 32, K 256, pooled
+docs of 136 tokens, Ward over N = 256) with ``interpret=False`` and
+asserts the compiled program holds the kernel (``tpu_custom_call``).
+Mosaic rejects what interpret mode accepts — block shapes off the
+(8, 128) tiling, unsupported reshapes, more VMEM than the scoped
+limit — so these keep the chip path compiling without chip time.
+
+The topology is described inside a module fixture (never at import:
+only one process at a time may load the TPU library, and every xdist
+worker imports this file).
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.maxsim.kernel import maxsim_pallas, maxsim_rerank_pallas
+from repro.kernels.maxsim_packed.kernel import maxsim_packed_rerank_pallas
+from repro.kernels.plaid_probe.kernel import plaid_probe_pallas
+from repro.kernels.ward_pool.kernel import ward_pool_pallas
+
+NQ, LQ, DIM, K, LD, S = 8, 32, 128, 256, 136, 256
+I32, F32 = jnp.int32, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_ward_pool_compiles(one_chip):
+    """N = doc_maxlen = 256: the [8, 256, 256] f32 distance block (2 MiB)
+    plus the merge loop's temporaries must fit the scoped VMEM."""
+    B, N = 16, 256
+    _compile(lambda *a: ward_pool_pallas(*a, block_b=8), one_chip,
+             ((B, N, N), F32), ((B, 1, N), I32), ((B, 1, 1), I32),
+             ((B // 8,), I32))
+
+
+@pytest.mark.parametrize("scan", ["all_docs", "rerank"])
+def test_maxsim_compiles(one_chip, scan):
+    if scan == "all_docs":
+        _compile(maxsim_pallas, one_chip, ((NQ, LQ, DIM), F32),
+                 ((NQ, LQ, 1), I32), ((4 * S, LD, DIM), F32),
+                 ((4 * S, LD), I32))
+    else:
+        _compile(maxsim_rerank_pallas, one_chip, ((NQ, LQ, DIM), F32),
+                 ((NQ, LQ, 1), I32), ((NQ, S, LD, DIM), F32),
+                 ((NQ, S, LD), I32))
+
+
+@pytest.mark.parametrize("bits", [2, 4])
+def test_maxsim_packed_compiles(one_chip, bits):
+    W = DIM * bits // 32
+    _compile(lambda *a: maxsim_packed_rerank_pallas(*a, bits=bits),
+             one_chip, ((NQ, LQ, DIM), F32), ((NQ, LQ, 1), I32),
+             ((NQ, S, W, LD), I32), ((NQ, S, LD), I32), ((NQ, S, LD), I32),
+             ((DIM, K), F32), ((DIM, 1 << bits), F32))
+
+
+@pytest.mark.parametrize("n_cand", [64, S])
+def test_plaid_probe_compiles(one_chip, n_cand):
+    """One output lane tile (64 candidates) and several (256)."""
+    _compile(lambda *a: plaid_probe_pallas(*a, t_cs=0.45), one_chip,
+             ((NQ, LQ, DIM), F32), ((NQ, LQ, 1), I32), ((DIM, K), F32),
+             ((NQ, n_cand, LD), I32), ((NQ, n_cand, LD), I32))
