@@ -31,6 +31,7 @@ from typing import List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.docstore import DocStore, pad_candidate_sets
 from repro.core.hnsw import HNSW
 from repro.core.ivf import train_centroids
@@ -199,19 +200,34 @@ class MultiVectorIndex:
         self._preset_codec = codec
 
     def _add_plaid(self, doc_vectors):
-        if self._plaid is None:
-            if self._preset_codec is not None:
-                codec = self._preset_codec
+        # bytes: the pooled vectors go to the device once for the codec
+        # encode (twice more when the codec is trained here); their ids
+        # and packed codes come back
+        moved = sum(v.nbytes for v in doc_vectors)
+        held = self._plaid_code_bytes()
+        with obs.span(obs.PLAID_ADD) as sp:
+            h2d = moved
+            if self._plaid is None:
+                if self._preset_codec is not None:
+                    codec = self._preset_codec
+                else:
+                    flat = np.concatenate(doc_vectors)
+                    k = min(self.n_centroids, len(flat))
+                    centroids = train_centroids(flat, k)
+                    codec = train_codec(jnp.asarray(flat), centroids,
+                                        bits=self.quant_bits)
+                    h2d += 2 * flat.nbytes
+                self._plaid = build_plaid_index(doc_vectors, codec,
+                                                self.doc_maxlen)
             else:
-                flat = np.concatenate(doc_vectors)
-                k = min(self.n_centroids, len(flat))
-                centroids = train_centroids(flat, k)
-                codec = train_codec(jnp.asarray(flat), centroids,
-                                    bits=self.quant_bits)
-            self._plaid = build_plaid_index(doc_vectors, codec,
-                                            self.doc_maxlen)
-        else:
-            self._plaid.add(doc_vectors)
+                self._plaid.add(doc_vectors)
+            sp.set_metadata(h2d_bytes=h2d,
+                            d2h_bytes=self._plaid_code_bytes() - held)
+
+    def _plaid_code_bytes(self) -> int:
+        """Host bytes of the plaid centroid ids and packed codes."""
+        p = self._plaid
+        return 0 if p is None else p.assignments.nbytes + p.codes.nbytes
 
     # ------------------------------------------------------------ persistence
     def save(self, path: str, extra_meta: Optional[dict] = None) -> dict:
@@ -289,6 +305,11 @@ class MultiVectorIndex:
         slots come back as -inf. ``cand=None`` scores the whole live
         corpus (scores [Nq, n_docs]); otherwise scores [Nq, C].
         """
+        if (cand is not None and self.backend == "plaid"
+                and self._plaid is not None and self.packed_rerank):
+            # host query arrays go to the device inside, under its span
+            return maxsim_packed_rerank_store(self._plaid, qs, q_mask,
+                                              cand, cand_mask)
         qs = jnp.asarray(qs, jnp.float32)
         qm = (jnp.ones(qs.shape[:2], bool) if q_mask is None
               else jnp.asarray(q_mask))
@@ -301,10 +322,6 @@ class MultiVectorIndex:
             scores = maxsim_all_docs(qs, qm, d, dm)        # [Nq, n_docs]
             return jnp.where(jnp.asarray(self._live())[None, :],
                              scores, -jnp.inf)
-        if (self.backend == "plaid" and self._plaid is not None
-                and self.packed_rerank):
-            return maxsim_packed_rerank_store(self._plaid, qs, qm,
-                                              cand, cand_mask)
         if not isinstance(cand, np.ndarray):    # legacy store path is
             cand = np.asarray(cand, np.int64)   # host-indexed
             cand_mask = np.asarray(cand_mask)

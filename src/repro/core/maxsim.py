@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.sharding.api import constrain
 
 
@@ -115,18 +116,22 @@ def topk_with_pads(scores, cand, k: int):
     (scores [Nq, k] f32, ids [Nq, k] i64) padded with -inf/-1.
     """
     import numpy as np
-    kk = min(k, scores.shape[1])
-    top_s, top_i = jax.lax.top_k(scores, kk)
-    if isinstance(cand, jax.Array):
-        # device candidates: gather the winning ids on device so the
-        # ONLY host transfer after encode is this [Nq, k] result
-        ids_dev = jnp.take_along_axis(cand, top_i, axis=1)
-        top_s, ids = np.asarray(top_s), np.asarray(ids_dev).astype(np.int64)
-    else:
-        top_s, top_i = np.asarray(top_s), np.asarray(top_i)
-        ids = (top_i.astype(np.int64) if cand is None
-               else np.take_along_axis(np.asarray(cand, np.int64), top_i,
-                                       axis=1))
+    with obs.span(obs.PLAID_TOPK) as sp:
+        kk = min(k, scores.shape[1])
+        top_s, top_i = jax.lax.top_k(scores, kk)
+        if isinstance(cand, jax.Array):
+            # device candidates: gather the winning ids on device so the
+            # ONLY host transfer after encode is this [Nq, k] result
+            top_s = np.asarray(top_s)
+            ids = np.asarray(jnp.take_along_axis(cand, top_i, axis=1))
+            sp.set_metadata(d2h_bytes=top_s.nbytes + ids.nbytes)
+            ids = ids.astype(np.int64)
+        else:
+            top_s, top_i = np.asarray(top_s), np.asarray(top_i)
+            sp.set_metadata(d2h_bytes=top_s.nbytes + top_i.nbytes)
+            ids = (top_i.astype(np.int64) if cand is None
+                   else np.take_along_axis(np.asarray(cand, np.int64),
+                                           top_i, axis=1))
     ids = np.where(np.isfinite(top_s), ids, -1)
     if kk < k:
         top_s = np.pad(top_s, ((0, 0), (0, k - kk)),

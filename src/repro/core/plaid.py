@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.docstore import (DocStore, pad_candidate_sets,
                                  ragged_arange)
 from repro.core.ivf import (DeviceInvertedLists, InvertedLists,
@@ -384,8 +385,11 @@ def device_probe_plan(index: PLAIDIndex, Lq: int, nprobe: int,
                       ndocs: int, probe_kernel: str = "auto"):
     """Static decision + geometry for the device-resident candidate path.
 
-    Returns ``(use_device, (div, k, c_score, s_out))``. The device path
-    engages only when it is PROVABLY bitwise-equal to the host path:
+    Returns ``(True, (div, k, c_score, s_out))``, or ``(False, reason)``
+    where ``reason`` names why the host path serves: ``host_kernel``
+    (pinned), ``empty``, ``overflow``, ``dense`` or ``gather_cap`` (one
+    for each condition below). The device path engages only when it is
+    PROVABLY bitwise-equal to the host path:
 
       * the device IVF view is exact (``overflow == 0``);
       * the dense corpus-wide dispatch is statically unreachable — for
@@ -400,11 +404,13 @@ def device_probe_plan(index: PLAIDIndex, Lq: int, nprobe: int,
     fits), ``s_out`` the static output width (= the rerank slate width).
     """
     assert probe_kernel in PROBE_KERNELS, probe_kernel
-    if probe_kernel == "host" or index.n_vectors == 0 or index.n_docs == 0:
-        return False, None
+    if probe_kernel == "host":
+        return False, "host_kernel"
+    if index.n_vectors == 0 or index.n_docs == 0:
+        return False, "empty"
     div = index.device_ivf()
     if div.overflow != 0:
-        return False, None
+        return False, "overflow"
     n_docs = index.n_docs
     k = min(nprobe, index.codec.n_centroids)
     W = max(Lq, 1) * k * div.list_cap       # padded gather slots / query
@@ -418,10 +424,10 @@ def device_probe_plan(index: PLAIDIndex, Lq: int, nprobe: int,
     f_prune = _pad_up(int(ndocs), _CAND_BLOCK) if lmax > ndocs else 0
     f_noprune = min(lmax, _floor_ladder(int(ndocs)))
     if max(f_prune, f_noprune) >= n_docs:
-        return False, None
+        return False, "dense"
     if (probe_kernel != "device"
             and div.doc_member.size > _DEVICE_GATHER_CAP):
-        return False, None
+        return False, "gather_cap"
     return True, (div, k, c_score, s_out)
 
 
@@ -451,65 +457,72 @@ def _device_candidates(cs, qs, qm, doc_member, live, codes,
     """
     Nq = cs.shape[0]
     n_docs = live.shape[0]
-    csm = jnp.where(qm[:, :, None], cs, -jnp.inf)
-    _, probe = jax.lax.top_k(csm, k)                     # [Nq, Lq, k]
-    flat = probe.reshape(Nq, -1)                         # [Nq, Lq*k]
-    pvalid = jnp.broadcast_to(qm[:, :, None], probe.shape
-                              ).reshape(Nq, -1)
-    # (query, doc) set union as ONE matmul: a probed-centroid one-hot
-    # row per query times the 0/1 membership table counts, exactly
-    # (small integers in f32), how many probed lists own each doc
-    K = doc_member.shape[0]
-    probed = jnp.any(
-        (flat[:, :, None] == jax.lax.broadcasted_iota(jnp.int32,
-                                                      (1, 1, K), 2))
-        & pvalid[:, :, None], axis=1)                    # [Nq, K]
-    hits = jax.lax.dot_general(
-        probed.astype(jnp.float32), doc_member,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # [Nq, n_docs]
-    member = (hits > 0.0) & live[None, :]
-    counts = member.sum(axis=1).astype(jnp.int32)        # [Nq]
-    # compact member columns ascending via cumsum positions —
-    # bit-for-bit np.unique's ascending unique ids at slots 0..cnt-1
-    pos = jnp.cumsum(member, axis=1, dtype=jnp.int32) - 1
-    docid = jax.lax.broadcasted_iota(jnp.int32, (Nq, n_docs), 1)
-    tpos = jnp.where(member, pos, jnp.int32(c_score))    # cnt <= c_score
-    cand_c = jax.vmap(lambda t, d: jnp.zeros((c_score,), jnp.int32)
-                      .at[t].set(d, mode="drop"))(tpos, docid)
-    mask_c = (jax.lax.broadcasted_iota(jnp.int32, (Nq, c_score), 1)
-              < counts[:, None])   # pad slots read doc 0, as on host
+    # named scopes only label the ops' metadata (device trace)
+    with jax.named_scope("candidates/probe"):
+        csm = jnp.where(qm[:, :, None], cs, -jnp.inf)
+        _, probe = jax.lax.top_k(csm, k)                 # [Nq, Lq, k]
+        flat = probe.reshape(Nq, -1)                     # [Nq, Lq*k]
+        pvalid = jnp.broadcast_to(qm[:, :, None], probe.shape
+                                  ).reshape(Nq, -1)
+    with jax.named_scope("candidates/gather"):
+        # (query, doc) set union as ONE matmul: a probed-centroid one-hot
+        # row per query times the 0/1 membership table counts, exactly
+        # (small integers in f32), how many probed lists own each doc
+        K = doc_member.shape[0]
+        probed = jnp.any(
+            (flat[:, :, None] == jax.lax.broadcasted_iota(jnp.int32,
+                                                          (1, 1, K), 2))
+            & pvalid[:, :, None], axis=1)                # [Nq, K]
+        hits = jax.lax.dot_general(
+            probed.astype(jnp.float32), doc_member,
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [Nq, n_docs]
+        member = (hits > 0.0) & live[None, :]
+        counts = member.sum(axis=1).astype(jnp.int32)    # [Nq]
+        # compact member columns ascending via cumsum positions —
+        # bit-for-bit np.unique's ascending unique ids at slots 0..cnt-1
+        pos = jnp.cumsum(member, axis=1, dtype=jnp.int32) - 1
+        docid = jax.lax.broadcasted_iota(jnp.int32, (Nq, n_docs), 1)
+        tpos = jnp.where(member, pos, jnp.int32(c_score))  # cnt <= c_score
+        cand_c = jax.vmap(lambda t, d: jnp.zeros((c_score,), jnp.int32)
+                          .at[t].set(d, mode="drop"))(tpos, docid)
+        mask_c = (jax.lax.broadcasted_iota(jnp.int32, (Nq, c_score), 1)
+                  < counts[:, None])   # pad slots read doc 0, as on host
 
-    # the host prune decision, replicated: padded gather width > ndocs
-    maxc = jnp.maximum(counts.max(), 1)
-    ladder = jnp.asarray([_CAND_BLOCK << m for m in range(26)], jnp.int32)
-    host_c = jnp.min(jnp.where(ladder >= maxc, ladder,
-                               jnp.int32(2**31 - 1)))
-    keep = min(ndocs, c_score)
+    with jax.named_scope("candidates/prune"):
+        # the host prune decision, replicated: padded gather width > ndocs
+        maxc = jnp.maximum(counts.max(), 1)
+        ladder = jnp.asarray([_CAND_BLOCK << m for m in range(26)],
+                             jnp.int32)
+        host_c = jnp.min(jnp.where(ladder >= maxc, ladder,
+                                   jnp.int32(2**31 - 1)))
+        keep = min(ndocs, c_score)
 
-    def unpruned(cand_c, mask_c):
-        return cand_c[:, :s_out], mask_c[:, :s_out]
+        def unpruned(cand_c, mask_c):
+            return cand_c[:, :s_out], mask_c[:, :s_out]
 
-    def pruned(cand_c, mask_c):
-        gcodes = jnp.take(codes, cand_c, axis=0)         # [Nq, C, L]
-        gmask = jnp.take(tok_mask, cand_c, axis=0) & mask_c[:, :, None]
-        if impl == "kernel":
-            from repro.kernels.plaid_probe.ops import plaid_probe_scores
-            approx = plaid_probe_scores(qs, qm, centroids, gcodes,
-                                        gmask, mask_c, t_cs=t_cs,
-                                        impl="kernel")
-        else:
-            approx = _approx_scores_batch(csm, gcodes, gmask, mask_c,
-                                          t_cs)
-        top_s, top_i = jax.lax.top_k(approx, keep)
-        cand_p = jnp.take_along_axis(cand_c, top_i, axis=1)
-        mask_p = jnp.isfinite(top_s)
-        if keep < s_out:
-            cand_p = jnp.pad(cand_p, ((0, 0), (0, s_out - keep)))
-            mask_p = jnp.pad(mask_p, ((0, 0), (0, s_out - keep)))
-        return cand_p, mask_p
+        def pruned(cand_c, mask_c):
+            gcodes = jnp.take(codes, cand_c, axis=0)     # [Nq, C, L]
+            gmask = (jnp.take(tok_mask, cand_c, axis=0)
+                     & mask_c[:, :, None])
+            if impl == "kernel":
+                from repro.kernels.plaid_probe.ops import plaid_probe_scores
+                approx = plaid_probe_scores(qs, qm, centroids, gcodes,
+                                            gmask, mask_c, t_cs=t_cs,
+                                            impl="kernel")
+            else:
+                approx = _approx_scores_batch(csm, gcodes, gmask, mask_c,
+                                              t_cs)
+            top_s, top_i = jax.lax.top_k(approx, keep)
+            cand_p = jnp.take_along_axis(cand_c, top_i, axis=1)
+            mask_p = jnp.isfinite(top_s)
+            if keep < s_out:
+                cand_p = jnp.pad(cand_p, ((0, 0), (0, s_out - keep)))
+                mask_p = jnp.pad(mask_p, ((0, 0), (0, s_out - keep)))
+            return cand_p, mask_p
 
-    return jax.lax.cond(host_c > ndocs, pruned, unpruned, cand_c, mask_c)
+        return jax.lax.cond(host_c > ndocs, pruned, unpruned, cand_c,
+                            mask_c)
 
 
 def plaid_candidates(index: PLAIDIndex, qs: np.ndarray,
@@ -535,37 +548,57 @@ def plaid_candidates(index: PLAIDIndex, qs: np.ndarray,
         return np.zeros((Nq, 1), np.int64), np.zeros((Nq, 1), bool)
     use_device, geom = device_probe_plan(index, qs.shape[1], nprobe,
                                          ndocs, probe_kernel)
+    args = ({"path": "device"} if use_device
+            else {"path": "host", "fallback": geom})
+    with obs.span(obs.PLAID_CANDIDATES, **args) as sp:
+        out, h2d, d2h = _candidates(index, qs, use_device, geom, nprobe,
+                                    t_cs, ndocs, live, q_mask)
+        sp.set_metadata(h2d_bytes=h2d, d2h_bytes=d2h)
+    return out
+
+
+def _candidates(index: PLAIDIndex, qs: np.ndarray, use_device: bool, geom,
+                nprobe: int, t_cs: float, ndocs: int, live, q_mask):
+    """``plaid_candidates`` past its plan: ((cand, mask), bytes copied to
+    the device, bytes copied back)."""
+    Nq = len(qs)
+    centroids = index.codec.centroids
+    h2d = obs.host_nbytes(qs, centroids)
     cs = _centroid_scores_batch(jnp.asarray(qs, jnp.float32),
-                                jnp.asarray(index.codec.centroids))
+                                jnp.asarray(centroids))
     if use_device:
         div, k, c_score, s_out = geom
         qm = (jnp.ones((Nq, qs.shape[1]), bool) if q_mask is None
-              else jnp.asarray(np.asarray(q_mask, bool)))
+              else np.asarray(q_mask, bool))
         live_dev = (jnp.ones(index.n_docs, bool) if live is None
                     else (live if isinstance(live, jax.Array)
-                          else jnp.asarray(np.asarray(live, bool))))
+                          else np.asarray(live, bool)))
+        h2d += obs.host_nbytes(qm, live_dev, qs, centroids)
         codes, tok_mask = index.padded_codes()
         return _device_candidates(
-            cs, jnp.asarray(qs), qm, div.doc_member,
-            live_dev, codes, tok_mask, jnp.asarray(index.codec.centroids),
+            cs, jnp.asarray(qs), jnp.asarray(qm), div.doc_member,
+            jnp.asarray(live_dev), codes, tok_mask, jnp.asarray(centroids),
             k=k, t_cs=float(t_cs), ndocs=int(ndocs), c_score=c_score,
-            s_out=s_out, impl="kernel" if _on_tpu() else "ref")
+            s_out=s_out, impl="kernel" if _on_tpu() else "ref"), h2d, 0
     if q_mask is not None:
         # masked tokens: -inf centroid scores are pruned to 0 in stage 3,
         # and their (degenerate) probe picks are dropped before the
         # gather — top_k over an all--inf row would otherwise walk
         # centroids 0..nprobe-1's lists into the candidate set
+        h2d += obs.host_nbytes(q_mask)
         cs = jnp.where(jnp.asarray(q_mask, bool)[:, :, None], cs, -jnp.inf)
     k = min(nprobe, index.codec.n_centroids)
     _, probe = jax.lax.top_k(cs, k)                    # [Nq, Lq, nprobe]
+    probe = np.asarray(probe)
+    d2h = probe.nbytes
     probe_valid = (None if q_mask is None else np.broadcast_to(
         np.asarray(q_mask, bool)[:, :, None], (Nq, qs.shape[1], k)))
-    cand, cmask = _gather_candidates(index, np.asarray(probe), live,
-                                     probe_valid)
+    cand, cmask = _gather_candidates(index, probe, live, probe_valid)
     if cand.shape[1] <= ndocs:
-        return cand, cmask
+        return (cand, cmask), h2d, d2h
     codes, tok_mask = index.padded_codes()
     idx = jnp.asarray(cand)
+    h2d += cand.nbytes + 2 * cmask.nbytes
     approx = _approx_scores_batch(
         cs, jnp.take(codes, idx, axis=0),
         jnp.take(tok_mask, idx, axis=0) & jnp.asarray(cmask)[:, :, None],
@@ -575,11 +608,12 @@ def plaid_candidates(index: PLAIDIndex, qs: np.ndarray,
     top_i = np.asarray(top_i)
     cand = np.take_along_axis(cand, top_i, axis=1)
     cmask = np.asarray(jnp.isfinite(top_s))
+    d2h += top_i.nbytes + cmask.nbytes
     S = _pad_up(keep, _CAND_BLOCK)             # block-pad for jit reuse
     if S > keep:
         cand = np.pad(cand, ((0, 0), (0, S - keep)))
         cmask = np.pad(cmask, ((0, 0), (0, S - keep)))
-    return cand, cmask
+    return (cand, cmask), h2d, d2h
 
 
 def _decode_rows(codec: ResidualCodec, ids, words):
@@ -604,29 +638,40 @@ def maxsim_packed_rerank_store(index: PLAIDIndex, q, q_mask, cand,
     fed to the same ``maxsim_rerank`` dispatcher, making the scores
     bitwise-equal to the reconstruction path.
     cand/cand_mask: [Nq, C] host arrays -> scores [Nq, C] (-inf invalid).
+    ``q`` and ``q_mask`` may be host arrays; ``q_mask=None`` means every
+    query token scores.
     """
-    codec = index.codec
-    ids, words, tmask = index.padded_packed()
-    q = jnp.asarray(q, jnp.float32)
-    if not isinstance(cand, jax.Array):
-        cand = np.asarray(cand, np.int64)
-        cand_mask = np.asarray(cand_mask)
-    parts = []
-    for lo in range(0, cand.shape[1], slab):
-        c = jnp.asarray(cand[:, lo:lo + slab])
-        cm = jnp.asarray(cand_mask[:, lo:lo + slab])
-        aw = jnp.take(ids, c, axis=0)                  # [Nq, S, Ld]
-        ww = jnp.take(words, c, axis=0)                # [Nq, S, Ld, W]
-        dm = jnp.take(tmask, c, axis=0) & cm[:, :, None]
-        if _on_tpu():
-            from repro.kernels.maxsim_packed.ops import maxsim_packed_rerank
-            s = maxsim_packed_rerank(q, q_mask, ww, aw, dm,
-                                     codec.centroids, codec.values,
-                                     bits=codec.bits)
-        else:
-            s = maxsim_rerank(q, q_mask, _decode_rows(codec, aw, ww), dm)
-        parts.append(jnp.where(cm, s, -jnp.inf))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    with obs.span(obs.PLAID_RERANK) as sp:
+        codec = index.codec
+        ids, words, tmask = index.padded_packed()
+        h2d = obs.host_nbytes(q, q_mask)
+        q = jnp.asarray(q, jnp.float32)
+        q_mask = (jnp.ones(q.shape[:2], bool) if q_mask is None
+                  else jnp.asarray(q_mask))
+        if not isinstance(cand, jax.Array):
+            cand = np.asarray(cand, np.int64)
+            cand_mask = np.asarray(cand_mask)
+        parts = []
+        for lo in range(0, cand.shape[1], slab):
+            c, cm = cand[:, lo:lo + slab], cand_mask[:, lo:lo + slab]
+            h2d += obs.host_nbytes(c, cm)
+            c, cm = jnp.asarray(c), jnp.asarray(cm)
+            aw = jnp.take(ids, c, axis=0)                  # [Nq, S, Ld]
+            ww = jnp.take(words, c, axis=0)                # [Nq, S, Ld, W]
+            dm = jnp.take(tmask, c, axis=0) & cm[:, :, None]
+            if _on_tpu():
+                from repro.kernels.maxsim_packed.ops import (
+                    maxsim_packed_rerank)
+                s = maxsim_packed_rerank(q, q_mask, ww, aw, dm,
+                                         codec.centroids, codec.values,
+                                         bits=codec.bits)
+            else:
+                s = maxsim_rerank(q, q_mask, _decode_rows(codec, aw, ww),
+                                  dm)
+            parts.append(jnp.where(cm, s, -jnp.inf))
+        sp.set_metadata(h2d_bytes=h2d)
+        return (parts[0] if len(parts) == 1
+                else jnp.concatenate(parts, axis=1))
 
 
 def plaid_search_batch(index: PLAIDIndex, qs: np.ndarray, k: int = 10,

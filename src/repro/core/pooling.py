@@ -172,18 +172,21 @@ def compact_pooled_begin(pooled, pooled_mask):
 
 def compact_pooled_finish(ticket):
     """Materialize a :func:`compact_pooled_begin` ticket on the host:
-    only ``sum(counts)`` rows + the [B] counts vector cross."""
+    only ``sum(counts)`` rows + the [B] counts vector cross. Returns
+    (per-doc arrays, bytes moved device->host) — the one byte figure
+    both the caller's span and ``compaction_transfer_stats`` take."""
     import numpy as np
     flat, counts_dev, shape, dtype = ticket
     counts = np.asarray(counts_dev)
     total = int(counts.sum())
     host = np.asarray(flat[:total])               # the only row transfer
     B, N, d = shape
+    moved = host.nbytes + counts.nbytes
     _TRANSFER_STATS["padded_bytes"] += (
         B * N * d * np.dtype(dtype).itemsize)
-    _TRANSFER_STATS["compact_bytes"] += host.nbytes + counts.nbytes
+    _TRANSFER_STATS["compact_bytes"] += moved
     _TRANSFER_STATS["batches"] += 1
-    return np.split(host, np.cumsum(counts[:-1]))
+    return np.split(host, np.cumsum(counts[:-1])), moved
 
 
 def compact_pooled(pooled, pooled_mask):
@@ -202,7 +205,7 @@ def compact_pooled(pooled, pooled_mask):
     if isinstance(pooled, jax.Array) and isinstance(pooled_mask,
                                                     jax.Array):
         return compact_pooled_finish(
-            compact_pooled_begin(pooled, pooled_mask))
+            compact_pooled_begin(pooled, pooled_mask))[0]
     pooled = np.asarray(pooled)
     pooled_mask = np.asarray(pooled_mask).astype(bool)
     counts = pooled_mask.sum(axis=1)

@@ -90,13 +90,17 @@ def _codes_per_word(bits):
 
 @functools.partial(jax.jit, static_argnames=("bits",))
 def pack_codes(codes, bits: int):
-    M, dim = codes.shape
-    cpw = _codes_per_word(bits)
-    assert dim % cpw == 0, (dim, cpw)
-    c = codes.reshape(M, dim // cpw, cpw).astype(jnp.uint32)
-    shifts = (jnp.arange(cpw, dtype=jnp.uint32) * bits)
-    words = jnp.sum(c << shifts[None, None, :], axis=-1)
-    return words.astype(jnp.uint32)
+    # the codec's one jitted step: its ops carry the scope ``codec`` in
+    # the device trace (a scope labels only ops traced inside a jit, so
+    # ``encode``'s eager steps carry none)
+    with jax.named_scope("codec"):
+        M, dim = codes.shape
+        cpw = _codes_per_word(bits)
+        assert dim % cpw == 0, (dim, cpw)
+        c = codes.reshape(M, dim // cpw, cpw).astype(jnp.uint32)
+        shifts = (jnp.arange(cpw, dtype=jnp.uint32) * bits)
+        words = jnp.sum(c << shifts[None, None, :], axis=-1)
+        return words.astype(jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "dim"))

@@ -22,19 +22,21 @@ def maxsim_packed_rerank(q, q_mask, words, ids, d_mask, centroids, values,
     words [Nq, S, Ld, W] packed residual words; ids [Nq, S, Ld] centroid
     ids; d_mask [Nq, S, Ld] token validity — the per-query gathers of the
     plaid packed views; centroids [K, dim] / values [dim, 2^bits] are the
-    codec tables. Query i scores only its own slab words[i]."""
+    codec tables. Query i scores only its own slab words[i]. Its ops
+    carry the scope ``rerank`` in the device trace."""
     S = words.shape[1]
     n = pad_slots(S, block_s)
 
     def pad(x):
         return _pad_axis_to(x, 1, n)
 
-    words_t = jnp.swapaxes(jax.lax.bitcast_convert_type(
-        pad(words.astype(jnp.uint32)), jnp.int32), 2, 3)
-    out = maxsim_packed_rerank_pallas(
-        jnp.asarray(q, jnp.float32), _q_mask_col(q_mask), words_t,
-        pad(ids.astype(jnp.int32)), pad(d_mask).astype(jnp.int32),
-        jnp.asarray(centroids, jnp.float32).T,
-        jnp.asarray(values, jnp.float32),
-        bits=bits, block_s=block_s, interpret=not _on_tpu())
-    return out[:, 0, :S]
+    with jax.named_scope("rerank"):
+        words_t = jnp.swapaxes(jax.lax.bitcast_convert_type(
+            pad(words.astype(jnp.uint32)), jnp.int32), 2, 3)
+        out = maxsim_packed_rerank_pallas(
+            jnp.asarray(q, jnp.float32), _q_mask_col(q_mask), words_t,
+            pad(ids.astype(jnp.int32)), pad(d_mask).astype(jnp.int32),
+            jnp.asarray(centroids, jnp.float32).T,
+            jnp.asarray(values, jnp.float32),
+            bits=bits, block_s=block_s, interpret=not _on_tpu())
+        return out[:, 0, :S]

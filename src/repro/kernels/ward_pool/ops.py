@@ -60,6 +60,7 @@ def ward_assign(x, mask, factor: int, *, impl: str = "auto",
     token's id is its cluster's representative (lowest) token index —
     the exact contract of ``ward_cluster_batch``.
     """
-    if resolve_impl(impl) == "ref":
-        return ward_assign_ref(x, mask, factor)
-    return _ward_assign_kernel(x, mask, int(factor), block_b)
+    with jax.named_scope("ward"):       # op metadata only
+        if resolve_impl(impl) == "ref":
+            return ward_assign_ref(x, mask, factor)
+        return _ward_assign_kernel(x, mask, int(factor), block_b)

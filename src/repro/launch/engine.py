@@ -53,6 +53,8 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
+
 logger = logging.getLogger(__name__)
 
 
@@ -274,19 +276,23 @@ class EngineStats:
         self.batch_sizes: deque = deque(maxlen=self.WINDOW)
         self.bucket_sizes: deque = deque(maxlen=self.WINDOW)
         self.queue_wait_s: deque = deque(maxlen=self.WINDOW)
+        # per batch: from staging an encoded batch to a search lane
+        # taking it (0 when the batcher serves inline)
+        self.staged_wait_s: deque = deque(maxlen=self.WINDOW)
         self.swaps = 0
         self.generations_seen: deque = deque(maxlen=self.WINDOW)
         self.replica_batches: dict = {}     # lane id -> batches served
 
     def record_batch(self, n_real: int, bucket: int, reason: str,
                      waits: List[float], generation: int,
-                     replica: int = 0) -> None:
+                     replica: int = 0, staged_wait: float = 0.0) -> None:
         with self._lock:
             self.batches += 1
             self.flush_reasons[reason] += 1
             self.batch_sizes.append(n_real)
             self.bucket_sizes.append(bucket)
             self.queue_wait_s.extend(waits)
+            self.staged_wait_s.append(staged_wait)
             self.served += n_real
             self.generations_seen.append(generation)
             self.replica_batches[replica] = (
@@ -303,6 +309,7 @@ class EngineStats:
     def snapshot(self) -> dict:
         with self._lock:
             waits = np.asarray(self.queue_wait_s, np.float64)
+            staged = np.asarray(self.staged_wait_s, np.float64)
             return {
                 "submitted": self.submitted,
                 "served": self.served,
@@ -317,6 +324,12 @@ class EngineStats:
                                       if waits.size else 0.0),
                 "queue_wait_p99_ms": (float(np.percentile(waits, 99) * 1e3)
                                       if waits.size else 0.0),
+                "staged_wait_p50_ms": (
+                    float(np.percentile(staged, 50) * 1e3)
+                    if staged.size else 0.0),
+                "staged_wait_p99_ms": (
+                    float(np.percentile(staged, 99) * 1e3)
+                    if staged.size else 0.0),
                 "swaps": self.swaps,
                 "generations_seen": list(self.generations_seen),
                 "replica_batches": dict(self.replica_batches),
@@ -415,6 +428,7 @@ class ServingEngine:
         self._stop = False
         self._abandon = False
         self._pending = 0       # batches popped but not yet resolved
+        self._batch_no = 0      # the next microbatch's number (spans)
         self._threads: List[threading.Thread] = []
         self._started = False
 
@@ -717,24 +731,31 @@ class ServingEngine:
             batch, kk, reason = popped
             if not batch:
                 continue
+            seq, self._batch_no = self._batch_no, self._batch_no + 1
             try:
-                toks = np.concatenate(
-                    [sl.future._tokens[sl.lo:sl.lo + sl.n] for sl in batch])
-                t_dequeue = time.perf_counter()
-                waits = [t_dequeue - sl.enqueue_t for sl in batch]
-                enc = self.searcher.encode_queries(toks)
-                n = len(enc)
-                bucket = bucket_for(n, self.buckets)
-                if bucket > n:
-                    # pad up to the warm shape by REPEATING the last
-                    # real row: stage 1 candidate generation then does
-                    # normal work for the pad rows (an all-zero query
-                    # can blow up threshold-based probing), and row
-                    # independence keeps the real rows bit-identical
-                    enc = np.concatenate(
-                        [enc, np.broadcast_to(enc[-1:],
-                                              (bucket - n,) + enc.shape[1:])])
-                staged = (enc, n, kk, batch, reason, waits)
+                with obs.span(obs.ENGINE_ENCODE, batch=seq,
+                              reason=reason) as sp:
+                    toks = np.concatenate(
+                        [sl.future._tokens[sl.lo:sl.lo + sl.n]
+                         for sl in batch])
+                    t_dequeue = time.perf_counter()
+                    waits = [t_dequeue - sl.enqueue_t for sl in batch]
+                    enc = self.searcher.encode_queries(toks)
+                    n = len(enc)
+                    bucket = bucket_for(n, self.buckets)
+                    sp.set_metadata(n=n, bucket=bucket)
+                    if bucket > n:
+                        # pad up to the warm shape by REPEATING the last
+                        # real row: stage 1 candidate generation then
+                        # does normal work for the pad rows (an all-zero
+                        # query can blow up threshold-based probing), and
+                        # row independence keeps the real rows
+                        # bit-identical
+                        enc = np.concatenate(
+                            [enc, np.broadcast_to(
+                                enc[-1:], (bucket - n,) + enc.shape[1:])])
+                staged = (enc, n, kk, batch, reason, waits, seq,
+                          time.perf_counter())
             except BaseException as e:      # noqa: BLE001
                 for sl in batch:
                     sl.future._fail(e)
@@ -755,31 +776,38 @@ class ServingEngine:
         batcher at pipeline depth 1). ``replica`` picks the lane a
         routed index serves this batch on — every lane is bitwise
         identical, so routing is purely a throughput decision."""
-        enc, n, kk, batch, reason, waits = staged
+        enc, n, kk, batch, reason, waits, seq, t_staged = staged
+        # from staging to this lane taking the batch (inline: ~0)
+        staged_wait = time.perf_counter() - t_staged
         try:
-            with self._handle_lock:
-                handle = self._handle
-                index = handle.acquire()
-            try:
-                search_on = getattr(index, "search_batch_on", None)
-                if search_on is not None:
-                    S, I = search_on(replica, enc, k=kk)
-                else:
-                    S, I = index.search_batch(enc, k=kk)
-            except BaseException as e:      # noqa: BLE001
+            with obs.span(obs.ENGINE_SEARCH, batch=seq, replica=replica,
+                          staged_wait_us=int(staged_wait * 1e6)):
+                with self._handle_lock:
+                    handle = self._handle
+                    index = handle.acquire()
+                try:
+                    search_on = getattr(index, "search_batch_on", None)
+                    if search_on is not None:
+                        S, I = search_on(replica, enc, k=kk)
+                    else:
+                        S, I = index.search_batch(enc, k=kk)
+                except BaseException as e:      # noqa: BLE001
+                    for sl in batch:
+                        sl.future._fail(e)
+                    self.stats.record_failed(sum(sl.n for sl in batch))
+                    return
+                finally:
+                    handle.release()
+            with obs.span(obs.ENGINE_RESOLVE, batch=seq):
+                S, I = np.asarray(S)[:n], np.asarray(I)[:n]
+                lo = 0
                 for sl in batch:
-                    sl.future._fail(e)
-                self.stats.record_failed(sum(sl.n for sl in batch))
-                return
-            finally:
-                handle.release()
-            S, I = np.asarray(S)[:n], np.asarray(I)[:n]
-            lo = 0
-            for sl in batch:
-                sl.future._fill(sl.lo, S[lo:lo + sl.n], I[lo:lo + sl.n])
-                lo += sl.n
-            self.stats.record_batch(n, len(enc), reason, waits,
-                                    handle.generation, replica=replica)
+                    sl.future._fill(sl.lo, S[lo:lo + sl.n],
+                                    I[lo:lo + sl.n])
+                    lo += sl.n
+                self.stats.record_batch(n, len(enc), reason, waits,
+                                        handle.generation, replica=replica,
+                                        staged_wait=staged_wait)
         finally:
             self._batch_done()
 

@@ -44,12 +44,16 @@ def init_colbert(key, cfg):
 
 
 def _encode(params, tokens, cfg, pad_mask):
-    """tokens [B, L] -> unit vectors [B, L, proj_dim]."""
-    hidden, _ = forward(params["trunk"], tokens, cfg.trunk,
-                        pad_mask=pad_mask)
-    v = dense(params["proj"], hidden).astype(jnp.float32)
-    v = v / jnp.maximum(jnp.linalg.norm(v, axis=-1, keepdims=True), 1e-9)
-    return constrain(v, "batch", "seq", None)
+    """tokens [B, L] -> unit vectors [B, L, proj_dim]. Its ops carry the
+    scope ``encoder`` (``encoder/attention``, ``encoder/mlp`` per layer)
+    in their metadata, for the device trace."""
+    with jax.named_scope("encoder"):
+        hidden, _ = forward(params["trunk"], tokens, cfg.trunk,
+                            pad_mask=pad_mask)
+        v = dense(params["proj"], hidden).astype(jnp.float32)
+        v = v / jnp.maximum(jnp.linalg.norm(v, axis=-1, keepdims=True),
+                            1e-9)
+        return constrain(v, "batch", "seq", None)
 
 
 def prepare_query_tokens(tokens, query_maxlen: int):

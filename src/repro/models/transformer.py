@@ -77,16 +77,19 @@ def init_transformer(key, cfg):
 # Blocks
 # ---------------------------------------------------------------------------
 def _block(x, lp, cfg, *, is_moe, moe_impl, positions, pad_mask):
-    h = norm(cfg.norm, lp["attn_norm"], x, cfg.norm_eps)
-    h = attention_forward(lp["attn"], h, cfg, positions=positions,
-                          pad_mask=pad_mask)
-    x = x + h
-    h = norm(cfg.norm, lp["mlp_norm"], x, cfg.norm_eps)
-    if is_moe:
-        h, aux = moe_apply(lp["moe"], h, cfg, impl=moe_impl)
-    else:
-        h = mlp(lp["mlp"], h, cfg.act, cfg.gated_mlp)
-        aux = jnp.zeros((), jnp.float32)
+    # named scopes only label the ops' metadata (device trace)
+    with jax.named_scope("attention"):
+        h = norm(cfg.norm, lp["attn_norm"], x, cfg.norm_eps)
+        h = attention_forward(lp["attn"], h, cfg, positions=positions,
+                              pad_mask=pad_mask)
+        x = x + h
+    with jax.named_scope("mlp"):
+        h = norm(cfg.norm, lp["mlp_norm"], x, cfg.norm_eps)
+        if is_moe:
+            h, aux = moe_apply(lp["moe"], h, cfg, impl=moe_impl)
+        else:
+            h = mlp(lp["mlp"], h, cfg.act, cfg.gated_mlp)
+            aux = jnp.zeros((), jnp.float32)
     # layer-boundary resharding point: under sequence parallelism
     # ("seq" -> model) the residual stream lives seq-sharded between
     # layers and XLA all-gathers/reduce-scatters around attn+mlp.

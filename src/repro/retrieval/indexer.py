@@ -17,12 +17,22 @@ re-opened mmap'd, so the finished ``ShardedIndex`` holds file mappings,
 not buffers.
 
 Flushing is PIPELINED by default: a single background thread runs the
-host-side shard construction + save + mmap-reopen while the device
-encodes the next batches, double-buffered through a depth-1 queue so
-encode is never idle behind shard I/O (``IndexStats.flush_wait_s`` is
-the realized stall; ``pipeline=False`` pins the serial path, which the
-bench's parity gate builds against — shard order, doc ids and artifact
-bytes are identical either way).
+host-side shard construction + save + mmap-reopen while the build
+thread encodes the next batches, double-buffered through a depth-1
+queue, so the encode loop waits on shard I/O only when a flush backlog
+fills the queue (``IndexStats.flush_wait_s`` is that wait;
+``pipeline=False`` pins the serial path, which the bench's parity gate
+builds against — shard order, doc ids and artifact bytes are identical
+either way). The flush thread shares the process, and Python's
+interpreter lock, with the encode loop, so its host work still delays
+the loop's dispatches; and the loop hands the device one encode batch
+at a time (see ``encode_and_pool_counted``), so the device idles while
+the host fetches each batch's pooled rows.
+
+Each stage runs under a host span (``repro.obs``): the wait for the
+next token batch, encode dispatch, pooling dispatch, the fetch of
+pooled rows, the wait to hand a shard over, and on the flush thread
+the shard's index build, save and reopen.
 
 Data-parallel posture: document batches are independent, so under pjit the
 encode+pool step shards on the ``data`` axis; the index build consumes the
@@ -46,6 +56,7 @@ import numpy as np
 
 import jax
 
+from repro import obs
 from repro.configs.base import ColbertConfig
 from repro.core.index import BACKENDS, MultiVectorIndex
 from repro.core.pooling import (compact_pooled, compact_pooled_begin,
@@ -56,6 +67,7 @@ from repro.models.colbert import encode_docs
 # tiny jit'd reduction: the eager astype+sum pair costs ~2ms of op-by-op
 # dispatch per batch on CPU, which serializes the encode stream
 _emit_count = jax.jit(lambda emit: jnp.sum(emit.astype(jnp.int32)))
+_END = object()     # end of a token-batch stream
 
 
 class EncodedDocs:
@@ -181,6 +193,7 @@ class Indexer:
         self.pool_factor = pooling_spec.factor
         self.backend = index_spec.backend
         self.encode_batch = encode_batch
+        self.batches_encoded = 0    # the next encode batch's number
 
     def _index_kw(self) -> dict:
         """Index construction knobs — ``IndexSpec.params()``, ONE
@@ -194,12 +207,13 @@ class Indexer:
         return self.encode_and_pool_counted(doc_tokens)[0]
 
     def _encoded_batches(self, doc_tokens):
-        """Yield (vectors [B,N,d], emit [B,N], n_real_docs) per encode
-        batch — from the encoder, or straight from an
+        """Yield (batch number, vectors [B,N,d], emit [B,N], n_real_docs)
+        per encode batch — from the encoder, or straight from an
         :class:`EncodedDocs` cache (same boundaries, same padding, so
         downstream pooling sees identical inputs either way)."""
         if isinstance(doc_tokens, EncodedDocs):
-            yield from doc_tokens.batches
+            for v, emit, n_real in doc_tokens.batches:
+                yield self._next_batch(), v, emit, n_real
             return
         N, B = doc_tokens.shape[0], self.encode_batch
         for lo in range(0, N, B):
@@ -207,8 +221,17 @@ class Indexer:
             pad = B - chunk.shape[0]
             if pad:
                 chunk = np.pad(chunk, ((0, pad), (0, 0)))
-            v, emit = encode_docs(self.params, jnp.asarray(chunk), self.cfg)
-            yield v, emit, B - pad
+            b = self._next_batch()
+            with obs.span(obs.INDEXER_ENCODE, batch=b, docs=B - pad,
+                          h2d_bytes=obs.host_nbytes(chunk)):
+                v, emit = encode_docs(self.params, jnp.asarray(chunk),
+                                      self.cfg)
+            yield b, v, emit, B - pad
+
+    def _next_batch(self) -> int:
+        b = self.batches_encoded
+        self.batches_encoded += 1
+        return b
 
     def encode_and_pool_counted(
             self, doc_tokens
@@ -220,42 +243,54 @@ class Indexer:
         :class:`EncodedDocs` input skips the encoder entirely and pools
         the cached batches (bitwise-identical output).
 
-        Runs a 1-deep software pipeline: batch i+1's encode+pool+compact
-        is DISPATCHED before batch i's compacted rows are pulled to the
-        host, so the host-side fetch/split overlaps the next batch's
-        device compute (dispatch is async; only the fetch blocks). Raw
-        counts stay device-resident scalars until the end for the same
-        reason. Output order and bits are unaffected — batches are
-        fetched strictly in order.
+        Within one call it runs a 1-deep software pipeline: batch i+1's
+        encode+pool+compact is DISPATCHED before batch i's compacted
+        rows are pulled to the host, so the host-side fetch/split
+        overlaps the next batch's device compute (dispatch is async;
+        only the fetch blocks). Raw counts stay device-resident scalars
+        until the end for the same reason. Output order and bits are
+        unaffected — batches are fetched strictly in order. A call that
+        holds one encode batch (``build_streaming`` makes one call per
+        batch) has nothing to overlap: its fetch and the raw-count sync
+        wait for the batch's whole device work, and the device idles
+        until the caller dispatches the next batch.
         """
         out: List[np.ndarray] = []
         raw_parts = []      # device scalars; materialized once at the end
-        pending = None      # (compaction ticket | docs list, n real docs)
+        pending = None      # (batch number, compaction ticket | docs, n real)
 
-        def fetch(prev):
-            ticket, keep = prev
-            docs = (ticket if isinstance(ticket, list)
-                    else compact_pooled_finish(ticket))
+        def fetch(prev) -> int:
+            """Append a pending batch's docs to ``out``; returns the
+            bytes fetched from the device."""
+            _, ticket, keep = prev
+            docs, moved = ((ticket, 0) if isinstance(ticket, list)
+                           else compact_pooled_finish(ticket))
             out.extend(docs[:keep] if keep < len(docs) else docs)
+            return moved
 
-        for v, emit, n_real in self._encoded_batches(doc_tokens):
-            pooled, pmask = self.pooling.apply(v, emit)
-            if n_real < emit.shape[0]:
-                # padding rows still emit their CLS/[D] markers — drop
-                # them from the raw count (and their docs below)
-                emit = emit[:n_real]
-            raw_parts.append(_emit_count(emit))
-            if isinstance(pooled, jnp.ndarray):
-                ticket = compact_pooled_begin(pooled, pmask)
-            else:           # host-resident strategy output: no pipeline
-                ticket = compact_pooled(pooled, pmask)
+        for b, v, emit, n_real in self._encoded_batches(doc_tokens):
+            with obs.span(obs.INDEXER_POOL, batch=b):
+                pooled, pmask = self.pooling.apply(v, emit)
+                if n_real < emit.shape[0]:
+                    # padding rows still emit their CLS/[D] markers —
+                    # drop them from the raw count (and their docs below)
+                    emit = emit[:n_real]
+                raw_parts.append(_emit_count(emit))
+                if isinstance(pooled, jnp.ndarray):
+                    ticket = compact_pooled_begin(pooled, pmask)
+                else:       # host-resident strategy output: no pipeline
+                    ticket = compact_pooled(pooled, pmask)
             if pending is not None:
-                fetch(pending)
-            pending = (ticket, n_real)
+                with obs.span(obs.INDEXER_FETCH, batch=pending[0]) as sp:
+                    sp.set_metadata(d2h_bytes=fetch(pending))
+            pending = (b, ticket, n_real)
         if pending is None:
             return out, 0
-        fetch(pending)
-        return out, int(np.sum([np.asarray(r) for r in raw_parts]))
+        with obs.span(obs.INDEXER_FETCH, batch=pending[0]) as sp:
+            moved = fetch(pending)
+            raw = [np.asarray(r) for r in raw_parts]
+            sp.set_metadata(d2h_bytes=moved + obs.host_nbytes(*raw))
+        return out, int(np.sum(raw))
 
     def build(self, doc_tokens: np.ndarray,
               out_dir: Optional[str] = None):
@@ -355,18 +390,24 @@ class Indexer:
         max_batch = 0
         flush_wait_s = 0.0
         flush_busy_s = 0.0
+        submitted = 0       # shard groups handed over so far
 
         def flush(docs_group: List[np.ndarray]) -> None:
             nonlocal flush_busy_s
             t0 = time.perf_counter()
-            shard = sharded._new_shard()
-            shard.add(docs_group)
-            if out_dir is not None:
-                # bytes leave the host: save, drop, reopen memory-mapped
-                sub = os.path.join(out_dir,
-                                   _shard_dirname(sharded.n_shards - 1))
-                shard.save(sub)
-                sharded.shards[-1] = MultiVectorIndex.load(sub, mmap=True)
+            n = sharded.n_shards
+            with obs.span(obs.INDEXER_SHARD, shard=n, docs=len(docs_group),
+                          vectors=sum(len(d) for d in docs_group)):
+                shard = sharded._new_shard()
+                shard.add(docs_group)
+                if out_dir is not None:
+                    # bytes leave the host: save, drop, reopen mmap'd
+                    sub = os.path.join(out_dir, _shard_dirname(n))
+                    with obs.span(obs.INDEXER_SHARD_SAVE, shard=n):
+                        shard.save(sub)
+                    with obs.span(obs.INDEXER_SHARD_REOPEN, shard=n):
+                        sharded.shards[-1] = MultiVectorIndex.load(
+                            sub, mmap=True)
             flush_busy_s += time.perf_counter() - t0
 
         # -- single background flush lane (only this thread ever touches
@@ -392,18 +433,29 @@ class Indexer:
             worker.start()
 
         def submit(docs_group: List[np.ndarray]) -> None:
-            nonlocal flush_wait_s
+            nonlocal flush_wait_s, submitted
             if failures:
                 raise failures[0]
+            shard, submitted = submitted, submitted + 1
             if worker is None:
                 flush(docs_group)
                 return
-            t0 = time.perf_counter()
-            handoff.put(docs_group)   # blocks only when a flush backlog
-            flush_wait_s += time.perf_counter() - t0
+            with obs.span(obs.INDEXER_FLUSH_WAIT, shard=shard,
+                          batch=self.batches_encoded - 1) as sp:
+                t0 = time.perf_counter()
+                handoff.put(docs_group)   # blocks only on a flush backlog
+                waited = time.perf_counter() - t0
+                sp.set_metadata(wait_us=int(waited * 1e6))
+            flush_wait_s += waited
 
+        batches = iter(token_batches)
         try:
-            for batch in token_batches:
+            while True:
+                with obs.span(obs.INDEXER_INPUT,
+                              batch=self.batches_encoded):
+                    batch = next(batches, _END)
+                if batch is _END:
+                    break
                 batch = np.asarray(batch)
                 if batch.size == 0:
                     continue

@@ -30,6 +30,7 @@ from typing import Iterable, List, Tuple, Union
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ColbertConfig
 from repro.core.index import MultiVectorIndex
 from repro.models.colbert import encode_queries
@@ -77,15 +78,21 @@ class Searcher:
         out = []
         N = query_tokens.shape[0]
         B = self.encode_batch
-        for lo in range(0, N, B):
-            chunk = query_tokens[lo:lo + B]
-            n = chunk.shape[0]
-            pad = self._encode_width(n) - n
-            if pad:
-                chunk = np.pad(chunk, ((0, pad), (0, 0)))
-            v, _ = encode_queries(self.params, jnp.asarray(chunk), self.cfg)
-            v = np.asarray(v)
-            out.append(v[:n] if pad else v)
+        h2d = d2h = 0
+        with obs.span(obs.ENCODER_QUERIES) as sp:
+            for lo in range(0, N, B):
+                chunk = query_tokens[lo:lo + B]
+                n = chunk.shape[0]
+                pad = self._encode_width(n) - n
+                if pad:
+                    chunk = np.pad(chunk, ((0, pad), (0, 0)))
+                h2d += obs.host_nbytes(chunk)
+                v, _ = encode_queries(self.params, jnp.asarray(chunk),
+                                      self.cfg)
+                v = np.asarray(v)
+                d2h += v.nbytes
+                out.append(v[:n] if pad else v)
+            sp.set_metadata(h2d_bytes=h2d, d2h_bytes=d2h)
         return np.concatenate(out)
 
     def encode(self, query_tokens: np.ndarray) -> np.ndarray:
