@@ -53,35 +53,28 @@ def run(verbose: bool = True):
 
 
 def run_plaid_probe(rng):
-    """Fused centroid-interaction probe cell (kernels/plaid_probe): jnp
-    reference wall time on CPU + the kernel tile's VMEM working set and
-    arithmetic intensity for the TPU target."""
-    from repro.kernels.plaid_probe.ops import plaid_probe_scores
+    """Doc-major centroid-interaction probe cell (kernels/plaid_probe):
+    jnp reference wall time on CPU + the kernel tile's VMEM working set
+    and arithmetic intensity for the TPU target."""
+    from repro.kernels.plaid_probe.ops import plaid_probe_bag_scores
 
     rows = []
-    for (nq, lq, c, l, k, dim, bc) in [
-            (8, 32, 1024, 64, 4096, 128, 8),
-            (16, 32, 4096, 64, 4096, 128, 8)]:
-        q = jnp.asarray(rng.normal(size=(nq, lq, dim)), jnp.float32)
-        qm = jnp.ones((nq, lq), bool)
-        cents = jnp.asarray(rng.normal(size=(k, dim)), jnp.float32)
-        codes = jnp.asarray(rng.integers(0, k, size=(nq, c, l)), jnp.int32)
-        cm = jnp.ones((nq, c, l), bool)
-        vm = jnp.ones((nq, c), bool)
-        t = _time(lambda *a: plaid_probe_scores(*a, t_cs=0.3, impl="ref"),
-                  q, qm, cents, codes, cm, vm)
-        # per-tile: q + centroid table + cs [lq, k] + one-hot [bc*l, k]
-        vmem = (lq * dim + k * dim + lq * k + bc * l * k + bc * l * lq) * 4
-        flops = 2.0 * nq * (lq * k * dim + c * l * k * lq)
-        ai = flops / (q.nbytes + cents.nbytes + codes.nbytes + nq * c * 4)
-        tpu_roof = flops / hw.PEAK_FLOPS_BF16
-        print(f"plaid_probe q{nq}x{lq} c{c}x{l} K{k}: "
+    for (nq, lq, k, n, bd) in [(8, 32, 256, 4096, 1024),
+                               (32, 32, 256, 8192, 1024)]:
+        cs = rng.normal(size=(nq, lq, k)).astype(np.float32)
+        csp = jnp.asarray(np.where(cs >= 0.45, cs, 0.0))
+        member = jnp.asarray((rng.random((k, n)) < 0.25).astype(np.float32))
+        t = _time(lambda *a: plaid_probe_bag_scores(*a, impl="ref"),
+                  csp, member)
+        # per tile: resident pruned scores + member tile + output tile
+        vmem = (nq * lq * k + k * bd + nq * bd) * 4
+        flops = 2.0 * nq * lq * k * n
+        ai = flops / (csp.nbytes + member.nbytes + nq * n * 4)
+        print(f"plaid_probe q{nq}x{lq} K{k} docs{n}: "
               f"jnp-cpu {t*1e3:7.1f}ms | kernel tile VMEM "
-              f"{vmem/2**20:5.2f}MiB, AI {ai:6.1f} flop/B, "
-              f"v5e compute-roof {tpu_roof*1e6:6.1f}us")
-        rows.append({"kernel": "plaid_probe",
-                     "shape": (nq, lq, c, l, k, dim), "cpu_ms": t * 1e3,
-                     "vmem_mb": vmem / 2**20, "ai": ai})
+              f"{vmem/2**20:5.2f}MiB, AI {ai:6.1f} flop/B")
+        rows.append({"kernel": "plaid_probe", "shape": (nq, lq, k, n),
+                     "cpu_ms": t * 1e3, "vmem_mb": vmem / 2**20, "ai": ai})
     return rows
 
 

@@ -414,7 +414,8 @@ def probe_cell(params, cfg, corpus, pool_factor: int, batch: int,
     from repro.core.plaid import device_probe_plan
     qv = qv_all[:batch]
     engaged, geom = device_probe_plan(index._plaid, qv.shape[1],
-                                      index.nprobe, index.ndocs, "device")
+                                      index.nprobe, index.ndocs, "device",
+                                      t_cs=index.t_cs)
     assert engaged, "device candidate path did not engage on this cell"
     S1, I1 = searcher.search(q_all, k=k)            # warm device traces
     device = timed()

@@ -263,21 +263,18 @@ def paths_engaged(built, Q, checks):
     p = ix._plaid
     Nq, Lq, dim = Q.shape
     use_dev, geom = P.device_probe_plan(p, Lq, ix.nprobe, ix.ndocs,
-                                        ix.probe_kernel)
+                                        ix.probe_kernel, t_cs=ix.t_cs)
     checks("device_probe_plan engages the device candidate path", use_dev)
     if not use_dev:
         return
     div, kk, c_score, s_out = geom
-    codes, tok_mask = p.padded_codes()
     cents = jnp.asarray(p.codec.centroids)
     f32 = jax.ShapeDtypeStruct
     checks("serve step (stages 1-3) holds the plaid_probe kernel",
            has_kernel(P._device_candidates,
                       f32((Nq, Lq, cents.shape[0]), jnp.float32),
-                      f32((Nq, Lq, dim), jnp.float32),
                       f32((Nq, Lq), jnp.bool_),
-                      *shapes(div.doc_member, ix._live_dev(), codes,
-                              tok_mask, cents),
+                      *shapes(div.doc_member, ix._live_dev()),
                       k=kk, t_cs=float(ix.t_cs), ndocs=int(ix.ndocs),
                       c_score=c_score, s_out=s_out, impl="kernel"))
     ids, words, _ = p.padded_packed()

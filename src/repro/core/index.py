@@ -157,7 +157,7 @@ class MultiVectorIndex:
         if self.backend != "plaid" or self._plaid is None:
             return False, None
         return device_probe_plan(self._plaid, Lq, self.nprobe, self.ndocs,
-                                 self.probe_kernel)
+                                 self.probe_kernel, t_cs=self.t_cs)
 
     # ------------------------------------------------------------------ build
     def add(self, doc_vectors: List[np.ndarray]) -> np.ndarray:

@@ -33,13 +33,15 @@ list bookkeeping (2) has two interchangeable implementations — the
 vectorized host-numpy reference, and a fully DEVICE-RESIDENT pipeline
 (``probe_kernel`` toggle) that runs stages 1-3 as ONE fixed-shape jit
 program: padded per-centroid doc-list gather from ``DeviceInvertedLists``,
-sort-based (query, doc) dedupe, and the fused centroid-interaction probe
-(``kernels/plaid_probe``) behind a ``lax.cond`` prune — no ``np.asarray``
-host hop between query encode and the final top-k. The device path is
-engaged only when it is provably bitwise-equal to the host path (exact
-IVF view, dense corpus-wide regime statically unreachable). Fixed shapes
-throughout: candidate sets are padded to a block multiple so stage 3/4
-trace once per (batch size, candidate budget) pair.
+sort-based (query, doc) dedupe, and the centroid-interaction probe
+(``kernels/plaid_probe``: every doc scored doc-major from its centroid
+bag) behind a ``lax.cond`` prune — no ``np.asarray`` host hop between
+query encode and the final top-k. The device path is engaged only when
+it is provably bitwise-equal to the host path (exact IVF view, no
+negative prune threshold, no doc cut by ``doc_maxlen``, dense
+corpus-wide regime statically unreachable). Fixed shapes throughout:
+candidate sets are padded to a block multiple so stage 3/4 trace once
+per (batch size, candidate budget) pair.
 """
 from __future__ import annotations
 
@@ -59,6 +61,7 @@ from repro.core.ivf import (DeviceInvertedLists, InvertedLists,
                             build_inverted_lists)
 from repro.core.maxsim import _on_tpu, maxsim_rerank, topk_with_pads
 from repro.core.quantization import ResidualCodec, decode, encode
+from repro.kernels.plaid_probe.ref import fold_sum
 
 _CAND_BLOCK = 32       # candidate-axis padding granularity (jit shape reuse)
 PROBE_KERNELS = ("auto", "device", "host")
@@ -358,7 +361,8 @@ def _approx_scores_batch(cs, codes, code_mask, cand_mask, t_cs,
         cb, mb = args                                  # [Nq, block, L]
         vals = jax.vmap(lambda t, i: t[i])(csT, cb)    # [Nq, block, L, Lq]
         vals = jnp.where(mb[..., None], vals, 0.0)
-        return carry, vals.max(axis=2).sum(axis=-1)    # [Nq, block]
+        # the query tokens summed in the bag kernel's order
+        return carry, fold_sum(vals.max(axis=2), 2)[..., 0]  # [Nq, block]
 
     _, out = jax.lax.scan(one, 0, (codes_b, mask_b))   # [nb, Nq, block]
     approx = jnp.moveaxis(out, 0, 1).reshape(Nq, C)
@@ -382,15 +386,23 @@ def _floor_ladder(n: int) -> int:
 
 
 def device_probe_plan(index: PLAIDIndex, Lq: int, nprobe: int,
-                      ndocs: int, probe_kernel: str = "auto"):
+                      ndocs: int, probe_kernel: str = "auto", *,
+                      t_cs: float):
     """Static decision + geometry for the device-resident candidate path.
 
     Returns ``(True, (div, k, c_score, s_out))``, or ``(False, reason)``
     where ``reason`` names why the host path serves: ``host_kernel``
-    (pinned), ``empty``, ``overflow``, ``dense`` or ``gather_cap`` (one
-    for each condition below). The device path engages only when it is
-    PROVABLY bitwise-equal to the host path:
+    (pinned), ``empty``, ``negative_t_cs``, ``long_docs``, ``overflow``,
+    ``dense`` or ``gather_cap`` (one for each condition below). The
+    device path engages only when it is PROVABLY bitwise-equal to the
+    host path:
 
+      * its stage 3 scores every doc from its centroid bag
+        (``kernels/plaid_probe``), which equals the host's score over
+        the doc's code row only when pruned scores are >= 0
+        (``t_cs >= 0``: an absent centroid's 0 must never win) and no
+        doc is longer than ``doc_maxlen`` (the code row cuts such a doc,
+        its bag does not);
       * the device IVF view is exact (``overflow == 0``);
       * the dense corpus-wide dispatch is statically unreachable — for
         every possible per-query candidate count, the host path's final
@@ -408,6 +420,10 @@ def device_probe_plan(index: PLAIDIndex, Lq: int, nprobe: int,
         return False, "host_kernel"
     if index.n_vectors == 0 or index.n_docs == 0:
         return False, "empty"
+    if t_cs < 0:
+        return False, "negative_t_cs"
+    if np.diff(index.doc_offsets).max() > index.doc_maxlen:
+        return False, "long_docs"
     div = index.device_ivf()
     if div.overflow != 0:
         return False, "overflow"
@@ -433,8 +449,7 @@ def device_probe_plan(index: PLAIDIndex, Lq: int, nprobe: int,
 
 @functools.partial(jax.jit, static_argnames=("k", "t_cs", "ndocs",
                                              "c_score", "s_out", "impl"))
-def _device_candidates(cs, qs, qm, doc_member, live, codes,
-                       tok_mask, centroids, *, k: int, t_cs: float,
+def _device_candidates(cs, qm, doc_member, live, *, k: int, t_cs: float,
                        ndocs: int, c_score: int, s_out: int, impl: str):
     """Stages 1-3 as one device program — no host round-trip.
 
@@ -454,6 +469,11 @@ def _device_candidates(cs, qs, qm, doc_member, live, codes,
         geometric ladder, then taken as a ``lax.cond`` — both branches
         emit the static width ``s_out``, so one executable serves the
         whole stream (no-retrace contract).
+
+      * score: every doc from its centroid bag (the ``doc_member``
+        rows), by ``kernels/plaid_probe`` (``impl``: its dispatcher's
+        ``"kernel"`` or ``"ref"``); exact where ``device_probe_plan``
+        engages this path.
     """
     Nq = cs.shape[0]
     n_docs = live.shape[0]
@@ -502,20 +522,19 @@ def _device_candidates(cs, qs, qm, doc_member, live, codes,
             return cand_c[:, :s_out], mask_c[:, :s_out]
 
         def pruned(cand_c, mask_c):
-            gcodes = jnp.take(codes, cand_c, axis=0)     # [Nq, C, L]
-            gmask = (jnp.take(tok_mask, cand_c, axis=0)
-                     & mask_c[:, :, None])
-            if impl == "kernel":
-                from repro.kernels.plaid_probe.ops import plaid_probe_scores
-                approx = plaid_probe_scores(qs, qm, centroids, gcodes,
-                                            gmask, mask_c, t_cs=t_cs,
-                                            impl="kernel")
-            else:
-                approx = _approx_scores_batch(csm, gcodes, gmask, mask_c,
-                                              t_cs)
+            from repro.kernels.plaid_probe.ops import plaid_probe_bag_scores
+            csp = jnp.where(csm >= t_cs, csm, 0.0)       # masked -> 0
+            bag = plaid_probe_bag_scores(csp, doc_member,
+                                         impl=impl)      # [Nq, n_docs]
+            # the candidates are the member docs, ascending in slots
+            # 0..count-1, so a top_k over doc ids picks the docs the
+            # slot top_k picks, in its order (ties go to the lower index
+            # either way), with no gather into the slots (keep <= n_docs:
+            # the plan's dense rule)
+            approx = jnp.where(member, bag, -jnp.inf)
             top_s, top_i = jax.lax.top_k(approx, keep)
-            cand_p = jnp.take_along_axis(cand_c, top_i, axis=1)
             mask_p = jnp.isfinite(top_s)
+            cand_p = jnp.where(mask_p, top_i, 0)     # pads read doc 0
             if keep < s_out:
                 cand_p = jnp.pad(cand_p, ((0, 0), (0, s_out - keep)))
                 mask_p = jnp.pad(mask_p, ((0, 0), (0, s_out - keep)))
@@ -547,8 +566,8 @@ def plaid_candidates(index: PLAIDIndex, qs: np.ndarray,
     if index.n_vectors == 0:
         return np.zeros((Nq, 1), np.int64), np.zeros((Nq, 1), bool)
     use_device, geom = device_probe_plan(index, qs.shape[1], nprobe,
-                                         ndocs, probe_kernel)
-    args = ({"path": "device"} if use_device
+                                         ndocs, probe_kernel, t_cs=t_cs)
+    args = ({"path": "device", "scorer": "bag"} if use_device
             else {"path": "host", "fallback": geom})
     with obs.span(obs.PLAID_CANDIDATES, **args) as sp:
         out, h2d, d2h = _candidates(index, qs, use_device, geom, nprobe,
@@ -573,11 +592,9 @@ def _candidates(index: PLAIDIndex, qs: np.ndarray, use_device: bool, geom,
         live_dev = (jnp.ones(index.n_docs, bool) if live is None
                     else (live if isinstance(live, jax.Array)
                           else np.asarray(live, bool)))
-        h2d += obs.host_nbytes(qm, live_dev, qs, centroids)
-        codes, tok_mask = index.padded_codes()
+        h2d += obs.host_nbytes(qm, live_dev)
         return _device_candidates(
-            cs, jnp.asarray(qs), jnp.asarray(qm), div.doc_member,
-            jnp.asarray(live_dev), codes, tok_mask, jnp.asarray(centroids),
+            cs, jnp.asarray(qm), div.doc_member, jnp.asarray(live_dev),
             k=k, t_cs=float(t_cs), ndocs=int(ndocs), c_score=c_score,
             s_out=s_out, impl="kernel" if _on_tpu() else "ref"), h2d, 0
     if q_mask is not None:

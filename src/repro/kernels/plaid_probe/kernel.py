@@ -1,24 +1,25 @@
-"""Fused PLAID centroid-interaction Pallas TPU kernel (stages 1 + 3).
+"""PLAID centroid-interaction Pallas TPU kernel (stage 3), doc-major.
 
-Candidate generation's matmul-shaped stages in one pass: each program
-scores ONE query's tokens against the whole centroid table
-(``q @ centroids^T`` on the MXU) and immediately runs the threshold-
-pruned centroid-only MaxSim over one VMEM tile of its candidate code
-rows — the approximate scores PLAID prunes with, straight from packed
-centroid ids, without ever materializing the host path's
-``[Nq, block, L, Lq]`` gathered-score intermediate in HBM.
+With ``t_cs >= 0`` every pruned centroid score is >= 0 (masked query
+tokens score 0), so a doc's centroid-only score depends only on the SET
+of centroids its tokens use (PLAID's "bag of centroid ids",
+arXiv:2205.09707 §4):
 
-The per-token centroid-score lookup is a one-hot MXU matmul, the same
-gather-free idiom as ``kernels/maxsim_packed``: a candidate's code row
--> [K, L] select plane -> [Lq, L] pruned scores. Every column of the
-select plane has exactly one 1.0 (ids live in [0, K)), so at HIGHEST
-precision the contraction reproduces the reference's ``csT[code]``
-gather exactly. The [dim, K] centroid table stays VMEM-resident across
-the whole grid; per-candidate HBM traffic drops to the code bytes
-(4B/token + mask) — see ``repro.roofline.probe``.
+    score(q, d) = sum_t max_k csp[q, t, k] * member[k, d]
 
-Grid, layout and output tiling are ``kernels/maxsim``'s: one program
-per (query, tile of ``block_c`` candidates).
+An absent centroid contributes 0, which never beats a real score, and
+``x * 1.0`` / ``x * 0.0`` are exact, so the per-token max equals the
+max over the doc's own tokens bit for bit. The query tokens are summed
+in ``fold_sum``'s order, the order the host path's stage 3
+(``core.plaid._approx_scores_batch``) sums in too, so the scores equal
+the host path's exactly (``core.plaid.device_probe_plan`` engages the
+device path only where this holds).
+
+Every doc of the index is scored from the 0/1 ``[K, n_docs]``
+membership table (``DeviceInvertedLists.doc_member``) on the VPU, a
+multiply and a max per element: no MXU pass, no gathered code rows.
+Grid: one program per tile of ``block_d`` docs; the pruned scores of the
+whole batch stay resident.
 """
 from __future__ import annotations
 
@@ -27,66 +28,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.maxsim.kernel import (compiler_params, pad_slots, place,
-                                         slab_out_spec)
-
-
-def _plaid_probe_kernel(q_ref, qm_ref, ct_ref, code_ref, cm_ref, o_ref, *,
-                        t_cs: float):
-    """One query x one tile of its own candidates, scored centroid-only:
-    q [Lq, dim], qm [Lq, 1], codes/cm [block, L] -> lanes of o [1, T]."""
-    # stage 1: all centroid interactions for this query's tokens
-    q = q_ref[...].astype(jnp.float32)                      # [Lq, dim]
-    cs = jax.lax.dot_general(q, ct_ref[...], (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [Lq, K]
-    cs = jnp.where(qm_ref[...] != 0, cs, -jnp.inf)  # masked tokens add 0
-    csp = jnp.where(cs >= t_cs, cs, 0.0)    # t_cs prune (-inf < t_cs)
-    # stage 3: per-token centroid-score lookup as a one-hot MXU matmul,
-    # at HIGHEST so the gathered f32 scores are exact on TPU too
-    codes = code_ref[...]
-    cm = cm_ref[...] != 0
-    K, L = csp.shape[1], codes.shape[1]
-    scores = []
-    for b in range(codes.shape[0]):
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (K, L), 0)
-                  == codes[b:b + 1]).astype(jnp.float32)
-        vals = jax.lax.dot_general(csp, onehot, (((1,), (0,)), ((), ())),
-                                   precision=jax.lax.Precision.HIGHEST,
-                                   preferred_element_type=jnp.float32)
-        vals = jnp.where(cm[b:b + 1], vals, 0.0)            # [Lq, L]
-        scores.append(jnp.sum(jnp.max(vals, axis=1, keepdims=True),
-                              axis=0, keepdims=True))
-    o_ref[...] = place(o_ref[...], scores, pl.program_id(1),
-                       codes.shape[0])
+from repro.kernels.plaid_probe.ref import fold_sum
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("t_cs", "block_c", "interpret"))
-def plaid_probe_pallas(q, q_mask, centroids_t, codes, code_mask, *,
-                       t_cs: float, block_c: int = 8,
-                       interpret: bool = False):
-    """q [Nq, Lq, dim]; q_mask [Nq, Lq, 1] int32; centroids_t [dim, K];
-    codes [Nq, C, L] int32 per-candidate centroid ids; code_mask
-    [Nq, C, L] int32 -> approx scores [Nq, 1, C] f32 (the wrapper masks
-    invalid candidate slots). C == ``pad_slots(C, block_c)``."""
-    Nq, Lq, dim = q.shape
-    _, C, L = codes.shape
-    K = centroids_t.shape[1]
-    assert C == pad_slots(C, block_c), (C, block_c)
-    kernel = functools.partial(_plaid_probe_kernel, t_cs=t_cs)
+def _plaid_probe_bag_kernel(csp_ref, mem_ref, o_ref):
+    """Every query x one tile of docs: csp [Nq, Lq, K] pruned centroid
+    scores (>= 0), member [K, Bd] 0/1 -> o [Nq, Bd]."""
+    nq, lq, n_cent = csp_ref.shape
+    bd = mem_ref.shape[1]
+
+    def one_query(q, carry):
+        cs = csp_ref[q]                                     # [Lq, K]
+        acc = jnp.zeros((lq, bd), jnp.float32)
+        for k in range(n_cent):
+            acc = jnp.maximum(acc, cs[:, k:k + 1] * mem_ref[k:k + 1, :])
+        o_ref[pl.ds(q, 1), :] = fold_sum(acc, 0)
+        return carry
+
+    jax.lax.fori_loop(0, nq, one_query, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
+def plaid_probe_bag_pallas(csp, doc_member, *, block_d: int = 1024,
+                           interpret: bool = False):
+    """csp [Nq, Lq, K] f32 t_cs-pruned centroid scores (>= 0, masked
+    tokens 0); doc_member [K, n_docs] f32 0/1 -> bag scores [Nq, n_docs]
+    f32. One program per tile of ``block_d`` docs (a multiple of 128);
+    the ragged last tile is left to Pallas's boundary handling, since
+    doc columns are independent."""
+    Nq, Lq, K = csp.shape
+    n_docs = doc_member.shape[1]
+    bd = block_d if n_docs > block_d else n_docs
     return pl.pallas_call(
-        kernel,
-        grid=(Nq, C // block_c),
+        _plaid_probe_bag_kernel,
+        grid=(pl.cdiv(n_docs, bd),),
         in_specs=[
-            pl.BlockSpec((None, Lq, dim), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, Lq, 1), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((dim, K), lambda i, j: (0, 0)),
-            pl.BlockSpec((None, block_c, L), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_c, L), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((Nq, Lq, K), lambda j: (0, 0, 0)),
+            pl.BlockSpec((K, bd), lambda j: (0, j)),
         ],
-        out_specs=slab_out_spec(C, block_c),
-        out_shape=jax.ShapeDtypeStruct((Nq, 1, C), jnp.float32),
-        compiler_params=compiler_params(),
+        out_specs=pl.BlockSpec((Nq, bd), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((Nq, n_docs), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(q, q_mask, centroids_t, codes, code_mask)
+        name="plaid_probe_bag_pallas",
+    )(csp, doc_member)

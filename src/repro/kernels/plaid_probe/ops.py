@@ -1,4 +1,4 @@
-"""Dispatcher for the fused centroid-interaction probe op.
+"""Dispatcher for the doc-major centroid-interaction probe op.
 
 ``impl``:
   * ``"auto"``   — Pallas kernel on TPU, jnp reference elsewhere (the
@@ -12,33 +12,20 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.maxsim.kernel import pad_slots
-from repro.kernels.maxsim.ops import _on_tpu, _pad_axis_to, _q_mask_col
-from repro.kernels.plaid_probe.kernel import plaid_probe_pallas
-from repro.kernels.plaid_probe.ref import plaid_probe_ref
+from repro.kernels.maxsim.ops import _on_tpu
+from repro.kernels.plaid_probe.kernel import plaid_probe_bag_pallas
+from repro.kernels.plaid_probe.ref import plaid_probe_bag_ref
 
 PROBE_IMPLS = ("auto", "kernel", "ref")
 
 
-def plaid_probe_scores(q, q_mask, centroids, codes, code_mask, cand_mask,
-                       *, t_cs: float, impl: str = "auto",
-                       block_c: int = 8):
-    """Approx (centroid-only, t_cs-pruned) MaxSim for gathered candidate
-    code rows: q [Nq, Lq, dim]; codes/code_mask [Nq, C, L]; cand_mask
-    [Nq, C] -> scores [Nq, C] f32 (-inf invalid)."""
+def plaid_probe_bag_scores(csp, doc_member, *, impl: str = "auto"):
+    """Bag (doc-major) centroid-only scores of every doc: csp [Nq, Lq, K]
+    t_cs-pruned centroid scores, all >= 0; doc_member [K, n_docs] 0/1
+    -> [Nq, n_docs] f32."""
     assert impl in PROBE_IMPLS, impl
     if impl == "ref" or (impl == "auto" and not _on_tpu()):
-        return plaid_probe_ref(q, q_mask, centroids, codes, code_mask,
-                               cand_mask, t_cs=t_cs)
-    C = codes.shape[1]
-    n = pad_slots(C, block_c)
-
-    def pad(x):
-        return _pad_axis_to(x, 1, n).astype(jnp.int32)
-
-    out = plaid_probe_pallas(
-        jnp.asarray(q, jnp.float32), _q_mask_col(q_mask),
-        jnp.asarray(centroids, jnp.float32).T, pad(codes),
-        pad(code_mask), t_cs=float(t_cs), block_c=block_c,
+        return plaid_probe_bag_ref(csp, doc_member)
+    return plaid_probe_bag_pallas(
+        jnp.asarray(csp, jnp.float32), jnp.asarray(doc_member, jnp.float32),
         interpret=not _on_tpu())
-    return jnp.where(cand_mask, out[:, 0, :C], -jnp.inf)

@@ -16,6 +16,18 @@ FLOPs are analytic (the one-hot matmul inside the Pallas body never
 shows up in XLA cost_analysis of the wrapper); sort cost is modeled as
 the bitonic-network bound XLA lowers ``jnp.sort`` to on accelerator
 backends.
+
+The device row prices the per-slot one-hot stage 3 this module was
+written for. The served program no longer runs it: its stage 3 is the
+doc-major bag kernel (``kernels/plaid_probe``), which scores every doc
+from the 0/1 ``[K, n_docs]`` ``doc_member`` table with a multiply and a
+max per (query token, centroid, doc) on the VPU, reading the table once
+per batch. It needs ``t_cs >= 0`` and no doc longer than
+``doc_maxlen`` (``core.plaid.device_probe_plan`` sends other indexes
+to the host path). At nprobe 2 x 32 query tokens over K=256 nearly
+every doc is a candidate; the ``ndocs`` survivors (256) are what the
+rerank reads. The bag's VPU work is not in this model: its measured
+share is the benchmark's ``plaid_probe_roofline``.
 """
 from __future__ import annotations
 

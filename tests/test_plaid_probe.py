@@ -65,7 +65,8 @@ def assert_candidates_equal(idx, qs, q_mask=None):
     required for its cell.
     """
     use_dev, _ = device_probe_plan(idx._plaid, np.asarray(qs).shape[1],
-                                   idx.nprobe, idx.ndocs, "device")
+                                   idx.nprobe, idx.ndocs, "device",
+                                   t_cs=idx.t_cs)
     idx.probe_kernel = "host"
     c0, m0 = idx.candidates(qs, q_mask=q_mask)
     S0, I0 = idx.search_batch(qs, k=7, q_mask=q_mask)
@@ -158,7 +159,7 @@ def test_fully_masked_token_adds_zero_candidates():
     masked = np.ones((2, 6), bool)
     masked[:, -1] = False
     assert device_probe_plan(idx._plaid, 6, idx.nprobe, idx.ndocs,
-                             "device")[0]
+                             "device", t_cs=idx.t_cs)[0]
     for pk in ("host", "device"):
         idx.probe_kernel = pk
         c_full, m_full = idx.candidates(qs[:, :5], q_mask=None)
@@ -212,7 +213,8 @@ def test_device_ivf_overflow_accounting():
         row = np.asarray(capped.doc_lists[c])[np.asarray(capped.doc_valid[c])]
         np.testing.assert_array_equal(row, want)
     p._device_ivf = capped
-    use_dev, _ = device_probe_plan(p, 5, idx.nprobe, idx.ndocs, "device")
+    use_dev, _ = device_probe_plan(p, 5, idx.nprobe, idx.ndocs, "device",
+                                   t_cs=idx.t_cs)
     assert not use_dev, "overflowed IVF must disqualify the device path"
     p._device_ivf = None
 
@@ -246,7 +248,8 @@ def test_sharded_and_replicated_parity():
     S0, I0 = sh.search_batch(qs, k=8)
     sh.set_probe_kernel("device")
     assert any(device_probe_plan(s._plaid, qs.shape[1], s.nprobe,
-                                 s.ndocs, "device")[0] for s in sh.shards)
+                                 s.ndocs, "device", t_cs=s.t_cs)[0]
+               for s in sh.shards)
     S1, I1 = sh.search_batch(qs, k=8)
     np.testing.assert_array_equal(I0, I1)
     assert np.array_equal(np.asarray(S0, np.float32).view(np.int32),
@@ -297,28 +300,176 @@ def test_no_retrace_through_mixed_shape_stream():
 
 
 # ------------------------------------------------------- kernel vs ref
-def test_probe_kernel_matches_reference():
-    """Pallas fused probe cell (interpret mode on CPU) vs the jnp
-    reference: same -inf prune pattern, scores equal to float tolerance
-    (reduction order differs inside the tile loop)."""
-    from repro.kernels.plaid_probe.ops import plaid_probe_scores
+def pruned_scores(rng, nq, lq, k, t_cs):
+    """Random centroid scores pruned as the candidate program prunes
+    them: masked query tokens -inf, then below ``t_cs`` -> 0."""
+    cs = jnp.asarray(rng.normal(size=(nq, lq, k)) * 0.5, jnp.float32)
+    qm = jnp.asarray(rng.random((nq, lq)) > 0.3).at[0, 0].set(False)
+    csm = jnp.where(qm[:, :, None], cs, -jnp.inf)
+    return jnp.where(csm >= t_cs, csm, 0.0)
+
+
+@pytest.mark.parametrize("t_cs", [0.0, 0.3, 0.9])
+def test_probe_kernel_matches_reference(t_cs):
+    """Pallas bag kernel (interpret mode on CPU) vs its jnp doc-major
+    reference, over ragged doc tiles (300 docs in tiles of 128), masked
+    query tokens and docs with no centroid: bitwise equal (both sum the
+    query tokens in ``fold_sum``'s order), and equal to the plain
+    max-then-sum formula up to that order."""
+    from repro.kernels.plaid_probe.kernel import plaid_probe_bag_pallas
+    from repro.kernels.plaid_probe.ops import plaid_probe_bag_scores
     rng = np.random.default_rng(53)
-    nq, lq, c, l, k = 2, 5, 64, 6, 16      # C block-padded, like stage 3
-    q = jnp.asarray(rng.normal(size=(nq, lq, DIM)), jnp.float32)
-    qm = jnp.asarray(rng.random((nq, lq)) > 0.2)
-    cents = jnp.asarray(rng.normal(size=(k, DIM)), jnp.float32)
-    codes = jnp.asarray(rng.integers(0, k, size=(nq, c, l)), jnp.int32)
-    cm = jnp.asarray(rng.random((nq, c, l)) > 0.3)
-    vm = jnp.asarray(rng.random((nq, c)) > 0.2)
-    for t_cs in (0.0, 0.3, 0.9):
-        ref = np.asarray(plaid_probe_scores(q, qm, cents, codes, cm, vm,
-                                            t_cs=t_cs, impl="ref"))
-        ker = np.asarray(plaid_probe_scores(q, qm, cents, codes, cm, vm,
-                                            t_cs=t_cs, impl="kernel"))
-        np.testing.assert_array_equal(np.isneginf(ref), np.isneginf(ker))
-        fin = np.isfinite(ref)
-        np.testing.assert_allclose(ker[fin], ref[fin], rtol=1e-5,
-                                   atol=1e-5)
+    nq, lq, k, n = 3, 5, 16, 300
+    member = rng.random((k, n)) < 0.2
+    member[:, [0, 7, 299]] = False                  # empty docs
+    member = jnp.asarray(member, jnp.float32)
+    csp = pruned_scores(rng, nq, lq, k, t_cs)
+    ref = np.asarray(plaid_probe_bag_scores(csp, member, impl="ref"))
+    ker = np.asarray(plaid_probe_bag_pallas(csp, member, block_d=128,
+                                            interpret=True))
+    assert np.array_equal(ref.view(np.int32), ker.view(np.int32)), \
+        "bag kernel drifted from its reference"
+    assert (ref[:, [0, 7, 299]] == 0).all()
+    plain = (np.asarray(csp)[:, :, :, None]
+             * np.asarray(member)[None, None]).max(axis=2).sum(axis=1)
+    np.testing.assert_allclose(ref, plain, rtol=1e-6, atol=0)
+
+
+# ------------------------------------------------ bag scorer exactness
+def indexes_of(kind, rng):
+    """The PLAID indexes (one per shard) of a monolithic index, of one
+    after deletes and adds, or of a sharded one."""
+    if kind == "sharded":
+        docs = unit_docs(rng, n=200)
+        cap = max(sum(len(d) for d in docs) // 3,
+                  max(len(d) for d in docs))
+        sh = ShardedIndex(dim=DIM, backend="plaid", shard_max_vectors=cap,
+                          **dict(KW, ndocs=16))
+        sh.add(docs)
+        assert sh.n_shards >= 2
+        return [s._plaid for s in sh.shards]
+    idx = build(rng, n=60, nprobe=4)
+    if kind == "mutated":
+        idx.delete([0, 5, 11, 40])
+        idx.add(unit_docs(rng, n=8))
+    return [idx._plaid]
+
+
+@pytest.mark.parametrize("kind", ["monolithic", "mutated", "sharded"])
+def test_bag_scores_equal_gathered_scores(kind):
+    """The doc-major bag reference, read at the candidate slots, equals
+    ``_approx_scores_batch`` over the gathered code rows bit for bit:
+    per query token the max over a doc's centroid bag IS the max over
+    its tokens, and both sum the tokens in ``fold_sum``'s order. The
+    candidate program's prune branch returns the same survivors with the
+    interpreted kernel as with the reference, and as the host path."""
+    from repro.core.plaid import (_approx_scores_batch,
+                                  _centroid_scores_batch,
+                                  _device_candidates, _gather_candidates)
+    from repro.kernels.plaid_probe.ref import plaid_probe_bag_ref
+    rng = np.random.default_rng(59)
+    qs = unit_queries(rng, 4, lq=6)
+    qm = rng.random(qs.shape[:2]) > 0.3
+    qm[:, 0] = True
+    qm[2, 1:] = False                           # one single-token query
+    engaged = 0
+    for p in indexes_of(kind, rng):
+        codes, tok_mask = p.padded_codes()
+        div = p.device_ivf()
+        cs = _centroid_scores_batch(jnp.asarray(qs), jnp.asarray(
+            p.codec.centroids))
+        csm = jnp.where(jnp.asarray(qm)[:, :, None], cs, -jnp.inf)
+        nprobe = 4
+        probe = np.asarray(jax.lax.top_k(csm, nprobe)[1])
+        valid = np.broadcast_to(qm[:, :, None], probe.shape)
+        cand, cmask = _gather_candidates(p, probe, None, valid)
+        idx, cm = jnp.asarray(cand), jnp.asarray(cmask)
+        gcodes = jnp.take(codes, idx, axis=0)
+        gmask = jnp.take(tok_mask, idx, axis=0) & cm[:, :, None]
+        for t_cs in (0.0, 0.3, 0.9):
+            csp = jnp.where(csm >= t_cs, csm, 0.0)
+            want = np.asarray(_approx_scores_batch(csm, gcodes, gmask, cm,
+                                                   t_cs))
+            bag = plaid_probe_bag_ref(csp, div.doc_member)
+            got = np.asarray(jnp.where(
+                cm, jnp.take_along_axis(bag, idx, axis=1), -jnp.inf))
+            np.testing.assert_array_equal(got.view(np.int32),
+                                          want.view(np.int32))
+            # per token, bitwise: max over the bag vs over the tokens
+            tok_bag = jnp.max(csp[:, :, :, None]
+                              * div.doc_member[None, None], axis=2)
+            tok_bag = np.asarray(jnp.take_along_axis(
+                tok_bag, idx[:, None, :], axis=2))          # [Nq, Lq, C]
+            vals = jax.vmap(lambda t, i: t[i])(jnp.swapaxes(csp, 1, 2),
+                                               gcodes)  # [Nq, C, L, Lq]
+            tok_rows = np.asarray(jnp.where(gmask[..., None], vals, 0.0
+                                            ).max(axis=2))  # [Nq, C, Lq]
+            tok_rows = np.swapaxes(tok_rows, 1, 2)
+            slots = np.broadcast_to(cmask[:, None, :], tok_bag.shape)
+            np.testing.assert_array_equal(tok_bag[slots], tok_rows[slots])
+            # the whole program: interpreted kernel vs reference vs host
+            ok, geom = device_probe_plan(p, qs.shape[1], nprobe, 8,
+                                         "device", t_cs=t_cs)
+            if not ok:                  # a small last shard reads dense
+                continue
+            engaged += 1
+            _, k, c_score, s_out = geom
+            outs = [_device_candidates(
+                cs, jnp.asarray(qm), div.doc_member,
+                jnp.ones(p.n_docs, bool), k=k, t_cs=t_cs, ndocs=8,
+                c_score=c_score, s_out=s_out, impl=impl)
+                for impl in ("ref", "kernel")]
+            for ref, got in zip(outs[0], outs[1]):
+                np.testing.assert_array_equal(np.asarray(ref),
+                                              np.asarray(got))
+            host = plaid_candidates(p, qs, nprobe=nprobe, t_cs=t_cs,
+                                    ndocs=8, q_mask=qm,
+                                    probe_kernel="host")
+            assert survivors(*host) == survivors(*outs[1])
+    assert engaged, "the device candidate path never engaged"
+
+
+@pytest.fixture(scope="module")
+def many_small_docs():
+    rng = np.random.default_rng(61)
+    idx = MultiVectorIndex(dim=DIM, backend="plaid", doc_maxlen=24,
+                           n_centroids=128, ndocs=16)
+    idx.add(unit_docs(rng, n=2000, lo=1, hi=3))
+    return idx._plaid
+
+
+@pytest.mark.parametrize("case,want", [
+    ("t_cs=0.3", None), ("t_cs=0", None), ("narrow_slots", None),
+    ("t_cs<0", "negative_t_cs"), ("long_doc", "long_docs")])
+def test_probe_plan_falls_back_where_the_bag_is_inexact(many_small_docs,
+                                                        case, want):
+    """The device path (bag scorer) engages for ``t_cs >= 0`` at any
+    slot width, however small against the corpus; the host path serves
+    a negative ``t_cs`` (a 0 could beat a pruned score) and an index
+    with a doc longer than ``doc_maxlen`` (cut in the code view, not in
+    the bag), and says why."""
+    p = many_small_docs
+    lq, nprobe, t_cs = 32, 4, 0.3
+    if case == "t_cs=0":
+        t_cs = 0.0
+    elif case == "narrow_slots":
+        lq, nprobe = 1, 1
+    elif case == "t_cs<0":
+        t_cs = -0.1
+    elif case == "long_doc":        # docs of 4-19 tokens, doc_maxlen 8
+        p = build(np.random.default_rng(67), n=40, doc_maxlen=8)._plaid
+    ok, geom = device_probe_plan(p, lq, nprobe, 16, "device", t_cs=t_cs)
+    qs = unit_queries(np.random.default_rng(71), 2, lq=lq)
+    cand, _ = plaid_candidates(p, qs, nprobe=nprobe, t_cs=t_cs, ndocs=16,
+                               probe_kernel="device")
+    if want is None:
+        assert ok, geom
+        if case == "narrow_slots":
+            assert 16 * geom[2] < p.n_docs
+        assert isinstance(cand, jax.Array)
+    else:
+        assert (ok, geom) == (False, want)
+        assert isinstance(cand, np.ndarray)
 
 
 # --------------------------------------------------------- property sweep

@@ -227,10 +227,12 @@ def test_serve_byte_args_equal_the_shapes(served, model):
     assert [a["h2d_bytes"] for a in enc] == [w * L * 4 for w in widths]
     assert [a["d2h_bytes"] for a in enc] == [w * q for w in widths]
     cand = named(spans, obs.PLAID_CANDIDATES)
-    assert all(a["path"] == "device" for a in cand)
-    # the batch's query vectors go over twice: centroid scores, then the
-    # device candidate program (centroids and live mask are resident)
-    assert [a["h2d_bytes"] for a in cand] == [2 * w * q for w in widths]
+    assert all(a["path"] == "device" and a["scorer"] == "bag"
+               for a in cand)
+    # the batch's query vectors go over once, for the centroid scores:
+    # the bag scorer (and, off TPU, the jnp stage 3) reads the scores,
+    # not the vectors (centroids and live mask are resident)
+    assert [a["h2d_bytes"] for a in cand] == [w * q for w in widths]
     assert [a["d2h_bytes"] for a in cand] == [0] * 3
     assert ([a["h2d_bytes"] for a in named(spans, obs.PLAID_RERANK)]
             == [w * q for w in widths])
@@ -263,3 +265,20 @@ def test_candidate_span_names_path_and_fallback(searcher, model, tmp_path,
     _, spans = traced(lambda: index.search_batch(qs, k=K), tmp_path)
     [a] = named(spans, obs.PLAID_CANDIDATES)
     assert a["path"] == path and a.get("fallback") == fallback
+    assert a.get("scorer") == ("bag" if path == "device" else None)
+
+
+def test_candidate_span_names_negative_t_cs_fallback(searcher, model,
+                                                     tmp_path, monkeypatch):
+    """A negative prune threshold sends the batch to the host path (the
+    bag scorer would let an absent centroid's 0 win), and the span says
+    so."""
+    cfg = model[0]
+    index = searcher.index
+    qs = searcher.encode_queries(np.full((2, cfg.query_maxlen - 2), 40,
+                                         np.int32))
+    monkeypatch.setattr(index, "t_cs", -0.1)
+    _, spans = traced(lambda: index.search_batch(qs, k=K), tmp_path)
+    [a] = named(spans, obs.PLAID_CANDIDATES)
+    assert (a["path"], a["fallback"]) == ("host", "negative_t_cs")
+    assert "scorer" not in a

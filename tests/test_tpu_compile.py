@@ -3,8 +3,9 @@
 No chip is needed: the TPU compiler compiles for a topology that is
 described, not attached. Each test lowers one Pallas kernel at the
 widths the full-size ColBERTv2 path uses (dim 128, Lq 32, K 256, pooled
-docs of 136 tokens, Ward over N = 256) with ``interpret=False`` and
-asserts the compiled program holds the kernel (``tpu_custom_call``).
+docs of 136 tokens, Ward over N = 256, the bag probe over 32768 docs)
+with ``interpret=False`` and asserts the compiled program holds the
+kernel (``tpu_custom_call``).
 Mosaic rejects what interpret mode accepts — block shapes off the
 (8, 128) tiling, unsupported reshapes, more VMEM than the scoped
 limit — so these keep the chip path compiling without chip time.
@@ -20,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.maxsim.kernel import maxsim_pallas, maxsim_rerank_pallas
 from repro.kernels.maxsim_packed.kernel import maxsim_packed_rerank_pallas
-from repro.kernels.plaid_probe.kernel import plaid_probe_pallas
+from repro.kernels.plaid_probe.kernel import plaid_probe_bag_pallas
 from repro.kernels.ward_pool.kernel import ward_pool_pallas
 
 NQ, LQ, DIM, K, LD, S = 8, 32, 128, 256, 136, 256
@@ -92,9 +93,11 @@ def test_maxsim_packed_compiles(one_chip, bits):
              ((DIM, K), F32), ((DIM, 1 << bits), F32))
 
 
-@pytest.mark.parametrize("n_cand", [64, S])
-def test_plaid_probe_compiles(one_chip, n_cand):
-    """One output lane tile (64 candidates) and several (256)."""
-    _compile(lambda *a: plaid_probe_pallas(*a, t_cs=0.45), one_chip,
-             ((NQ, LQ, DIM), F32), ((NQ, LQ, 1), I32), ((DIM, K), F32),
-             ((NQ, n_cand, LD), I32), ((NQ, n_cand, LD), I32))
+@pytest.mark.parametrize("nq,n_docs", [(32, 32768), (32, 32768 - 37),
+                                        (8, 32768), (1, 32768)])
+def test_plaid_probe_compiles(one_chip, nq, n_docs):
+    """The serve cell's bag probe over K 256 and 32768 docs at the full
+    batch of 32 queries x 32 tokens and at smaller batch buckets, and a
+    ragged last doc tile."""
+    _compile(plaid_probe_bag_pallas, one_chip, ((nq, LQ, K), F32),
+             ((K, n_docs), F32))
