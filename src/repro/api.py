@@ -79,10 +79,10 @@ def _spec_extra_meta(spec: RetrieverSpec) -> dict:
 # Registry builders
 # ---------------------------------------------------------------------------
 def _build_multi_vector(params, cfg, docs, spec: RetrieverSpec,
-                        out_dir: Optional[str]):
+                        out_dir: Optional[str], encode_batch: int):
     """flat | hnsw | plaid, monolithic or streaming-sharded."""
     indexer = Indexer(params, cfg, index_spec=spec.index,
-                      pooling_spec=spec.pooling)
+                      pooling_spec=spec.pooling, encode_batch=encode_batch)
     if spec.shard.sharded:
         return indexer.build_streaming(
             docs, shard_max_vectors=int(spec.shard.shard_max_vectors),
@@ -92,7 +92,7 @@ def _build_multi_vector(params, cfg, docs, spec: RetrieverSpec,
 
 
 def _build_cascade(params, cfg, docs, spec: RetrieverSpec,
-                   out_dir: Optional[str]):
+                   out_dir: Optional[str], encode_batch: int):
     """Encode once, pool twice (coarse + fine), store both levels."""
     from repro.core import persist
     from repro.retrieval.cascade import CascadeIndex
@@ -105,7 +105,8 @@ def _build_cascade(params, cfg, docs, spec: RetrieverSpec,
     def pool(factor: int):
         return Indexer(params, cfg, index_spec=flat,
                        pooling_spec=spec.pooling.replace(
-                           factor=max(int(factor), 1)))
+                           factor=max(int(factor), 1)),
+                       encode_batch=encode_batch)
 
     coarse_ix = pool(ix.coarse_factor)
     index = CascadeIndex(dim=cfg.proj_dim, coarse_factor=ix.coarse_factor,
@@ -180,13 +181,16 @@ class Retriever:
         ``spec`` may be a full :class:`RetrieverSpec`, a bare
         :class:`IndexSpec`/:class:`PoolingSpec`/:class:`ShardSpec`
         (the rest defaults from ``cfg``), or None (all from ``cfg``).
+        ``encode_batch`` docs go through the encoder at a time, in the
+        build and in the searcher's encodes.
         """
         spec = RetrieverSpec.coerce(spec, cfg)
         info = backend_info(spec.index.backend)
         if info.builder is None:
             raise ValueError(f"backend {spec.index.backend!r} has no "
                              f"registered builder")
-        index, stats = info.builder(params, cfg, docs, spec, out_dir)
+        index, stats = info.builder(params, cfg, docs, spec, out_dir,
+                                    encode_batch)
         return cls(params, cfg, index, spec, stats=stats,
                    encode_batch=encode_batch)
 

@@ -22,6 +22,7 @@ _MODULES = {
     "dlrm-rm2": "repro.configs.dlrm_rm2",
     # The paper's own models (extra cells, not part of the assigned 40)
     "colbertv2": "repro.configs.colbertv2",
+    "gte-moderncolbert": "repro.configs.moderncolbert",
 }
 
 ASSIGNED_ARCHS = [

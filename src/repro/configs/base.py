@@ -48,6 +48,13 @@ class TransformerConfig:
     pos_emb: str = "rope"              # "rope" | "learned" | "none"
     attn_chunk: int = 1024             # kv/q chunk for online-softmax attention
     attn_full_threshold: int = 2048    # use plain attention below this seq len
+    # alternating local/global layers (ModernBERT): layer i is global when
+    # i % global_every == 0, else local; a local layer attends only keys
+    # with |i - j| <= local_window. 0 = every layer global.
+    local_window: int = 0
+    global_every: int = 1
+    local_rope_theta: float = 0.0      # RoPE theta of local layers (0 ->
+                                       # rope_theta)
     use_flash_kernel: bool = False     # dispatch the Pallas kernel (TPU;
                                        # interpret=True on CPU — slow, tests only)
 
@@ -56,6 +63,10 @@ class TransformerConfig:
     act: str = "silu"
     norm: str = "rmsnorm"              # "rmsnorm" | "layernorm"
     norm_eps: float = 1e-6
+    norm_bias: bool = True             # layernorm only: False = scale only
+    embed_norm: bool = False           # a norm on the token embeddings
+    first_attn_norm: bool = True       # False: layer 0 has no pre-attention
+                                       # norm (identity)
     tie_embeddings: bool = False
 
     # --- execution ---
@@ -82,6 +93,12 @@ class TransformerConfig:
             object.__setattr__(self, "d_head", self.d_model // self.n_heads)
         if self.moe and self.moe_d_ff == 0:
             object.__setattr__(self, "moe_d_ff", self.d_ff)
+        if self.local_window and (self.moe or self.causal):
+            raise ValueError("local/global layers are a dense "
+                             "bidirectional encoder's")
+
+    def is_global(self, layer: int) -> bool:
+        return not self.local_window or layer % self.global_every == 0
 
     @property
     def q_per_kv(self) -> int:
@@ -132,6 +149,12 @@ class ColbertConfig:
     doc_maxlen: int = 256
     query_maxlen: int = 32
     mask_punctuation: bool = True
+    # [CLS], [Q] and [D] marker ids and the query-expansion [MASK] id;
+    # raw token streams pad with 0, as everywhere in the system
+    cls_id: int = 1
+    mask_id: int = 3
+    q_marker_id: int = 4
+    d_marker_id: int = 5
     # Token pooling (the paper's technique) applied at indexing time:
     pool_method: str = "ward"          # "ward" | "kmeans" | "sequential" | "none"
     pool_factor: int = 1               # 1 = no pooling

@@ -132,7 +132,8 @@ class BackendInfo:
     name: str
     artifact_kind: str              # manifest "kind" this backend persists as
     param_keys: Tuple[str, ...]     # IndexSpec fields that apply to it
-    # facade-level builder: (params, cfg, docs, spec, out_dir) ->
+    # facade-level builder: (params, cfg, docs, spec, out_dir,
+    # encode_batch) ->
     # (index, IndexStats). Filled by repro.api at import; a new backend
     # registers its own and rides Retriever/serve unchanged.
     builder: Optional[Callable] = None

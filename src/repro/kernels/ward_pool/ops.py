@@ -9,20 +9,46 @@ off-TPU), and unpads.
     slower of the two, so it is a correctness tool there).
   * ``"kernel"`` — force the Pallas path.
   * ``"ref"``    — force ``core/ward.py``'s ``ward_cluster_batch``.
+
+Which kernel, and how many docs a program holds, follow from the doc
+width N and a VMEM budget (``ward_block_b``): the resident kernel keeps
+``block_b`` whole [N, N] matrices with the merge step's [N, N]
+temporaries (8 f32 copies a doc, input double-buffering included), up
+to 8 docs; where not even one doc fits the budget (N > 724), the
+long-doc kernel holds one doc's matrix in VMEM and walks it in row
+tiles of 128 (``ward_pool_rows_pallas``).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.ward import ward_distances
 from repro.kernels.maxsim.ops import _on_tpu, _pad_to
-from repro.kernels.ward_pool.kernel import ward_pool_pallas
+from repro.kernels.ward_pool.kernel import (ward_pool_pallas,
+                                            ward_pool_rows_pallas)
 from repro.kernels.ward_pool.ref import ward_assign_ref
 
 WARD_IMPLS = ("auto", "kernel", "ref")
+VMEM_BUDGET = 16 << 20      # bytes: v5e's default scoped VMEM limit
+RESIDENT_COPIES = 8         # f32 [N, N] copies the resident kernel holds
+MAX_BLOCK_B = 8
+ROWS = 128                  # row tile of the long-doc kernel
+
+
+def resident(N: int) -> bool:
+    """Whether one doc's [N, N] matrix and the merge step's temporaries
+    fit the budget (the resident kernel), else the long-doc kernel."""
+    return RESIDENT_COPIES * 4 * N * N <= VMEM_BUDGET
+
+
+def ward_block_b(N: int) -> int:
+    """Docs per program at doc width N (1 for the long-doc kernel)."""
+    return max(1, min(MAX_BLOCK_B, VMEM_BUDGET // (RESIDENT_COPIES * 4
+                                                   * N * N)))
 
 
 def resolve_impl(impl: str) -> str:
@@ -35,32 +61,49 @@ def resolve_impl(impl: str) -> str:
     return impl
 
 
-@functools.partial(jax.jit, static_argnames=("factor", "block_b"))
-def _ward_assign_kernel(x, mask, factor: int, block_b: int = 8):
-    B = x.shape[0]
+@functools.partial(jax.jit, static_argnames=("factor", "block_b", "rows"))
+def _ward_assign_kernel(x, mask, factor: int, block_b: int = 8,
+                        rows: int = 0):
+    B, N = mask.shape
     # the reference's own initial distances, so both paths merge
-    # identical values; padded docs are all-masked (all +inf)
-    d2 = _pad_to(jax.vmap(ward_distances)(x, mask), 0, block_b,
-                 value=jnp.inf)
-    mp = _pad_to(mask, 0, block_b)
+    # identical values; padded docs and tokens are masked (all +inf)
+    d2 = jax.vmap(ward_distances)(x, mask)
+    mp = mask
+    if rows:
+        d2 = _pad_to(_pad_to(d2, 1, rows, value=jnp.inf), 2, rows,
+                     value=jnp.inf)
+        mp = _pad_to(mask, 1, rows)
+    else:
+        d2 = _pad_to(d2, 0, block_b, value=jnp.inf)
+        mp = _pad_to(mask, 0, block_b)
     n_valid = jnp.sum(mp.astype(jnp.int32), axis=-1)
     k = jnp.maximum(n_valid // factor + 1, 1)
-    steps = jnp.maximum(n_valid - k, 0).reshape(-1, block_b).max(axis=1)
-    out = ward_pool_pallas(d2, mp.astype(jnp.int32)[:, None, :],
-                           k[:, None, None], steps, block_b=block_b,
-                           interpret=not _on_tpu())
-    return out[:B, 0]
+    steps = jnp.maximum(n_valid - k, 0)
+    args = (d2, mp.astype(jnp.int32)[:, None, :], k[:, None, None])
+    if rows:
+        out = ward_pool_rows_pallas(*args, steps, rows=rows,
+                                    interpret=not _on_tpu())
+    else:
+        out = ward_pool_pallas(*args, steps.reshape(-1, block_b).max(axis=1),
+                               block_b=block_b, interpret=not _on_tpu())
+    return out[:B, 0, :N]
 
 
 def ward_assign(x, mask, factor: int, *, impl: str = "auto",
-                block_b: int = 8):
+                block_b: Optional[int] = None, rows: Optional[int] = None):
     """Batched Ward cluster assignments, reference-bitwise.
 
     x [B, N, d], mask [B, N] -> assign [B, N] int32 where each valid
     token's id is its cluster's representative (lowest) token index —
-    the exact contract of ``ward_cluster_batch``.
+    the exact contract of ``ward_cluster_batch``. ``block_b`` (resident
+    kernel) and ``rows`` (long-doc kernel) default to what N gives
+    (module doc); ``rows`` chooses the long-doc kernel.
     """
+    N = mask.shape[1]
+    if rows is None:
+        rows = 0 if resident(N) else ROWS
     with jax.named_scope("ward"):       # op metadata only
         if resolve_impl(impl) == "ref":
             return ward_assign_ref(x, mask, factor)
-        return _ward_assign_kernel(x, mask, int(factor), block_b)
+        return _ward_assign_kernel(x, mask, int(factor),
+                                   block_b or ward_block_b(N), rows)

@@ -22,11 +22,17 @@ see ``sharding.api.lm_rules``.
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
 from repro.models.layers import dense, init_dense, init_rmsnorm, rmsnorm
 from repro.sharding.api import constrain
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -68,8 +74,9 @@ def init_attention(key, cfg, dtype=jnp.float32):
     return p
 
 
-def _project_qkv(p, x, cfg, positions):
-    """Returns q [B,S,H,dh], k,v [B,S,KV,dh] with RoPE/qk-norm applied."""
+def _project_qkv(p, x, cfg, positions, theta=None):
+    """Returns q [B,S,H,dh], k,v [B,S,KV,dh] with RoPE/qk-norm applied
+    (RoPE at ``theta``, the config's ``rope_theta`` if None)."""
     B, S, _ = x.shape
     H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     q = dense(p["wq"], x).reshape(B, S, H, dh)
@@ -79,8 +86,9 @@ def _project_qkv(p, x, cfg, positions):
         q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
         k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
     if cfg.pos_emb == "rope":
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        theta = cfg.rope_theta if theta is None else theta
+        q = apply_rope(q, positions, theta)
+        k = apply_rope(k, positions, theta)
     return q, k, v
 
 
@@ -96,8 +104,9 @@ def _repeat_kv(k, n_rep):
 # ---------------------------------------------------------------------------
 # Full attention (short sequences / masked encoder) — MHA layout
 # ---------------------------------------------------------------------------
-def _full_attn(q, k, v, *, causal, pad_mask=None, q_offset=0):
-    """q,k,v: [B,S,H,dh]; pad_mask [B,Skv] True=valid. -> [B,Sq,H,dh]"""
+def _full_attn(q, k, v, *, causal, pad_mask=None, q_offset=0, window=0):
+    """q,k,v: [B,S,H,dh]; pad_mask [B,Skv] True=valid; ``window`` > 0
+    keeps only keys with |i - j| <= window. -> [B,Sq,H,dh]"""
     dh = q.shape[-1]
     scale = 1.0 / jnp.sqrt(jnp.float32(dh))
     s = jnp.einsum("bqhd,bshd->bhqs", q, k,
@@ -108,6 +117,10 @@ def _full_attn(q, k, v, *, causal, pad_mask=None, q_offset=0):
         kpos = jnp.arange(Skv)
         cm = qpos[:, None] >= kpos[None, :]
         s = jnp.where(cm[None, None], s, -jnp.inf)
+    if window:
+        qpos = jnp.arange(Sq) + q_offset
+        band = jnp.abs(qpos[:, None] - jnp.arange(Skv)[None, :]) <= window
+        s = jnp.where(band[None, None], s, -jnp.inf)
     if pad_mask is not None:
         s = jnp.where(pad_mask[:, None, None, :], s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
@@ -186,12 +199,20 @@ def _chunked_attn(q, k, v, *, causal, chunk, unroll=False):
 # Public forward (train / prefill)
 # ---------------------------------------------------------------------------
 def attention_forward(p, x, cfg, *, positions=None, pad_mask=None,
-                      return_kv=False):
-    """x: [B, S, d_model]. Returns y [B, S, d_model] (and (k, v) if asked)."""
+                      return_kv=False, local=False):
+    """x: [B, S, d_model]. Returns y [B, S, d_model] (and (k, v) if asked).
+
+    ``local`` runs a local layer of an alternating model (keys within
+    ``cfg.local_window``, RoPE at ``cfg.local_rope_theta``). A padded
+    bidirectional input longer than ``attn_full_threshold`` takes the
+    masked kernel (``kernels/flash_attention/masked.py``) on the TPU and
+    never forms an [S, S] score matrix there."""
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.arange(S)
-    q, k, v = _project_qkv(p, x, cfg, positions)
+    window = cfg.local_window if local else 0
+    theta = (cfg.local_rope_theta or cfg.rope_theta) if local else None
+    q, k, v = _project_qkv(p, x, cfg, positions, theta)
     kv_out = (k, v)
     kf = _repeat_kv(k, cfg.q_per_kv)
     vf = _repeat_kv(v, cfg.q_per_kv)
@@ -200,18 +221,29 @@ def attention_forward(p, x, cfg, *, positions=None, pad_mask=None,
     vf = constrain(vf, "batch", "kvseq", "heads", None)
 
     use_full = (S <= cfg.attn_full_threshold or S % cfg.attn_chunk != 0
-                or pad_mask is not None)
-    if cfg.use_flash_kernel and pad_mask is None and cfg.causal:
-        from repro.kernels.flash_attention.ops import flash_attention
-        o = flash_attention(q.transpose(0, 2, 1, 3),
-                            k.transpose(0, 2, 1, 3),
-                            v.transpose(0, 2, 1, 3), causal=True)
-        o = o.transpose(0, 2, 1, 3)
-    elif use_full:
-        o = _full_attn(q, kf, vf, causal=cfg.causal, pad_mask=pad_mask)
-    else:
-        o = _chunked_attn(q, kf, vf, causal=cfg.causal, chunk=cfg.attn_chunk,
-                          unroll=cfg.unroll_scans)
+                or pad_mask is not None or window > 0)
+    masked_long = (pad_mask is not None and not cfg.causal
+                   and S > cfg.attn_full_threshold)
+    # alternating models: each layer's attention core under its kind's
+    # scope (encoder/attention/global, encoder/attention/local)
+    scope = (jax.named_scope("local" if local else "global")
+             if cfg.local_window else contextlib.nullcontext())
+    with scope:
+        if masked_long and _on_tpu():
+            from repro.kernels.flash_attention.masked import masked_attention
+            o = masked_attention(q, kf, vf, pad_mask, window=window)
+        elif cfg.use_flash_kernel and pad_mask is None and cfg.causal:
+            from repro.kernels.flash_attention.ops import flash_attention
+            o = flash_attention(q.transpose(0, 2, 1, 3),
+                                k.transpose(0, 2, 1, 3),
+                                v.transpose(0, 2, 1, 3), causal=True)
+            o = o.transpose(0, 2, 1, 3)
+        elif use_full:
+            o = _full_attn(q, kf, vf, causal=cfg.causal, pad_mask=pad_mask,
+                           window=window)
+        else:
+            o = _chunked_attn(q, kf, vf, causal=cfg.causal,
+                              chunk=cfg.attn_chunk, unroll=cfg.unroll_scans)
     o = o.reshape(B, S, cfg.n_heads * cfg.d_head)
     o = constrain(o, "batch", "qseq", "heads")
     y = dense(p["wo"], o)

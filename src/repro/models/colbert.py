@@ -56,31 +56,33 @@ def _encode(params, tokens, cfg, pad_mask):
         return constrain(v, "batch", "seq", None)
 
 
-def prepare_query_tokens(tokens, query_maxlen: int):
+def prepare_query_tokens(tokens, query_maxlen: int, cls_id: int = CLS_ID,
+                         q_id: int = Q_MARK_ID, mask_id: int = MASK_ID):
     """[B, L] raw token ids -> ([B, Lq] with [CLS][Q]...[MASK] expansion,
     attention pad-mask (all True — MASK expansion tokens attend))."""
     B, L = tokens.shape
     body = tokens[:, :query_maxlen - 2]
-    out = jnp.full((B, query_maxlen), MASK_ID, jnp.int32)
-    out = out.at[:, 0].set(CLS_ID).at[:, 1].set(Q_MARK_ID)
+    out = jnp.full((B, query_maxlen), mask_id, jnp.int32)
+    out = out.at[:, 0].set(cls_id).at[:, 1].set(q_id)
     body_len = query_maxlen - 2
     pad = body_len - body.shape[1]
     body = jnp.pad(body, ((0, 0), (0, max(pad, 0))))[:, :body_len]
     # query augmentation: PAD slots become MASK (attended, vector-emitting)
-    body = jnp.where(body == PAD_ID, MASK_ID, body)
+    body = jnp.where(body == PAD_ID, mask_id, body)
     out = jax.lax.dynamic_update_slice(out, body.astype(jnp.int32), (0, 2))
     return out, jnp.ones((B, query_maxlen), bool)
 
 
-def prepare_doc_tokens(tokens, doc_maxlen: int):
+def prepare_doc_tokens(tokens, doc_maxlen: int, cls_id: int = CLS_ID,
+                       d_id: int = D_MARK_ID):
     """[B, L] raw ids -> ([B, Ld] with [CLS][D] prefix, pad mask)."""
     B, L = tokens.shape
     body = tokens[:, :doc_maxlen - 2]
     pad = (doc_maxlen - 2) - body.shape[1]
     body = jnp.pad(body, ((0, 0), (0, max(pad, 0))))
     out = jnp.concatenate(
-        [jnp.full((B, 1), CLS_ID, jnp.int32),
-         jnp.full((B, 1), D_MARK_ID, jnp.int32),
+        [jnp.full((B, 1), cls_id, jnp.int32),
+         jnp.full((B, 1), d_id, jnp.int32),
          body.astype(jnp.int32)], axis=1)
     return out, out != PAD_ID
 
@@ -100,7 +102,8 @@ def encode_queries(params, tokens, cfg):
     """Raw query token ids [B, L] -> ([B, Lq, dim] unit vectors, emit mask).
 
     Every expanded slot emits (ColBERT scores all Lq query vectors)."""
-    toks, attn = prepare_query_tokens(tokens, cfg.query_maxlen)
+    toks, attn = prepare_query_tokens(tokens, cfg.query_maxlen, cfg.cls_id,
+                                      cfg.q_marker_id, cfg.mask_id)
     v = _encode(params, toks, cfg, attn)
     return v, jnp.ones(toks.shape, bool)
 
@@ -108,7 +111,8 @@ def encode_queries(params, tokens, cfg):
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def encode_docs(params, tokens, cfg):
     """Raw doc token ids [B, L] -> ([B, Ld, dim] unit vectors, emit mask)."""
-    toks, attn = prepare_doc_tokens(tokens, cfg.doc_maxlen)
+    toks, attn = prepare_doc_tokens(tokens, cfg.doc_maxlen, cfg.cls_id,
+                                    cfg.d_marker_id)
     v = _encode(params, toks, cfg, attn)
     emit = emit_mask_docs(toks, attn, cfg.mask_punctuation)
     return jnp.where(emit[..., None], v, 0.0), emit

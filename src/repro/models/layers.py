@@ -71,8 +71,11 @@ def rmsnorm(p, x, eps=1e-6):
     return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
 
 
-def init_layernorm(d, dtype=jnp.float32):
-    return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
+def init_layernorm(d, dtype=jnp.float32, bias=True):
+    p = {"scale": jnp.ones((d,), dtype)}
+    if bias:
+        p["bias"] = jnp.zeros((d,), dtype)
+    return p
 
 
 def layernorm(p, x, eps=1e-6):
@@ -80,12 +83,16 @@ def layernorm(p, x, eps=1e-6):
     mu = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
     y = (xf - mu) * jax.lax.rsqrt(var + eps)
-    return (y * p["scale"].astype(jnp.float32)
-            + p["bias"].astype(jnp.float32)).astype(x.dtype)
+    y = y * p["scale"].astype(jnp.float32)
+    if "bias" in p:             # a bias-free LayerNorm holds no bias
+        y = y + p["bias"].astype(jnp.float32)
+    return y.astype(x.dtype)
 
 
-def init_norm(kind, d, dtype=jnp.float32):
-    return init_rmsnorm(d, dtype) if kind == "rmsnorm" else init_layernorm(d, dtype)
+def init_norm(kind, d, dtype=jnp.float32, bias=True):
+    if kind == "rmsnorm":
+        return init_rmsnorm(d, dtype)
+    return init_layernorm(d, dtype, bias)
 
 
 def norm(kind, p, x, eps=1e-6):
@@ -111,7 +118,8 @@ def embed(p, ids, dtype=None):
 # ---------------------------------------------------------------------------
 ACTS = {
     "silu": jax.nn.silu,
-    "gelu": jax.nn.gelu,
+    "gelu": jax.nn.gelu,                # tanh approximation
+    "gelu_erf": lambda x: jax.nn.gelu(x, approximate=False),
     "relu": jax.nn.relu,
     "tanh": jnp.tanh,
 }

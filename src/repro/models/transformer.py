@@ -35,9 +35,9 @@ from repro.sharding.api import constrain
 def _init_layer(key, cfg, is_moe, dtype):
     ks = jax.random.split(key, 4)
     p = {
-        "attn_norm": init_norm(cfg.norm, cfg.d_model, dtype),
+        "attn_norm": init_norm(cfg.norm, cfg.d_model, dtype, cfg.norm_bias),
         "attn": init_attention(ks[0], cfg, dtype),
-        "mlp_norm": init_norm(cfg.norm, cfg.d_model, dtype),
+        "mlp_norm": init_norm(cfg.norm, cfg.d_model, dtype, cfg.norm_bias),
     }
     if is_moe:
         p["moe"] = init_moe(ks[1], cfg, dtype)
@@ -54,6 +54,9 @@ def init_transformer(key, cfg):
     ks = jax.random.split(key, 4)
     params = {"embed": init_embed(ks[0], cfg.vocab_size, cfg.d_model,
                                   dtype=dtype)}
+    if cfg.embed_norm:
+        params["embed_norm"] = init_norm(cfg.norm, cfg.d_model, dtype,
+                                         cfg.norm_bias)
     if cfg.pos_emb == "learned":
         params["pos_embed"] = init_embed(
             jax.random.fold_in(ks[0], 7), cfg.max_seq_len, cfg.d_model,
@@ -66,7 +69,8 @@ def init_transformer(key, cfg):
         lk = jax.random.split(ks[2], n_moe)
         params["moe_layers"] = jax.vmap(
             lambda k: _init_layer(k, cfg, True, dtype))(lk)
-    params["final_norm"] = init_norm(cfg.norm, cfg.d_model, dtype)
+    params["final_norm"] = init_norm(cfg.norm, cfg.d_model, dtype,
+                                     cfg.norm_bias)
     if not cfg.tie_embeddings:
         params["lm_head"] = init_dense(
             ks[3], cfg.d_model, cfg.vocab_size, dtype=dtype)
@@ -76,12 +80,14 @@ def init_transformer(key, cfg):
 # ---------------------------------------------------------------------------
 # Blocks
 # ---------------------------------------------------------------------------
-def _block(x, lp, cfg, *, is_moe, moe_impl, positions, pad_mask):
+def _block(x, lp, cfg, *, is_moe, moe_impl, positions, pad_mask,
+           local=False, attn_norm=True):
     # named scopes only label the ops' metadata (device trace)
     with jax.named_scope("attention"):
-        h = norm(cfg.norm, lp["attn_norm"], x, cfg.norm_eps)
+        h = norm(cfg.norm, lp["attn_norm"], x, cfg.norm_eps) if attn_norm \
+            else x
         h = attention_forward(lp["attn"], h, cfg, positions=positions,
-                              pad_mask=pad_mask)
+                              pad_mask=pad_mask, local=local)
         x = x + h
     with jax.named_scope("mlp"):
         h = norm(cfg.norm, lp["mlp_norm"], x, cfg.norm_eps)
@@ -115,6 +121,42 @@ def _scan_stack(x, stack, cfg, *, is_moe, moe_impl, positions, pad_mask):
     return x, aux
 
 
+def _alternating_stack(x, stack, cfg, *, positions, pad_mask):
+    """The dense stack of an alternating model (ModernBERT): layer i is
+    global when ``i % global_every == 0``. Layer 0 (global, without its
+    pre-attention norm when ``first_attn_norm`` is off) runs alone, then
+    one scan over the periods of ``global_every - 1`` local layers and a
+    global one, then any layers left over."""
+    P, n = cfg.global_every, cfg.n_layers
+
+    def block(x, lp, i):
+        fn = functools.partial(_block, cfg=cfg, is_moe=False, moe_impl=None,
+                               positions=positions, pad_mask=pad_mask,
+                               local=not cfg.is_global(i),
+                               attn_norm=i > 0 or cfg.first_attn_norm)
+        if cfg.remat:
+            fn = jax.checkpoint(fn)
+        return fn(x, lp)[0]
+
+    def layer(i):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            stack)
+
+    def period(x, p):
+        for r in range(P):      # layer 1 + p * P + r
+            x = block(x, layer(1 + p * P + r), 1 + r)
+        return x, None
+
+    x = block(x, layer(0), 0)
+    m = (n - 1) // P
+    x, _ = jax.lax.scan(period, x, jnp.arange(m),
+                        unroll=m if cfg.unroll_scans else 1)
+    for i in range(1 + m * P, n):
+        x = block(x, layer(i), i)
+    return x
+
+
 # ---------------------------------------------------------------------------
 # Forward (hidden states)
 # ---------------------------------------------------------------------------
@@ -127,9 +169,14 @@ def forward(params, tokens, cfg, *, pad_mask=None, positions=None,
         positions = jnp.arange(tokens.shape[1])
     if cfg.pos_emb == "learned":
         x = x + embed(params["pos_embed"], positions, dtype=cdt)
+    if cfg.embed_norm:
+        x = norm(cfg.norm, params["embed_norm"], x, cfg.norm_eps)
     x = constrain(x, "batch", "seq", "dmodel")
     aux = jnp.zeros((), jnp.float32)
-    if "dense_layers" in params:
+    if cfg.local_window:
+        x = _alternating_stack(x, params["dense_layers"], cfg,
+                               positions=positions, pad_mask=pad_mask)
+    elif "dense_layers" in params:
         x, a = _scan_stack(x, params["dense_layers"], cfg, is_moe=False,
                            moe_impl=moe_impl, positions=positions,
                            pad_mask=pad_mask)

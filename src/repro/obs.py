@@ -21,8 +21,9 @@ import numpy as np
 
 # build thread (retrieval/indexer.py, core/pooling.py)
 INDEXER_INPUT = "repro.indexer.input"            # batch
-INDEXER_ENCODE = "repro.indexer.encode"          # batch, docs, h2d_bytes
-INDEXER_POOL = "repro.indexer.pool"              # batch
+INDEXER_ENCODE = "repro.indexer.encode"          # batch, docs, h2d_bytes,
+#                                                  tokens, valid_tokens
+INDEXER_POOL = "repro.indexer.pool"              # batch, n_max, block_b
 INDEXER_FETCH = "repro.indexer.fetch"            # batch, d2h_bytes
 INDEXER_FLUSH_WAIT = "repro.indexer.flush_wait"  # batch, shard, wait_us
 # flush thread

@@ -62,6 +62,7 @@ from repro.core.index import BACKENDS, MultiVectorIndex
 from repro.core.pooling import (compact_pooled, compact_pooled_begin,
                                 compact_pooled_finish)
 from repro.core.spec import IndexSpec, PoolingSpec
+from repro.kernels.ward_pool.ops import ward_block_b
 from repro.models.colbert import encode_docs
 
 # tiny jit'd reduction: the eager astype+sum pair costs ~2ms of op-by-op
@@ -222,8 +223,11 @@ class Indexer:
             if pad:
                 chunk = np.pad(chunk, ((0, pad), (0, 0)))
             b = self._next_batch()
+            L = self.cfg.doc_maxlen
             with obs.span(obs.INDEXER_ENCODE, batch=b, docs=B - pad,
-                          h2d_bytes=obs.host_nbytes(chunk)):
+                          h2d_bytes=obs.host_nbytes(chunk), tokens=B * L,
+                          valid_tokens=2 * B + int(np.count_nonzero(
+                              chunk[:, :L - 2]))):
                 v, emit = encode_docs(self.params, jnp.asarray(chunk),
                                       self.cfg)
             yield b, v, emit, B - pad
@@ -268,8 +272,11 @@ class Indexer:
             out.extend(docs[:keep] if keep < len(docs) else docs)
             return moved
 
+        ward = self.pooling.method == "ward"
         for b, v, emit, n_real in self._encoded_batches(doc_tokens):
-            with obs.span(obs.INDEXER_POOL, batch=b):
+            n_max = int(v.shape[1])
+            with obs.span(obs.INDEXER_POOL, batch=b, n_max=n_max,
+                          block_b=ward_block_b(n_max) if ward else 0):
                 pooled, pmask = self.pooling.apply(v, emit)
                 if n_real < emit.shape[0]:
                     # padding rows still emit their CLS/[D] markers —

@@ -145,6 +145,40 @@ def test_impl_dispatch_and_validation():
 
 
 # ---------------------------------------------------------------------------
+# docs per program from N, and the long-doc kernel, bitwise
+# ---------------------------------------------------------------------------
+def test_block_b_follows_n_and_the_budget():
+    from repro.kernels.ward_pool.ops import resident, ward_block_b
+    assert [ward_block_b(n) for n in (40, 256, 300, 512, 2048)] == \
+        [8, 8, 5, 2, 1]
+    assert resident(724) and not resident(725) and not resident(2048)
+
+
+@pytest.mark.parametrize("N,kw", [(40, dict(block_b=1)),
+                                  (40, dict(block_b=3)),
+                                  (40, dict(rows=8)),
+                                  (64, dict(rows=16)),
+                                  (50, dict(rows=16))])   # N padded to 64
+@pytest.mark.parametrize("factor", [2, 3])
+def test_kernels_bitwise_at_each_block(N, kw, factor):
+    """The resident kernel at 1 and 3 docs a program and the long-doc
+    kernel (one doc a program, row tiles of 8 or 16) assign bitwise
+    like ``ward_cluster_batch``: ties, short, holed and empty docs."""
+    rng = np.random.default_rng(N + factor)
+    B, d = 5, 16
+    x = rng.normal(size=(B, N, d)).astype(np.float32)
+    x[:, N // 2] = x[:, 3]                        # exact ties
+    mask = np.ones((B, N), bool)
+    mask[1, N // 2:] = False
+    mask[2, ::3] = False
+    mask[3] = False
+    x, mask = jnp.asarray(x), jnp.asarray(mask)
+    want = np.asarray(ward_cluster_batch(x, mask, factor))
+    got = np.asarray(ward_assign(x, mask, factor, impl="kernel", **kw))
+    np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
 # the full pooled pipeline through either impl, incl. device compaction
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("factor", [2, 3])

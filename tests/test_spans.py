@@ -25,8 +25,9 @@ B = 16                  # encode batch and stream batch: no padded rows
 N_BATCHES = 6
 BUILD_ARGS = {
     obs.INDEXER_INPUT: {"batch"},
-    obs.INDEXER_ENCODE: {"batch", "docs", "h2d_bytes"},
-    obs.INDEXER_POOL: {"batch"},
+    obs.INDEXER_ENCODE: {"batch", "docs", "h2d_bytes", "tokens",
+                         "valid_tokens"},
+    obs.INDEXER_POOL: {"batch", "n_max", "block_b"},
     obs.INDEXER_FETCH: {"batch", "d2h_bytes"},
     obs.INDEXER_FLUSH_WAIT: {"batch", "shard", "wait_us"},
     obs.INDEXER_SHARD: {"shard", "docs", "vectors"},
@@ -123,6 +124,47 @@ def test_build_spans_of_a_batch_share_its_number(built):
     for name in (obs.INDEXER_FLUSH_WAIT, obs.INDEXER_SHARD,
                  obs.INDEXER_SHARD_SAVE, obs.INDEXER_SHARD_REOPEN):
         assert sorted(a["shard"] for a in named(spans, name)) == shards
+
+
+def test_build_token_and_pool_args(built, model):
+    """``tokens`` counts the encoder's slots, ``valid_tokens`` the
+    [CLS][D] markers and the body tokens that fit; the pool span names
+    the doc width and the Ward kernel's docs per program at it."""
+    from repro.kernels.ward_pool.ops import ward_block_b
+    cfg, _, toks = model
+    L = cfg.doc_maxlen
+    enc = named(built[2], obs.INDEXER_ENCODE)
+    for b, a in enumerate(enc):
+        body = toks[b * B:(b + 1) * B, :L - 2]
+        assert a["tokens"] == B * L
+        assert a["valid_tokens"] == 2 * B + np.count_nonzero(body)
+    for a in named(built[2], obs.INDEXER_POOL):
+        assert a["n_max"] == L and a["block_b"] == ward_block_b(L) == 8
+
+
+def test_layer_kinds_land_under_their_scopes():
+    """An alternating model's attention cores carry the scopes
+    ``encoder/attention/global`` and ``encoder/attention/local``; a
+    model of global layers only carries neither."""
+    import re
+    import jax.numpy as jnp
+    from repro.models.colbert import encode_docs
+
+    def scopes(cfg):
+        p = jax.eval_shape(lambda k: init_colbert(k, cfg),
+                           jax.random.PRNGKey(0))
+        toks = jax.ShapeDtypeStruct((2, cfg.doc_maxlen - 2), jnp.int32)
+        text = encode_docs.lower(p, toks, cfg).compile().as_text()
+        return set(re.findall(r'op_name="([^"]*)"', text))
+
+    names = scopes(get_smoke_config("gte-moderncolbert"))
+    for kind in ("global", "local"):
+        # a scan's ``while/body`` may sit between the components
+        under = re.compile(rf"encoder/(.*/)?attention/{kind}/")
+        assert any(under.search(n) for n in names), kind
+    plain = scopes(get_smoke_config("colbertv2"))
+    assert any(re.search(r"encoder/(.*/)?attention/", n) for n in plain)
+    assert not any("/global/" in n or "/local/" in n for n in plain)
 
 
 def test_build_byte_args_equal_the_shapes(built, model):

@@ -22,7 +22,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.maxsim.kernel import maxsim_pallas, maxsim_rerank_pallas
 from repro.kernels.maxsim_packed.kernel import maxsim_packed_rerank_pallas
 from repro.kernels.plaid_probe.kernel import plaid_probe_bag_pallas
-from repro.kernels.ward_pool.kernel import ward_pool_pallas
+from repro.kernels.flash_attention.masked import masked_attention
+from repro.kernels.ward_pool.kernel import (ward_pool_pallas,
+                                            ward_pool_rows_pallas)
 
 NQ, LQ, DIM, K, LD, S = 8, 32, 128, 256, 136, 256
 I32, F32 = jnp.int32, jnp.float32
@@ -70,6 +72,30 @@ def test_ward_pool_compiles(one_chip):
     _compile(lambda *a: ward_pool_pallas(*a, block_b=8), one_chip,
              ((B, N, N), F32), ((B, 1, N), I32), ((B, 1, 1), I32),
              ((B // 8,), I32))
+
+
+def test_ward_pool_long_docs_compiles(one_chip):
+    """N = 2048: one doc's [2048, 2048] f32 matrix (16 MiB) resident in
+    VMEM, walked in row tiles of 128, within the kernel's VMEM limit."""
+    B, N = 16, 2048
+    _compile(ward_pool_rows_pallas, one_chip,
+             ((B, N, N), F32), ((B, 1, N), I32), ((B, 1, 1), I32),
+             ((B,), I32))
+
+
+@pytest.mark.parametrize("window", [0, 64])
+def test_masked_attention_compiles(one_chip, window):
+    """The long-doc encoder's attention at (16, 2048), 12 heads of 64:
+    global (window 0) and banded (64); no [16, 12, 2048, 2048] f32 score
+    buffer in the program."""
+    B, S, H, dh = 16, 2048, 12, 64
+    bf = jnp.bfloat16
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            (((B, S, H, dh), bf),) * 3 + (((B, S), jnp.bool_),)]
+    text = jax.jit(lambda q, k, v, m: masked_attention(
+        q, k, v, m, window=window)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"f32[{B},{H},{S},{S}]" not in text
 
 
 @pytest.mark.parametrize("scan", ["all_docs", "rerank"])
