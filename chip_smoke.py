@@ -288,6 +288,30 @@ def paths_engaged(built, Q, checks):
                bits=p.codec.bits))
 
 
+def ward_long_contract(seed=0, n_docs=4, N=2048, dim=128, factor=2):
+    """The long-doc Ward kernel against ``core/ward.py`` at N = 2048 on
+    seeded clustered token vectors (2046 valid, the long-doc cap, and a
+    shorter doc), and its rows read again per merge."""
+    import jax.numpy as jnp
+    from repro.kernels.ward_pool import ward_assign
+    from repro.kernels.ward_pool.ops import ward_rescans
+    rng = np.random.default_rng(seed)
+    cent = rng.normal(size=(n_docs, 96, dim)).astype(np.float32)
+    x = (cent[np.arange(n_docs)[:, None], rng.integers(0, 96, (n_docs, N))]
+         + 0.6 * rng.normal(size=(n_docs, N, dim)).astype(np.float32))
+    n_valid = np.full(n_docs, N - 2)
+    n_valid[-1] = N // 3
+    emit = jnp.asarray(np.arange(N)[None, :] < n_valid[:, None])
+    a_k, rescans = (np.asarray(a) for a in ward_rescans(x, emit, factor))
+    a_r = np.asarray(ward_assign(jnp.asarray(x), emit, factor, impl="ref"))
+    merges = int((n_valid - (n_valid // factor + 1)).sum())
+    print(f"   long-doc ward kernel == core/ward.py at N = {N}: bitwise "
+          f"{np.array_equal(a_k, a_r)} ({int((a_k == a_r).all(axis=1).sum())}"
+          f"/{n_docs} docs equal); rescans per merge "
+          f"{rescans.sum() / merges:.3f} ({int(rescans.sum())} over "
+          f"{merges} merges)")
+
+
 def contracts(built, first_batch, Q):
     """The CPU suite's bitwise contracts, measured on this chip: reported,
     not enforced (the chip's matmuls need not reproduce the CPU's bits)."""
@@ -297,6 +321,7 @@ def contracts(built, first_batch, Q):
     a_r = np.asarray(ward_assign(v, emit, 2, impl="ref"))
     print(f"   ward kernel == core/ward.py: bitwise {np.array_equal(a_k, a_r)}"
           f" ({int((a_k == a_r).all(axis=1).sum())}/{len(a_k)} docs equal)")
+    ward_long_contract()
     ix = built["plaid"].index
     packed = ix.search_batch(Q, k=10)
     for name, attr, alt in (("packed rerank == f32 recon rerank",

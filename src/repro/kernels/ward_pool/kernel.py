@@ -26,6 +26,12 @@ of ``_merge_once`` becomes a one-hot select over the resident matrix:
 
 The loop runs the block's largest merge budget (scalar-prefetched per
 block); steps past a doc's own budget are ``do``-folded no-ops.
+
+Past N = 724 one doc's matrix fills the budget alone, and walking it
+every merge costs O(N^2) a merge. The long-doc kernel
+(``ward_pool_rows_pallas``) instead keeps each row's minimum exact as
+the matrix changes, so a merge reads and writes O(N) values: rows i and
+j, two column stripes, and the rows whose minimum moved elsewhere.
 """
 from __future__ import annotations
 
@@ -154,146 +160,223 @@ def ward_pool_pallas(d2, mask, k, steps, *, block_b: int = 8,
 
 
 # ---------------------------------------------------------------------------
-# Long docs: one doc per program, its matrix walked in row tiles
+# Long docs: one doc per program, O(N) work a merge
 # ---------------------------------------------------------------------------
-def _ward_rows_kernel(steps_ref, d2_hbm, mask_ref, k_ref, o_ref, d2, rmin,
-                      rarg, rij, *, rows: int):
-    """One doc. Its [N, N] matrix is copied once into VMEM (``d2``) and
-    stays there; each merge walks it in tiles of ``rows`` rows, so no
-    temporary larger than a tile exists. Row minima and their first
-    columns (``rmin``, ``rarg``, [N, 1]) are kept up to date by the
-    update pass, so the selection reads [N] values, not [N, N]:
-    the first row holding the global minimum, then (its ``rarg``) the
-    first column in that row — the flat row-major argmin of the
-    reference. Lance-Williams and the row/column rewrite are the
-    resident kernel's expressions, tile by tile, so the merges are
-    bitwise ``ward_cluster_batch``'s; a tile's piece of columns i and j
-    is its diagonal block's slice of rows i and j (``rij``), turned
-    into a column through that [rows, rows] block's diagonal."""
-    N = d2.shape[0]
-    n_tiles = N // rows
-    pltpu.sync_copy(d2_hbm.at[pl.program_id(0)], d2)
-    mask, k_target = mask_ref[0], k_ref[0]                  # [1,N], [1,1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
-    tile_row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    diag = tile_row == jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
-    col_row = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
+def _first2(hit, col, n: int):
+    """First True flat index of a [S, W] mask (``n`` if none): [1, 1]."""
+    return jnp.min(_first(hit, col, n, axis=1), axis=0, keepdims=True)
 
-    def tile_at(t):
-        r0 = pl.multiple_of(t * rows, rows)
-        return r0, d2[pl.ds(r0, rows), :]
 
-    def set_minima(r0, tile):
-        m = jnp.min(tile, axis=1, keepdims=True)              # [rows, 1]
-        rmin[pl.ds(r0, rows), :] = m
-        rarg[pl.ds(r0, rows), :] = _first(tile == m, lane, N, axis=1)
+def _min2(x):
+    """Minimum of a [S, W] array: [1, 1]."""
+    return jnp.min(jnp.min(x, axis=1, keepdims=True), axis=0, keepdims=True)
 
-    def init_tile(t, _):
-        set_minima(*tile_at(t))
+
+def _sum2(x):
+    """Sum of a [S, W] array: [1, 1]."""
+    return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)
+
+
+def _at(x, hit):
+    """The one entry of a [S, W] array where ``hit``: [1, 1] (exact:
+    one value plus zeros)."""
+    return _sum2(jnp.where(hit, x, jnp.zeros_like(x)))
+
+
+def _transpose_broadcast(v):
+    """A [1, W] row -> [W, W] with ``out[t, :] = v[t]``, or a [W, 1]
+    column -> [W, W] with ``out[:, t] = v[t]``: exact copies through a
+    transpose."""
+    W = max(v.shape)
+    return jnp.transpose(jnp.broadcast_to(v, (W, W)))
+
+
+def _ward_stripes_kernel(steps_ref, d2_hbm, mask_ref, k_ref, o_ref, n_ref,
+                         d2, rmin, rarg, sizes, *, lanes: int):
+    """One doc. Its [N, N] matrix is copied once into VMEM as S = N / W
+    column stripes of W lanes, stacked: ``d2[c * N + r, l]`` is entry
+    (r, c * W + l). Column c * W + l is lane l of stripe c; row r is
+    the strided sublanes ``r, N + r, ...``, which read as [S, W] — the
+    layout of every per-column vector here (flat index s * W + l).
+
+    The matrix is kept whole and symmetric, and ``rmin``/``rarg`` hold
+    every row's minimum and the first column holding it, exactly. A
+    merge then touches O(N) values:
+
+      * select: the first row holding the global minimum, its ``rarg``
+        — ``argmin(d2.reshape(-1))``, the reference's flat row-major
+        tie-break;
+      * Lance-Williams on rows i and j, the reference's expression op
+        for op; row and column i take the new distances, row and
+        column j +inf (a column through its stripe, with the row
+        turned into sublanes by a transpose — an exact copy);
+      * minima: only entries (k, i) and (k, j) of another row k
+        changed, so with v = new[k] its minimum becomes (v, i) if v is
+        lower, its first column i if v ties and i comes first, or i if
+        its old first column was i or j and v ties (no column before j
+        held the minimum). If that column was i or j and v is higher,
+        row k is read again (a rescan, counted in ``n_ref``).
+
+    Merges the reference skips (k reached, or only +inf left) write
+    nothing."""
+    N, W = d2_hbm.shape[1], lanes
+    S = N // W
+    b = pl.program_id(0)
+    for c in range(S):
+        pltpu.sync_copy(d2_hbm.at[b, :, pl.ds(c * W, W)],
+                        d2.at[pl.ds(c * N, N), :])
+    col = (jax.lax.broadcasted_iota(jnp.int32, (S, W), 0) * W
+           + jax.lax.broadcasted_iota(jnp.int32, (S, W), 1))
+    lane_w = jax.lax.broadcasted_iota(jnp.int32, (W, W), 1)
+    inf_row = jnp.full((S, W), _INF, jnp.float32)
+
+    def read_row(r):
+        return d2[pl.ds(r, S, stride=N), :]
+
+    def write_row(r, row):
+        d2[pl.ds(r, S, stride=N), :] = row
+
+    # initial minima, one walk: per block of W rows, stripe by stripe
+    # (a strictly lower minimum wins, so the first column is kept)
+    def init_block(t, _):
+        r0 = pl.multiple_of(t * W, W)
+
+        def scan(c, best):
+            tile = d2[pl.ds(c * N + r0, W), :]                # [W, W]
+            m = jnp.min(tile, axis=1, keepdims=True)
+            a = _first(tile == m, lane_w, W, axis=1) + c * W
+            lt = m < best[0]
+            return jnp.where(lt, m, best[0]), jnp.where(lt, a, best[1])
+
+        m, a = jax.lax.fori_loop(
+            0, S, scan, (jnp.full((W, 1), _INF, jnp.float32),
+                         jnp.zeros((W, 1), jnp.int32)))
+        rmin[pl.ds(t, 1), :] = _transpose_broadcast(m)[0:1]
+        rarg[pl.ds(t, 1), :] = _transpose_broadcast(a)[0:1]
         return 0
 
-    jax.lax.fori_loop(0, n_tiles, init_tile, 0)
+    jax.lax.fori_loop(0, S, init_block, 0)
+    mask = mask_ref[0]                                        # [S, W]
+    sizes[...] = jnp.where(mask != 0, 1.0, 0.0)
+    o_ref[0] = col
+    k_target = k_ref[0]                                       # [1, 1]
 
-    def at(row, i):
-        return jnp.sum(jnp.where(lane == i, row, 0.0), axis=1, keepdims=True)
-
-    def step(_, state):
-        sizes, assign, n_active = state
-        rm = rmin[...]                                        # [N, 1]
-        dij = jnp.min(rm, axis=0, keepdims=True)              # [1, 1]
-        i0 = _first(rm == dij, col_row, N, axis=0)
-        j0 = jnp.sum(jnp.where(col_row == i0, rarg[...], 0), axis=0,
-                     keepdims=True)
-        i, j = jnp.minimum(i0, j0), jnp.maximum(i0, j0)
-
-        def rows_ij(t, carry):
-            d2i, d2j = carry
-            r0, tile = tile_at(t)
-            r = r0 + tile_row
-            return (jnp.minimum(d2i, jnp.min(jnp.where(r == i, tile, _INF),
-                                             axis=0, keepdims=True)),
-                    jnp.minimum(d2j, jnp.min(jnp.where(r == j, tile, _INF),
-                                             axis=0, keepdims=True)))
-
-        inf_row = jnp.full((1, N), _INF, jnp.float32)
-        d2i, d2j = jax.lax.fori_loop(0, n_tiles, rows_ij, (inf_row, inf_row))
-        do = (n_active > k_target) & jnp.isfinite(dij)
-        si, sj = at(sizes, i), at(sizes, j)
-        sc = sizes
+    def merge(i, j, iv, jv, dij):
+        d2i, d2j = read_row(i), read_row(j)
+        sz = sizes[...]
+        si, sj = _at(sz, col == iv), _at(sz, col == jv)
+        sc = sz
         denom = si + sj + sc
-        new_row = ((si + sc) * d2i + (sj + sc) * d2j
-                   - sc * dij) / jnp.maximum(denom, 1e-9)
-        oh_i, oh_j = lane == i, lane == j
+        # Lance-Williams (squared Ward form), same guard as the ref
+        new = ((si + sc) * d2i + (sj + sc) * d2j
+               - sc * dij) / jnp.maximum(denom, 1e-9)
+        oh_i, oh_j = col == iv, col == jv
         was_inf = jnp.isinf(d2i) | jnp.isinf(d2j)
-        new_row = jnp.where(was_inf | oh_i | oh_j, _INF, new_row)
-        row_i = jnp.where(do, new_row, d2i)
-        row_j = jnp.where(do, _INF, d2j)
-        rij[0:1, :] = row_i
-        rij[1:2, :] = row_j
+        new = jnp.where(was_inf | oh_i | oh_j, _INF, new)
+        write_row(i, new)
+        write_row(j, inf_row)
+        ci, li = i // W, i % W
+        for s in range(S):
+            rows = pl.ds(pl.multiple_of(ci * N + s * W, W), W)
+            d2[rows, :] = jnp.where(lane_w == li,
+                                    _transpose_broadcast(new[s:s + 1]),
+                                    d2[rows, :])
+        cj = pl.ds(pl.multiple_of(j // W * N, N), N)
+        d2[cj, :] = jnp.where(lane_w[0:1] == j % W, _INF, d2[cj, :])
+        sizes[...] = jnp.where(oh_i, si + sj, jnp.where(oh_j, 0.0, sz))
+        o_ref[0] = jnp.where(o_ref[0] == jv, iv, o_ref[0])
 
-        def update(t, _):
-            r0, tile = tile_at(t)
-            r = r0 + tile_row
-            seg = rij[:, pl.ds(r0, rows)]                     # [8, rows]
-            col_i = jnp.min(jnp.where(diag, seg[0:1], _INF), axis=1,
-                            keepdims=True)
-            col_j = jnp.min(jnp.where(diag, seg[1:2], _INF), axis=1,
-                            keepdims=True)
-            r_i, r_j = r == i, r == j
-            tile = jnp.where(r_j | oh_j, jnp.where(r_j, row_j, col_j),
-                             jnp.where(r_i, row_i,
-                                       jnp.where(oh_i, col_i, tile)))
-            d2[pl.ds(r0, rows), :] = tile
-            set_minima(r0, tile)
-            return 0
+        rm, ra = rmin[...], rarg[...]
+        lt, eq = new < rm, new == rm
+        hit = (ra == iv) | (ra == jv)
+        mi = _min2(new)
+        rmin[...] = jnp.where(oh_i, mi, jnp.where(oh_j, _INF,
+                                                 jnp.where(lt, new, rm)))
+        rarg[...] = jnp.where(
+            oh_i, _first2(new == mi, col, N),
+            jnp.where(oh_j, 0, jnp.where(lt | (eq & (hit | (iv < ra))),
+                                         iv, ra)))
+        marked = jnp.where(hit & (new > rm) & ~oh_i & ~oh_j, 1, 0)
 
-        jax.lax.fori_loop(0, n_tiles, update, 0)
-        sizes = jnp.where(do, jnp.where(oh_i, si + sj,
-                                        jnp.where(oh_j, 0.0, sizes)), sizes)
-        assign = jnp.where(do & (assign == j), i, assign)
-        n_active = jnp.where(do, n_active - 1, n_active)
-        return sizes, assign, n_active
+        def rescan(c):
+            k, marked, n = c                                  # k: scalar
+            row = read_row(k)
+            m = _min2(row)
+            at_k = col == k
+            rmin[...] = jnp.where(at_k, m, rmin[...])
+            rarg[...] = jnp.where(at_k, _first2(row == m, col, N), rarg[...])
+            marked = jnp.where(at_k, 0, marked)
+            return _first2(marked > 0, col, N)[0, 0], marked, n + 1
 
-    state = (jnp.where(mask != 0, 1.0, 0.0), lane,
-             jnp.sum(mask, axis=1, keepdims=True))
-    o_ref[0] = jax.lax.fori_loop(0, steps_ref[pl.program_id(0)], step,
-                                 state)[1]
+        return jax.lax.while_loop(
+            lambda c: c[0] < N, rescan,
+            (_first2(marked > 0, col, N)[0, 0], marked, jnp.int32(0)))[2]
+
+    def step(_, carry):
+        n_active, n_rescans = carry                   # [1, 1], scalar
+        rm = rmin[...]
+        dij = _min2(rm)
+        i0 = _first2(rm == dij, col, N)
+        j0 = _at(rarg[...], col == i0)
+        do = (n_active > k_target) & jnp.isfinite(dij)
+        # a skipped merge reads i = N
+        iv = jnp.where(do, jnp.minimum(i0, j0), N)
+        jv = jnp.maximum(i0, j0)
+        i = iv[0, 0]
+        n = jax.lax.cond(i < N, lambda: merge(i, jv[0, 0], iv, jv, dij),
+                         lambda: jnp.int32(0))
+        return (jnp.where(do, n_active - 1, n_active), n_rescans + n)
+
+    _, n = jax.lax.fori_loop(
+        0, steps_ref[b], step,
+        (_sum2(mask), jnp.int32(0)))
+    n_ref[0] = jnp.full((1, 1), n, jnp.int32)
 
 
-def rows_vmem_bytes(N: int, rows: int) -> int:
-    """VMEM the long-doc kernel asks for: the resident [N, N] f32 matrix,
-    its two [N, 1] minima caches (lane-padded to 128), rows i and j, and
-    about a dozen live [rows, N] f32 tile temporaries, with 4 MiB to
-    spare."""
-    return (4 * N * N + 2 * 4 * 128 * N + 4 * 8 * N + 12 * 4 * rows * N
-            + (4 << 20))
+def stripes_vmem_bytes(N: int, lanes: int) -> int:
+    """VMEM the long-doc kernel asks for: the resident [N, N] f32
+    matrix, a few live [N, lanes] stripe temporaries, with 4 MiB to
+    spare (the per-column vectors are [N / lanes, lanes], a few KiB)."""
+    return 4 * N * N + 4 * 4 * N * lanes + (4 << 20)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
-def ward_pool_rows_pallas(d2, mask, k, steps, *, rows: int = 128,
+@functools.partial(jax.jit, static_argnames=("lanes", "interpret"))
+def ward_pool_rows_pallas(d2, mask, k, steps, *, lanes: int = 128,
                           interpret: bool = False):
-    """The long-doc kernel: same arguments and result as
-    ``ward_pool_pallas`` with one doc per program (``steps`` [B], each
-    doc's own merge budget); N must be a multiple of ``rows``."""
+    """The long-doc kernel, one doc per program.
+
+    Args:
+      d2: [B, N, N] f32 initial distances (``core.ward.ward_distances``);
+        N a multiple of ``lanes`` and of 8.
+      mask: [B, N // lanes, lanes] int32 emit mask (1 = valid).
+      k: [B, 1, 1] int32 per-doc cluster target.
+      steps: [B] int32 each doc's merge budget (``n_valid - k``).
+    Returns (assign [B, N // lanes, lanes] int32, bitwise
+    ``ward_cluster_batch``'s, flattened row-major; rescans [B, 1, 1]
+    int32, the rows each doc read again to keep its row minima).
+    """
     B, N, _ = d2.shape
-    assert N % rows == 0, (N, rows)
+    S = N // lanes
+    assert N == S * lanes and N % 8 == 0, (N, lanes)
     one = lambda b, s: (b, 0, 0)
     return pl.pallas_call(
-        functools.partial(_ward_rows_kernel, rows=rows),
+        functools.partial(_ward_stripes_kernel, lanes=lanes),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B,),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec((1, 1, N), one),
+                      pl.BlockSpec((1, S, lanes), one),
                       pl.BlockSpec((1, 1, 1), one)],
-            out_specs=pl.BlockSpec((1, 1, N), one),
-            scratch_shapes=[pltpu.VMEM((N, N), jnp.float32),
-                            pltpu.VMEM((N, 1), jnp.float32),
-                            pltpu.VMEM((N, 1), jnp.int32),
-                            pltpu.VMEM((8, N), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B, 1, N), jnp.int32),
+            out_specs=[pl.BlockSpec((1, S, lanes), one),
+                       pl.BlockSpec((1, 1, 1), one)],
+            scratch_shapes=[pltpu.VMEM((S * N, lanes), jnp.float32),
+                            pltpu.VMEM((S, lanes), jnp.float32),
+                            pltpu.VMEM((S, lanes), jnp.int32),
+                            pltpu.VMEM((S, lanes), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((B, S, lanes), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1, 1), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=rows_vmem_bytes(N, rows)),
+            vmem_limit_bytes=stripes_vmem_bytes(N, lanes)),
         interpret=interpret,
     )(steps, d2, mask, k)

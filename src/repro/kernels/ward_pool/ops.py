@@ -15,12 +15,15 @@ width N and a VMEM budget (``ward_block_b``): the resident kernel keeps
 ``block_b`` whole [N, N] matrices with the merge step's [N, N]
 temporaries (8 f32 copies a doc, input double-buffering included), up
 to 8 docs; where not even one doc fits the budget (N > 724), the
-long-doc kernel holds one doc's matrix in VMEM and walks it in row
-tiles of 128 (``ward_pool_rows_pallas``).
+long-doc kernel holds one doc's matrix in VMEM as column stripes of 128
+lanes and keeps every row's minimum exact, so a merge touches rows i
+and j, two stripes and the few rows it must read again, not the whole
+matrix (``ward_pool_rows_pallas``).
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -36,7 +39,7 @@ WARD_IMPLS = ("auto", "kernel", "ref")
 VMEM_BUDGET = 16 << 20      # bytes: v5e's default scoped VMEM limit
 RESIDENT_COPIES = 8         # f32 [N, N] copies the resident kernel holds
 MAX_BLOCK_B = 8
-ROWS = 128                  # row tile of the long-doc kernel
+LANES = 128                 # column stripe width of the long-doc kernel
 
 
 def resident(N: int) -> bool:
@@ -61,49 +64,63 @@ def resolve_impl(impl: str) -> str:
     return impl
 
 
-@functools.partial(jax.jit, static_argnames=("factor", "block_b", "rows"))
+@functools.partial(jax.jit,
+                   static_argnames=("factor", "block_b", "lanes"))
 def _ward_assign_kernel(x, mask, factor: int, block_b: int = 8,
-                        rows: int = 0):
+                        lanes: int = 0):
+    """-> (assign [B, N], rescans [B]: the long-doc kernel's count of
+    rows read again; None from the resident kernel)."""
     B, N = mask.shape
     # the reference's own initial distances, so both paths merge
     # identical values; padded docs and tokens are masked (all +inf)
     d2 = jax.vmap(ward_distances)(x, mask)
-    mp = mask
-    if rows:
-        d2 = _pad_to(_pad_to(d2, 1, rows, value=jnp.inf), 2, rows,
+    if lanes:
+        mult = lanes * 8 // math.gcd(lanes, 8)
+        d2 = _pad_to(_pad_to(d2, 1, mult, value=jnp.inf), 2, mult,
                      value=jnp.inf)
-        mp = _pad_to(mask, 1, rows)
+        mp = _pad_to(mask, 1, mult)
     else:
         d2 = _pad_to(d2, 0, block_b, value=jnp.inf)
         mp = _pad_to(mask, 0, block_b)
-    n_valid = jnp.sum(mp.astype(jnp.int32), axis=-1)
+    mp = mp.astype(jnp.int32)
+    n_valid = jnp.sum(mp, axis=-1)
     k = jnp.maximum(n_valid // factor + 1, 1)
     steps = jnp.maximum(n_valid - k, 0)
-    args = (d2, mp.astype(jnp.int32)[:, None, :], k[:, None, None])
-    if rows:
-        out = ward_pool_rows_pallas(*args, steps, rows=rows,
-                                    interpret=not _on_tpu())
-    else:
-        out = ward_pool_pallas(*args, steps.reshape(-1, block_b).max(axis=1),
-                               block_b=block_b, interpret=not _on_tpu())
-    return out[:B, 0, :N]
+    if lanes:
+        out, rescans = ward_pool_rows_pallas(
+            d2, mp.reshape(B, -1, lanes), k[:, None, None], steps,
+            lanes=lanes, interpret=not _on_tpu())
+        return out.reshape(B, -1)[:, :N], rescans[:, 0, 0]
+    out = ward_pool_pallas(d2, mp[:, None, :], k[:, None, None],
+                           steps.reshape(-1, block_b).max(axis=1),
+                           block_b=block_b, interpret=not _on_tpu())
+    return out[:B, 0, :N], None
 
 
 def ward_assign(x, mask, factor: int, *, impl: str = "auto",
-                block_b: Optional[int] = None, rows: Optional[int] = None):
+                block_b: Optional[int] = None, lanes: Optional[int] = None):
     """Batched Ward cluster assignments, reference-bitwise.
 
     x [B, N, d], mask [B, N] -> assign [B, N] int32 where each valid
     token's id is its cluster's representative (lowest) token index —
     the exact contract of ``ward_cluster_batch``. ``block_b`` (resident
-    kernel) and ``rows`` (long-doc kernel) default to what N gives
-    (module doc); ``rows`` chooses the long-doc kernel.
+    kernel) and ``lanes`` (long-doc kernel) default to what N gives
+    (module doc); ``lanes`` chooses the long-doc kernel.
     """
     N = mask.shape[1]
-    if rows is None:
-        rows = 0 if resident(N) else ROWS
+    if lanes is None:
+        lanes = 0 if resident(N) else LANES
     with jax.named_scope("ward"):       # op metadata only
         if resolve_impl(impl) == "ref":
             return ward_assign_ref(x, mask, factor)
         return _ward_assign_kernel(x, mask, int(factor),
-                                   block_b or ward_block_b(N), rows)
+                                   block_b or ward_block_b(N), lanes)[0]
+
+
+def ward_rescans(x, mask, factor: int, *, lanes: int = LANES):
+    """The long-doc kernel at any N: (assign [B, N], rescans [B] int32,
+    each doc's rows read again to keep its row minima exact). For tests
+    and the chip smoke; the build path drops the counts (reading them
+    back would stall each batch on the host)."""
+    with jax.named_scope("ward"):
+        return _ward_assign_kernel(x, mask, int(factor), 1, lanes)

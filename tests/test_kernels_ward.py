@@ -156,14 +156,15 @@ def test_block_b_follows_n_and_the_budget():
 
 @pytest.mark.parametrize("N,kw", [(40, dict(block_b=1)),
                                   (40, dict(block_b=3)),
-                                  (40, dict(rows=8)),
-                                  (64, dict(rows=16)),
-                                  (50, dict(rows=16))])   # N padded to 64
+                                  (40, dict(lanes=8)),
+                                  (64, dict(lanes=16)),
+                                  (50, dict(lanes=16))])  # N padded to 64
 @pytest.mark.parametrize("factor", [2, 3])
 def test_kernels_bitwise_at_each_block(N, kw, factor):
     """The resident kernel at 1 and 3 docs a program and the long-doc
-    kernel (one doc a program, row tiles of 8 or 16) assign bitwise
-    like ``ward_cluster_batch``: ties, short, holed and empty docs."""
+    kernel (one doc a program, column stripes of 8 or 16 lanes, so rows
+    i and j fall in different stripes) assign bitwise like
+    ``ward_cluster_batch``: ties, short, holed and empty docs."""
     rng = np.random.default_rng(N + factor)
     B, d = 5, 16
     x = rng.normal(size=(B, N, d)).astype(np.float32)
@@ -176,6 +177,146 @@ def test_kernels_bitwise_at_each_block(N, kw, factor):
     want = np.asarray(ward_cluster_batch(x, mask, factor))
     got = np.asarray(ward_assign(x, mask, factor, impl="kernel", **kw))
     np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
+# the long-doc kernel: row minima kept by rules, not by walking the matrix
+# ---------------------------------------------------------------------------
+@jax.jit
+def _lance_williams(si, sj, sc, d2i, d2j, dij):
+    """The reference's expression, compiled as XLA compiles it (NumPy
+    rounds its products and sums differently)."""
+    return ((si + sc) * d2i + (sj + sc) * d2j
+            - sc * dij) / jnp.maximum(si + sj + sc, 1e-9)
+
+
+def _rescan_model(d2, mask, factor):
+    """NumPy model of one doc's merges under the long-doc kernel's
+    row-minimum rules -> (assign, rescans). It asserts after every merge
+    that the kept minima and first columns are the matrix's own, and
+    that the merge picked is the reference's flat argmin."""
+    d2, N = d2.copy(), len(d2)
+    sizes = mask.astype(np.float32)
+    assign = np.arange(N, dtype=np.int32)
+    n_valid = int(mask.sum())
+    k = max(n_valid // factor + 1, 1)
+    rmin, rarg = d2.min(1), d2.argmin(1).astype(np.int32)
+    rescans = 0
+    for _ in range(max(n_valid - k, 0)):
+        i0 = int(np.argmin(rmin))
+        i, j = sorted((i0, int(rarg[i0])))
+        assert (i0, rarg[i0]) == divmod(int(np.argmin(d2)), N)
+        dij = rmin[i0]
+        if not np.isfinite(dij):
+            continue
+        si, sj, sc = sizes[i], sizes[j], sizes
+        new = np.array(_lance_williams(si, sj, sc, d2[i], d2[j], dij))
+        new[np.isinf(d2[i]) | np.isinf(d2[j])] = np.inf
+        new[[i, j]] = np.inf
+        d2[i], d2[:, i], d2[j], d2[:, j] = new, new, np.inf, np.inf
+        sizes[i], sizes[j] = si + sj, 0.0
+        assign[assign == j] = i
+        hit = (rarg == i) | (rarg == j)
+        lt, eq = new < rmin, new == rmin
+        redo = np.flatnonzero(hit & (new > rmin))
+        redo = redo[(redo != i) & (redo != j)]
+        rarg = np.where(lt | (eq & (hit | (i < rarg))), i, rarg)
+        rmin = np.where(lt, new, rmin)
+        rmin[i], rarg[i] = new.min(), new.argmin()
+        rmin[j], rarg[j] = np.inf, 0
+        for r in redo:
+            rmin[r], rarg[r] = d2[r].min(), d2[r].argmin()
+        rescans += len(redo)
+        np.testing.assert_array_equal(rmin, d2.min(1))
+        np.testing.assert_array_equal(rarg, d2.argmin(1))
+    return assign, rescans
+
+
+def _docs(B, N, d, seed, groups=0):
+    """Seeded docs: random vectors, or (``groups``) that many distinct
+    vectors each repeated, so most distances tie; with a short, a holed
+    and an empty doc."""
+    rng = np.random.default_rng(seed)
+    if groups:
+        base = rng.normal(size=(B, groups, d)).astype(np.float32)
+        x = base[:, rng.integers(0, groups, size=N)]
+    else:
+        x = rng.normal(size=(B, N, d)).astype(np.float32)
+    mask = np.ones((B, N), bool)
+    mask[1, N // 2:] = False
+    mask[2, ::3] = False
+    mask[3] = False
+    return jnp.asarray(x), jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("groups", [3, 7])
+@pytest.mark.parametrize("factor", [2, 4])
+def test_long_doc_kernel_ties_bitwise(groups, factor):
+    """Groups of identical vectors: zero distances and equal
+    Lance-Williams values everywhere, so the tie rules (``v == rmin``,
+    ``i < rarg``) and rescans at equal values decide the merge order."""
+    from repro.kernels.ward_pool.ops import ward_rescans
+    x, mask = _docs(5, 48, 8, groups + factor, groups=groups)
+    want = np.asarray(ward_cluster_batch(x, mask, factor))
+    got, rescans = ward_rescans(x, mask, factor, lanes=8)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert np.asarray(rescans).sum() > 0
+
+
+@pytest.mark.parametrize("groups", [0, 5])
+@pytest.mark.parametrize("factor", [2, 3])
+def test_long_doc_rescans_match_numpy_model(groups, factor):
+    """The kernel's per-doc rescan count equals the NumPy model's, and
+    both assign like the reference."""
+    from repro.core.ward import ward_distances
+    from repro.kernels.ward_pool.ops import ward_rescans
+    x, mask = _docs(5, 40, 16, 11 * factor + groups, groups=groups)
+    got, rescans = ward_rescans(x, mask, factor, lanes=8)
+    want = np.asarray(ward_cluster_batch(x, mask, factor))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # compiled, as the kernel's wrapper and the reference compute them:
+    # run op by op, the matmul can round near-zero distances otherwise
+    d2 = np.asarray(jax.jit(jax.vmap(ward_distances))(x, mask))
+    model = [_rescan_model(d2[b], np.asarray(mask[b]), factor)
+             for b in range(len(d2))]
+    np.testing.assert_array_equal(np.stack([a for a, _ in model]), want)
+    assert np.asarray(rescans).tolist() == [n for _, n in model]
+
+
+def _merge_loop_ref(d2, mask, k, steps):
+    """``core/ward.py``'s merge step, run ``steps`` times from given
+    distances."""
+    from repro.core.ward import _merge_once
+
+    def one(d2, mask, k):
+        state = (d2, jnp.where(mask, 1.0, 0.0), jnp.arange(len(mask)),
+                 jnp.sum(mask.astype(jnp.int32)))
+        body = lambda _, s: _merge_once(*s, k)
+        return jax.lax.fori_loop(0, steps, body, state)[2]
+
+    return np.asarray(jax.vmap(one)(d2, mask, k))
+
+
+def test_long_doc_kernel_when_finite_pairs_run_out():
+    """Two groups of tokens with no finite distance between them and a
+    target of one cluster: the merges run out of finite pairs before
+    the budget, and the rest must be no-ops, like the reference's."""
+    from repro.core.ward import ward_distances
+    from repro.kernels.ward_pool.kernel import ward_pool_rows_pallas
+    x, mask = _docs(4, 32, 8, 3)
+    x, mask = x[:2], jnp.ones_like(mask[:2])
+    d2 = jax.vmap(ward_distances)(x, mask)
+    group = np.arange(32) % 3 == 0
+    apart = group[:, None] != group[None, :]
+    d2 = jnp.where(apart, jnp.inf, d2)
+    steps = np.int32(31)
+    k = jnp.ones((2,), jnp.int32)
+    want = _merge_loop_ref(d2, mask, k, steps)
+    assert len(set(want[0].tolist())) == 2          # two clusters left
+    got, rescans = ward_pool_rows_pallas(
+        d2, mask.astype(jnp.int32).reshape(2, 4, 8), k[:, None, None],
+        jnp.full((2,), steps), lanes=8, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got).reshape(2, 32), want)
 
 
 # ---------------------------------------------------------------------------

@@ -76,10 +76,11 @@ def test_ward_pool_compiles(one_chip):
 
 def test_ward_pool_long_docs_compiles(one_chip):
     """N = 2048: one doc's [2048, 2048] f32 matrix (16 MiB) resident in
-    VMEM, walked in row tiles of 128, within the kernel's VMEM limit."""
+    VMEM as 16 column stripes of 128 lanes, rows read and written at a
+    dynamic sublane stride, within the kernel's VMEM limit."""
     B, N = 16, 2048
     _compile(ward_pool_rows_pallas, one_chip,
-             ((B, N, N), F32), ((B, 1, N), I32), ((B, 1, 1), I32),
+             ((B, N, N), F32), ((B, N // 128, 128), I32), ((B, 1, 1), I32),
              ((B,), I32))
 
 
