@@ -15,14 +15,12 @@ TRUNK = TransformerConfig(
     n_kv_heads=12,
     d_ff=3072,
     vocab_size=30522,
-    causal=False,
     pos_emb="learned",
     gated_mlp=False,
     act="gelu",
     norm="layernorm",
     norm_eps=1e-12,
     max_seq_len=512,
-    attn_shard="heads",
     attn_full_threshold=4096,
 )
 
@@ -42,14 +40,12 @@ JA_TRUNK = TransformerConfig(
     n_kv_heads=12,
     d_ff=3072,
     vocab_size=32768,
-    causal=False,
     pos_emb="learned",
     gated_mlp=False,
     act="gelu",
     norm="layernorm",
     norm_eps=1e-12,
     max_seq_len=512,
-    attn_shard="heads",
     attn_full_threshold=4096,
 )
 
@@ -69,7 +65,6 @@ SMOKE_TRUNK = TransformerConfig(
     n_kv_heads=4,
     d_ff=128,
     vocab_size=1024,
-    causal=False,
     pos_emb="learned",
     gated_mlp=False,
     act="gelu",

@@ -26,7 +26,6 @@ TRUNK = TransformerConfig(
     n_kv_heads=12,
     d_ff=1152,
     vocab_size=50368,
-    causal=False,
     pos_emb="rope",
     rope_theta=160_000.0,
     local_rope_theta=10_000.0,
@@ -64,7 +63,6 @@ SMOKE_TRUNK = TransformerConfig(
     n_kv_heads=4,
     d_ff=96,
     vocab_size=1024,
-    causal=False,
     pos_emb="rope",
     rope_theta=160_000.0,
     local_rope_theta=10_000.0,

@@ -1,6 +1,6 @@
 from repro.data.tokenizer import HashTokenizer
 from repro.data.corpus import SyntheticRetrievalCorpus, DATASET_SPECS
-from repro.data.pipeline import DataPipeline, lm_batches
+from repro.data.pipeline import DataPipeline
 
 __all__ = ["HashTokenizer", "SyntheticRetrievalCorpus", "DATASET_SPECS",
-           "DataPipeline", "lm_batches"]
+           "DataPipeline"]

@@ -72,24 +72,3 @@ class DataPipeline:
                 yield batch
         finally:
             stop.set()
-
-
-def lm_batches(tokens: np.ndarray, batch_size: int, seq_len: int,
-               seed: int = 0, start_step: int = 0) -> Iterator[Dict]:
-    """Fixed-shape causal-LM batches from a flat token stream.
-
-    tokens: [N] int32. Yields {tokens [B, S], labels [B, S]} (labels are
-    tokens shifted left; last position predicts the next stream token).
-    """
-    n_seq = (len(tokens) - 1) // seq_len
-
-    def make(ids):
-        b_tok = np.stack([tokens[i * seq_len:(i + 1) * seq_len]
-                          for i in ids])
-        b_lab = np.stack([tokens[i * seq_len + 1:(i + 1) * seq_len + 1]
-                          for i in ids])
-        return {"tokens": b_tok.astype(np.int32),
-                "labels": b_lab.astype(np.int32)}
-
-    pipe = DataPipeline(n_seq, batch_size, make, seed=seed)
-    return pipe.batches(start_step=start_step)

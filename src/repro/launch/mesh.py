@@ -1,24 +1,14 @@
-"""Production mesh builders.
-
-Single pod: (data=16, model=16) = 256 chips (one v5e pod).
-Multi-pod:  (pod=2, data=16, model=16) = 512 chips; the ``pod`` axis is
-pure data parallelism across pods (gradients all-reduce over
-("pod", "data") — DCN-friendly: only one collective crosses pods).
+"""Mesh builders: the local host mesh, and the serving mesh and device
+table that ``core/replicated.py`` places index shards on.
 
 Functions, not module constants: importing this module must never touch
-jax device state (the dry-run sets XLA_FLAGS before first jax init).
+jax device state (a caller may set XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
 from typing import List
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh():
@@ -83,13 +73,3 @@ def distinct_row(row) -> bool:
     """True when a replica group's device row has no reuse — the
     precondition for building a real shard mesh over it."""
     return len({d.id for d in row}) == len(row)
-
-
-def batch_axes(mesh) -> object:
-    """The data-parallel axis spec for this mesh ('data' or (pod, data))."""
-    return ("pod", "data") if "pod" in mesh.axis_names else "data"
-
-
-def fsdp_axes(mesh) -> object:
-    """Weight-sharding (ZeRO) axes: same as the DP axes."""
-    return batch_axes(mesh)

@@ -1,6 +1,8 @@
 """ColBERT encoder (Khattab & Zaharia, 2020): late-interaction over BERT.
 
-Wraps any bidirectional ``TransformerConfig`` trunk with the ColBERT head:
+Puts the ColBERT head on the encoder trunk (``models/transformer.py``:
+BERT-style learned positions for ColBERTv2, RoPE and alternating
+global/local layers for ModernBERT):
 
   * ``[Q]``/``[D]`` marker token inserted after [CLS] (query vs document).
   * Queries are *expanded*: padded to ``query_maxlen`` with [MASK] tokens
@@ -18,14 +20,12 @@ module never changes, exactly the paper's "no architectural change" claim.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.models.layers import dense, dt, init_dense
 from repro.models.transformer import forward, init_transformer
-from repro.sharding.api import constrain
 
 # Special token ids (see data/tokenizer.py — shared vocabulary layout)
 PAD_ID, CLS_ID, SEP_ID, MASK_ID, Q_MARK_ID, D_MARK_ID = 0, 1, 2, 3, 4, 5
@@ -48,12 +48,11 @@ def _encode(params, tokens, cfg, pad_mask):
     scope ``encoder`` (``encoder/attention``, ``encoder/mlp`` per layer)
     in their metadata, for the device trace."""
     with jax.named_scope("encoder"):
-        hidden, _ = forward(params["trunk"], tokens, cfg.trunk,
-                            pad_mask=pad_mask)
+        hidden = forward(params["trunk"], tokens, cfg.trunk,
+                         pad_mask=pad_mask)
         v = dense(params["proj"], hidden).astype(jnp.float32)
-        v = v / jnp.maximum(jnp.linalg.norm(v, axis=-1, keepdims=True),
-                            1e-9)
-        return constrain(v, "batch", "seq", None)
+        return v / jnp.maximum(jnp.linalg.norm(v, axis=-1, keepdims=True),
+                               1e-9)
 
 
 def prepare_query_tokens(tokens, query_maxlen: int, cls_id: int = CLS_ID,

@@ -30,12 +30,6 @@ def trunc_normal(key, shape, std=0.02, dtype=jnp.float32):
             * std).astype(dtype)
 
 
-def lecun_normal(key, shape, fan_in=None, dtype=jnp.float32):
-    fan_in = fan_in if fan_in is not None else shape[0]
-    std = 1.0 / np.sqrt(max(fan_in, 1))
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
 # ---------------------------------------------------------------------------
 # Dense
 # ---------------------------------------------------------------------------
@@ -132,15 +126,6 @@ def act_fn(name):
 # ---------------------------------------------------------------------------
 # Tree utilities
 # ---------------------------------------------------------------------------
-def tree_size(tree) -> int:
-    return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(tree))
-
-
-def tree_bytes(tree) -> int:
-    return sum(int(np.prod(x.shape)) * x.dtype.itemsize
-               for x in jax.tree_util.tree_leaves(tree))
-
-
 def tree_paths(tree):
     """Yield ('a/b/c', leaf) pairs for a nested dict/list pytree."""
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)

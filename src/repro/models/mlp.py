@@ -1,11 +1,10 @@
-"""Dense FFN: SwiGLU-style gated or plain 2-layer MLP."""
+"""Dense FFN: SwiGLU/GeGLU-style gated or plain 2-layer MLP."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
 from repro.models.layers import act_fn, dense, init_dense
-from repro.sharding.api import constrain
 
 
 def init_mlp(key, d_model, d_ff, gated=True, bias=False, dtype=jnp.float32):
@@ -20,12 +19,7 @@ def init_mlp(key, d_model, d_ff, gated=True, bias=False, dtype=jnp.float32):
 
 
 def mlp(p, x, act="silu", gated=True):
-    h = dense(p["w1"], x)
-    h = constrain(h, "batch", "seq", "ff")
-    h = act_fn(act)(h)
+    h = act_fn(act)(dense(p["w1"], x))
     if gated:
-        g = dense(p["w3"], x)
-        g = constrain(g, "batch", "seq", "ff")
-        h = h * g
-    y = dense(p["w2"], h)
-    return constrain(y, "batch", "seq", "dmodel")
+        h = h * dense(p["w3"], x)
+    return dense(p["w2"], h)

@@ -1,4 +1,4 @@
-"""Three-term roofline from compiled dry-run artifacts.
+"""Three-term roofline: compute, HBM and collective time of a program.
 
     compute term    = HLO_FLOPs / (chips x peak_FLOP/s)
     memory term     = HLO_bytes / (chips x HBM_bw)
@@ -21,8 +21,8 @@ Notes on semantics:
 from __future__ import annotations
 
 import re
-from dataclasses import dataclass, field
-from typing import Dict, Optional
+from dataclasses import dataclass
+from typing import Dict
 
 from repro.roofline import hw
 
@@ -132,34 +132,3 @@ class RooflineTerms:
 HEADER = (f"{'arch':22s} {'cell':14s} {'mesh':9s} {'compute_s':>9s} "
           f"{'memory_s':>9s} {'collect_s':>9s} {'bottleneck':10s} "
           f"{'useful':>6s} {'mfu':>6s}")
-
-
-def model_flops_lm(cfg, cell_kind: str, n_tokens: int, n_chips: int,
-                   seq_len: int = 0, batch: int = 0) -> float:
-    """MODEL_FLOPS = 6*N*D (train) / 2*N*D (fwd-only), N = active params;
-    plus exact attention term 12*L*H*dh*S per token (causal halves it).
-    Returned PER CHIP."""
-    n_active = cfg.active_param_count()
-    per_tok = (6 if cell_kind == "train" else 2) * n_active
-    attn = 0
-    if seq_len:
-        mult = 6 if cell_kind == "train" else 2
-        # qk^T + av: 2 matmuls of S x dh per head per token, causal ~ S/2
-        eff_s = seq_len / 2 if cfg.causal else seq_len
-        attn = mult * 2 * cfg.n_layers * cfg.n_heads * cfg.d_head * eff_s
-    return (per_tok + attn) * n_tokens / n_chips
-
-
-def model_flops_decode(cfg, batch: int, seq_len: int, n_chips: int) -> float:
-    """One decode step: 2*N_active per token + cache attention reads."""
-    n_active = cfg.active_param_count()
-    attn = 2 * 2 * cfg.n_layers * cfg.n_heads * cfg.d_head * seq_len
-    return (2 * n_active + attn) * batch / n_chips
-
-
-def from_dryrun(result: Dict, model_flops: float = 0.0) -> RooflineTerms:
-    return RooflineTerms(
-        arch=result["arch"], cell=result["cell"], mesh=result["mesh"],
-        flops=result["flops"], hlo_bytes=result["bytes_accessed"],
-        collective_bytes=result["collective_bytes"],
-        model_flops=model_flops)

@@ -1,9 +1,9 @@
 """Independent flop counter: parse dot-general ops out of optimized HLO.
 
 Cross-checks XLA's cost_analysis (which undercounts while-loop bodies —
-they are counted ONCE regardless of trip count). In analysis mode all
-scans are unrolled, so summing every dot in the module is exact for
-matmul flops (elementwise flops are negligible for these workloads).
+they are counted ONCE regardless of trip count). Summing every dot in a
+module without loops is exact for matmul flops (elementwise flops are
+negligible for these workloads).
 
 Handles: plain `dot(...)` ops and dots inside fusion computations (each
 fusion is called once per op that references it — we count call sites).

@@ -7,10 +7,9 @@ rerank stage read (dim*4 + 1 bytes per token). Per-chip HBM traffic for
 the doc operand drops by ~(dim*4) / (4 + 4*W); the decode work moves
 on-chip as a one-hot gather matmul plus an in-register where-chain.
 
-This module prices both paths with the same three-term model
-``roofline/analysis.py`` applies to the dry-run cells, so the bytes
-ratio and the bottleneck flip (memory -> compute) land in the familiar
-report format:
+This module prices both paths with the three-term model of
+``roofline/analysis.py``, so the bytes ratio and the bottleneck flip
+(memory -> compute) land in one report format:
 
     python -m repro.roofline.run --kernel packed_rerank --json out.json
 

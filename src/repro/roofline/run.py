@@ -1,181 +1,20 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512")
+"""Roofline rows for the retrieval kernels the served path runs.
 
-DOC = """Roofline runner: baseline every (arch x shape) cell.
+    python -m repro.roofline.run --kernel packed_rerank --json packed.json
+    python -m repro.roofline.run --kernel plaid_probe --json probe.json
 
-Per cell:
-  1. scanned compile, single-pod + multi-pod  -> proves sharding coherence,
-     memory_analysis (does it fit 16 GiB HBM).
-  2. unrolled compiles at L in {2, 4} (single-pod) -> flops / bytes /
-     collective bytes, linearly extrapolated to the full layer count
-     (XLA cost_analysis counts while bodies ONCE; unrolled small-L runs
-     measure the exact per-layer marginal, which is constant by
-     construction for scanned stacks).
-  3. three roofline terms + MODEL_FLOPS (analytic 6ND/2ND) + bottleneck.
-
-Emits JSON (for EXPERIMENTS.md) and a markdown table.
-
-    python -m repro.roofline.run --arch qwen3-0.6b --json roofline.json
+``packed_rerank`` prices the fused compressed-domain rerank kernel
+against the reconstruction baseline (``roofline/packed.py``);
+``plaid_probe`` the device-resident candidate pipeline against the
+host-gather baseline (``roofline/probe.py``). Both are analytic: op and
+byte counts from the shapes, over the v5e peaks in ``roofline/hw.py``.
 """
 
 import argparse
-import dataclasses
 import json
 import sys
-import time
-import traceback
 
-from repro.configs import ASSIGNED_ARCHS, get_config
-from repro.configs.base import (ColbertConfig, DimeNetConfig, RecsysConfig,
-                                TransformerConfig)
-from repro.launch.dryrun import run_cell
-from repro.launch.input_specs import all_cells
-from repro.roofline import hw
-from repro.roofline.analysis import (HEADER, RooflineTerms,
-                                     from_dryrun)
-
-
-def _full_layers(cfg) -> int:
-    if isinstance(cfg, TransformerConfig):
-        return cfg.n_layers
-    if isinstance(cfg, DimeNetConfig):
-        return cfg.n_blocks
-    if isinstance(cfg, ColbertConfig):
-        return cfg.trunk.n_layers
-    return 0
-
-
-def _model_flops(arch: str, cell: str, n_chips: int) -> float:
-    """Analytic useful flops per chip for the cell (6ND train / 2ND fwd,
-    plus exact attention-matmul terms)."""
-    cfg = get_config(arch)
-    if isinstance(cfg, TransformerConfig):
-        from repro.configs.base import LM_SHAPES
-        c = {s.name: s for s in LM_SHAPES}[cell]
-        seq, gb = c.dim("seq_len"), c.dim("global_batch")
-        n_act = cfg.active_param_count()
-        L, Hd = cfg.n_layers, cfg.n_heads * cfg.d_head
-        if c.kind == "train":
-            toks = seq * gb
-            attn = 4 * toks * (seq / 2) * Hd * L        # qk+av, causal
-            return (6 * n_act * toks + 3 * attn) / n_chips
-        if c.kind == "prefill":
-            toks = seq * gb
-            attn = 4 * toks * (seq / 2) * Hd * L
-            return (2 * n_act * toks + attn) / n_chips
-        # decode: 1 token/seq against seq-length cache
-        attn = 4 * gb * seq * Hd * L
-        return (2 * n_act * gb + attn) / n_chips
-    if isinstance(cfg, RecsysConfig):
-        # MLP-dominated: count MLP + interaction flops analytically
-        from repro.configs.base import RECSYS_SHAPES
-        c = {s.name: s for s in RECSYS_SHAPES}[cell]
-        B = c.dim("batch")
-        D = cfg.embed_dim
-        f = 0
-        dims = None
-        if cfg.kind == "dlrm":
-            seqs = [(cfg.n_dense,) + tuple(cfg.bot_mlp_dims)]
-            n_emb = cfg.n_sparse + 1
-            d_top = n_emb * (n_emb - 1) // 2 + cfg.bot_mlp_dims[-1]
-            seqs.append((d_top,) + tuple(cfg.top_mlp_dims))
-        elif cfg.kind in ("wide_deep", "deepfm"):
-            d_in = cfg.n_sparse * D + cfg.n_dense
-            seqs = [(d_in,) + tuple(cfg.mlp_dims) + (1,)]
-        else:
-            seqs = []
-        for seq_dims in seqs:
-            for a, b in zip(seq_dims[:-1], seq_dims[1:]):
-                f += 2 * a * b
-        f += 4 * cfg.n_sparse * D                        # fm/interaction-ish
-        mult = 3 if c.kind == "train" else 1
-        total = mult * f * B
-        if cell == "retrieval_cand":
-            total += 2 * c.dim("n_candidates") * D * B
-        return total / n_chips
-    if isinstance(cfg, ColbertConfig):
-        from repro.configs.base import COLBERT_SHAPES
-        c = {s.name: s for s in COLBERT_SHAPES}[cell]
-        n_trunk = cfg.trunk.param_count() + cfg.trunk.d_model * cfg.proj_dim
-        if cell == "index_build":
-            toks = c.dim("n_docs") * c.dim("doc_len")
-            return 2 * n_trunk * toks / n_chips
-        # search: query encode + MaxSim over the sharded doc set
-        q_toks = c.dim("n_queries") * cfg.query_maxlen
-        maxsim = (2 * c.dim("n_queries") * cfg.query_maxlen
-                  * c.dim("n_docs") * c.dim("doc_len") * cfg.proj_dim)
-        return (2 * n_trunk * q_toks + maxsim) / n_chips
-    if isinstance(cfg, DimeNetConfig):
-        from repro.launch.input_specs import GNN_CELL_META, _gnn_counts
-        from repro.configs.base import GNN_SHAPES
-        c = {s.name: s for s in GNN_SHAPES}[cell]
-        N, E, T = _gnn_counts(c, cfg.triplet_cap)
-        h, nb = cfg.d_hidden, cfg.n_bilinear
-        per_edge = 6 * h * h * cfg.n_blocks              # msg MLPs
-        per_trip = 2 * nb * h * h * cfg.n_blocks         # bilinear einsum
-        fwd = E * per_edge + T * per_trip + N * 2 * h * h
-        return 3 * fwd / n_chips                         # train
-    return 0.0
-
-
-def analyse_cell(arch: str, cell: str, *, skip_multipod: bool = False,
-                 verbose: bool = True) -> dict:
-    cfg = get_config(arch)
-    L_full = _full_layers(cfg)
-    out = {"arch": arch, "cell": cell}
-
-    # 1. scanned compiles (the §Dry-run deliverable)
-    r1 = run_cell(arch, cell, multi_pod=False, verbose=False)
-    out["single_pod"] = r1
-    if not skip_multipod:
-        r2 = run_cell(arch, cell, multi_pod=True, verbose=False)
-        out["multi_pod"] = {k: v for k, v in r2.items()
-                            if k not in ("collectives",)}
-
-    # 2. unrolled cost extrapolation
-    if L_full > 4:
-        a = run_cell(arch, cell, unroll=True, layers_override=2,
-                     verbose=False)
-        b = run_cell(arch, cell, unroll=True, layers_override=4,
-                     verbose=False)
-        def extrap(key):
-            per_layer = (b[key] - a[key]) / 2.0
-            base = a[key] - 2.0 * per_layer
-            return max(base + L_full * per_layer, 0.0)
-        flops = extrap("flops")
-        byts = extrap("bytes_accessed")
-        coll = extrap("collective_bytes")
-        out["extrapolated"] = {"L": L_full, "flops": flops, "bytes": byts,
-                               "collective_bytes": coll,
-                               "L2": {k: a[k] for k in
-                                      ("flops", "bytes_accessed",
-                                       "collective_bytes")},
-                               "L4": {k: b[k] for k in
-                                      ("flops", "bytes_accessed",
-                                       "collective_bytes")}}
-    else:
-        c = run_cell(arch, cell, unroll=True, verbose=False)
-        flops, byts, coll = (c["flops"], c["bytes_accessed"],
-                             c["collective_bytes"])
-        out["extrapolated"] = {"L": L_full, "flops": flops, "bytes": byts,
-                               "collective_bytes": coll}
-
-    n_chips = r1["n_devices"]
-    terms = RooflineTerms(
-        arch=arch, cell=cell, mesh=r1["mesh"], flops=flops, hlo_bytes=byts,
-        collective_bytes=coll,
-        model_flops=_model_flops(arch, cell, n_chips))
-    out["terms"] = {
-        "compute_s": terms.compute_s, "memory_s": terms.memory_s,
-        "collective_s": terms.collective_s, "bottleneck": terms.bottleneck,
-        "model_flops": terms.model_flops,
-        "useful_flops_frac": terms.useful_flops_frac, "mfu": terms.mfu,
-        "step_time_s": terms.step_time_s,
-    }
-    if verbose:
-        print(terms.row(), flush=True)
-    return out
+from repro.roofline.analysis import HEADER
 
 
 def run_packed_rerank(args) -> int:
@@ -233,13 +72,9 @@ def run_plaid_probe(args) -> int:
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
-    ap.add_argument("--cell", default=None)
-    ap.add_argument("--skip-multipod", action="store_true")
-    ap.add_argument("--kernel", default=None,
+    ap.add_argument("--kernel", required=True,
                     choices=("packed_rerank", "plaid_probe"),
-                    help="analyse a hand-written kernel instead of the "
-                         "(arch x cell) dry-run grid")
+                    help="the kernel to price")
     ap.add_argument("--bits", default="2,4",
                     help="packed_rerank: codec widths to price")
     ap.add_argument("--rerank-shape", default=None,
@@ -249,32 +84,9 @@ def main(argv=None):
                          "c,ld,dim")
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
-
     if args.kernel == "packed_rerank":
         return run_packed_rerank(args)
-    if args.kernel == "plaid_probe":
-        return run_plaid_probe(args)
-
-    archs = [args.arch] if args.arch else ASSIGNED_ARCHS
-    print(HEADER, flush=True)
-    results, failures = [], []
-    for arch in archs:
-        for cell in ([args.cell] if args.cell else all_cells(arch)):
-            try:
-                results.append(analyse_cell(
-                    arch, cell, skip_multipod=args.skip_multipod))
-            except Exception as e:
-                traceback.print_exc()
-                failures.append({"arch": arch, "cell": cell,
-                                 "error": repr(e)})
-    print(f"\n{len(results)} cells analysed, {len(failures)} failed")
-    for f in failures:
-        print("FAILED:", f)
-    if args.json:
-        with open(args.json, "w") as fh:
-            json.dump({"results": results, "failures": failures}, fh,
-                      indent=1)
-    return 1 if failures else 0
+    return run_plaid_probe(args)
 
 
 if __name__ == "__main__":

@@ -1,14 +1,11 @@
 """Logical-axis sharding constraints.
 
-Model code annotates activations with *logical* axis names
-(``constrain(x, "batch", "seq", "heads", None)``); a ``MeshContext`` maps
-logical names to physical mesh axes. With no active context every
-annotation is a no-op, so the same model code runs single-device (tests,
-smoke) and multi-pod (dry-run, production) unchanged.
-
-Rules are per-strategy dictionaries: e.g. the LM "heads-TP" strategy maps
-``heads -> model``, the sequence-parallel fallback maps ``qseq -> model``
-instead (for archs whose head count does not divide the TP axis).
+Code annotates arrays with *logical* axis names
+(``constrain(x, "docs", None, None)``, ``logical_spec("docs")``); a
+``MeshContext`` maps logical names to physical mesh axes. With no active
+context every annotation is a no-op, so the same code runs on one device
+and on a serving mesh unchanged. ``serve_rules`` is the rule set of the
+scale-out serving mesh (``core/replicated.py``).
 """
 from __future__ import annotations
 
@@ -71,92 +68,6 @@ def constrain(x, *names: Optional[str]):
 # ---------------------------------------------------------------------------
 # Standard rule sets
 # ---------------------------------------------------------------------------
-def lm_rules(batch_axes: Axis = "data", model_axis: str = "model",
-             attn_shard: str = "heads") -> Dict[str, Axis]:
-    """Megatron-style TP + DP rules for LM transformers.
-
-    ``attn_shard="sequence"`` is the fallback for head counts that do not
-    divide the TP degree (e.g. qwen2.5-14b H=40 on tp=16): the query
-    sequence axis is model-sharded instead and KV is replicated across TP.
-    """
-    rules: Dict[str, Axis] = {
-        "batch": batch_axes,
-        "seq": None,
-        "dmodel": None,
-        "ff": model_axis,
-        "vocab": model_axis,
-        "experts": model_axis,
-        "kv": None,            # kv heads replicated across TP (kv < tp)
-        "dh": None,
-        "kvseq": None,
-        "qseq": None,
-        "heads": model_axis,
-        # prefill cache emission: the cache's seq axis CAN shard over TP
-        # (unlike attention's in-flight kv, which is head-sharded)
-        "cacheseq": model_axis,
-    }
-    if attn_shard == "sequence":
-        rules["heads"] = None
-        rules["qseq"] = model_axis
-    return rules
-
-
-def lm_decode_rules(batch_axes: Axis = "data",
-                    model_axis: str = "model") -> Dict[str, Axis]:
-    """Decode: flash-decoding style — KV cache sequence-sharded over TP,
-    queries (1 token) replicated; exact softmax combine via all-reduce."""
-    return {
-        "batch": batch_axes,
-        "seq": None,
-        "dmodel": None,
-        "ff": model_axis,
-        "vocab": model_axis,
-        "experts": model_axis,
-        "heads": None,
-        "kv": None,
-        "dh": None,
-        "kvseq": model_axis,
-        "qseq": None,
-    }
-
-
-def lm_long_decode_rules(batch_axes: Axis = "data",
-                         model_axis: str = "model") -> Dict[str, Axis]:
-    """long_500k (batch=1): the KV cache sequence axis is the ONLY big axis
-    — shard it over every mesh axis (data+model combined)."""
-    axes = ((batch_axes,) if isinstance(batch_axes, str)
-            else tuple(batch_axes)) + (model_axis,)
-    r = lm_decode_rules(batch_axes, model_axis)
-    r["kvseq"] = axes
-    r["batch"] = None
-    return r
-
-
-def gnn_rules(batch_axes: Axis = "data", model_axis: str = "model") -> Dict[str, Axis]:
-    """Node tables shard on data; edge/triplet tables (the big ones) shard
-    over data+model combined — DimeNet's triplet tensors dwarf everything."""
-    axes = ((batch_axes,) if isinstance(batch_axes, str)
-            else tuple(batch_axes)) + (model_axis,)
-    return {
-        "nodes": batch_axes,
-        "edges": axes,
-        "triplets": axes,
-        "batch": batch_axes,
-        "feat": None,
-        "hidden": None,
-    }
-
-
-def recsys_rules(batch_axes: Axis = "data", model_axis: str = "model") -> Dict[str, Axis]:
-    return {
-        "batch": batch_axes,
-        "vocab_rows": model_axis,   # embedding tables row-sharded over TP
-        "embed": None,
-        "feat": None,
-        "candidates": batch_axes,   # retrieval_cand: 1M candidates data-sharded
-    }
-
-
 def serve_rules(shard_axis: str = "shard",
                 replica_axis: str = "replica") -> Dict[str, Axis]:
     """Scale-out serving (launch/mesh.make_serve_mesh): the doc axis
@@ -172,27 +83,4 @@ def serve_rules(shard_axis: str = "shard",
         "dim": None,
         "centroids": None,
         "batch": replica_axis,
-    }
-
-
-def retrieval_rules(batch_axes: Axis = "data", model_axis: str = "model") -> Dict[str, Axis]:
-    axes = ((batch_axes,) if isinstance(batch_axes, str)
-            else tuple(batch_axes)) + (model_axis,)
-    return {
-        "docs": axes,               # doc shards over EVERY axis (§Perf cell 3)
-        "queries": None,            # queries replicated
-        "tokens": None,
-        "dim": None,
-        "batch": batch_axes,
-        "seq": None,
-        "heads": model_axis,
-        "ff": model_axis,
-        "vocab": model_axis,
-        "dmodel": None,
-        "kv": None,
-        "dh": None,
-        "experts": model_axis,
-        "qseq": None,
-        "kvseq": None,
-        "centroids": None,
     }

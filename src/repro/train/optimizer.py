@@ -1,19 +1,15 @@
 """Optimizers from scratch: AdamW + Adafactor, with LR schedules.
 
 Optimizer state is a pytree shaped like (or factored from) the param tree,
-so under pjit the state inherits the params' PartitionSpecs — ZeRO-style
-sharded optimizer state for free (DESIGN.md §5).
+so under pjit the state inherits the params' PartitionSpecs.
 
 Adafactor (Shazeer & Stern, 2018) keeps a FACTORED second moment — row and
-column accumulators instead of a full [m, n] slot — which is what makes the
-1T-param MoE config's optimizer state fit in HBM (see EXPERIMENTS.md
-§Dry-run memory accounting).
+column accumulators instead of a full [m, n] slot.
 """
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -177,20 +173,3 @@ def make_optimizer(name: str, lr, **kw) -> Optimizer:
     if name == "adafactor":
         return adafactor(lr, **kw)
     raise ValueError(name)
-
-
-def optimizer_state_bytes(params, name: str) -> int:
-    """Analytic optimizer-memory accounting (EXPERIMENTS.md §Dry-run)."""
-    import numpy as np
-    total = 0
-    for p in jax.tree_util.tree_leaves(params):
-        n = int(np.prod(p.shape))
-        if name == "adamw":
-            total += 2 * n * 4
-        else:  # adafactor factored
-            if p.ndim >= 2:
-                total += (int(np.prod(p.shape[:-1]))
-                          + int(np.prod(p.shape[:-2] + p.shape[-1:]))) * 4
-            else:
-                total += n * 4
-    return total

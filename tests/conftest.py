@@ -2,7 +2,7 @@ import numpy as np
 import pytest
 
 # NOTE: no XLA_FLAGS here on purpose — tests must see the 1 real CPU
-# device; only launch/dryrun.py forces 512 placeholder devices.
+# device.
 
 
 @pytest.fixture(scope="session")

@@ -5,7 +5,8 @@ described, not attached. Each test lowers one Pallas kernel at the
 widths the full-size ColBERTv2 path uses (dim 128, Lq 32, K 256, pooled
 docs of 136 tokens, Ward over N = 256, the bag probe over 32768 docs)
 with ``interpret=False`` and asserts the compiled program holds the
-kernel (``tpu_custom_call``).
+kernel (``tpu_custom_call``); the long-doc encoder's smoke model is
+compiled whole, through the masked kernel.
 Mosaic rejects what interpret mode accepts — block shapes off the
 (8, 128) tiling, unsupported reshapes, more VMEM than the scoped
 limit — so these keep the chip path compiling without chip time.
@@ -97,6 +98,31 @@ def test_masked_attention_compiles(one_chip, window):
         q, k, v, m, window=window)).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert f"f32[{B},{H},{S},{S}]" not in text
+
+
+def test_long_doc_encoder_compiles_with_its_scopes(one_chip, monkeypatch):
+    """``encode_docs`` of the ModernBERT smoke model, past its threshold,
+    takes the masked kernel on the chip, and its ops keep the scopes the
+    benchmark's attention metrics read."""
+    import dataclasses
+    import re
+    from repro.configs.moderncolbert import SMOKE
+    from repro.models import attention
+    from repro.models.colbert import encode_docs, init_colbert
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    # a config of its own name: encode_docs traces afresh
+    cfg = dataclasses.replace(SMOKE, name="smoke-tpu-compile")
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_colbert(jax.random.PRNGKey(0), cfg)))
+    toks = jax.ShapeDtypeStruct((2, cfg.doc_maxlen - 2), I32,
+                                sharding=one_chip)
+    text = encode_docs.lower(params, toks, cfg).compile().as_text()
+    assert "tpu_custom_call" in text
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("attention", "attention/global", "attention/local", "mlp"):
+        assert any(re.search(rf"encoder/(.*/)?{scope}/", n)
+                   for n in names), scope
 
 
 @pytest.mark.parametrize("scan", ["all_docs", "rerank"])
