@@ -36,7 +36,7 @@ from repro.core.docstore import DocStore, pad_candidate_sets
 from repro.core.hnsw import HNSW
 from repro.core.ivf import train_centroids
 from repro.core.maxsim import (maxsim_all_docs, maxsim_rerank_store,
-                               topk_with_pads)
+                               topk_dispatch)
 from repro.core.plaid import (PLAIDIndex, PROBE_KERNELS, build_plaid_index,
                               device_probe_plan, maxsim_packed_rerank_store,
                               plaid_candidates)
@@ -428,7 +428,7 @@ class MultiVectorIndex:
             cand = np.zeros((Nq, C), np.int64)   # doc 0: shape-only work
             mask = np.ones((Nq, C), bool)
             scores = self.rerank(qs, cand, mask)
-            topk_with_pads(scores, cand, k)
+            topk_dispatch(scores, cand, k).fetch()
         if (self.backend == "plaid" and self._plaid is not None
                 and not self._probe_plan(qs.shape[1])[0]):
             # host path only: the device pipeline is ONE executable per
@@ -445,7 +445,7 @@ class MultiVectorIndex:
             C = max(widths, default=32)
             scores = self._rerank_dense(qs, np.zeros((Nq, C), np.int64),
                                         np.ones((Nq, C), bool), None)
-            topk_with_pads(scores, None, k)
+            topk_dispatch(scores, None, k).fetch()
 
     def _warm_plaid_prune(self, qs: np.ndarray) -> None:
         """Trace plaid's PRE-prune stage-3 shapes for this batch shape.
@@ -481,16 +481,22 @@ class MultiVectorIndex:
 
     # ----------------------------------------------------------------- search
     def search_batch(self, qs: np.ndarray, k: int = 10,
-                     q_mask: Optional[np.ndarray] = None
-                     ) -> Tuple[np.ndarray, np.ndarray]:
-        """qs: [Nq, Lq, dim] -> (scores [Nq, k], ids [Nq, k]; -inf/-1 pads)."""
+                     q_mask: Optional[np.ndarray] = None, *,
+                     fetch: bool = True):
+        """qs: [Nq, Lq, dim] -> (scores [Nq, k], ids [Nq, k]; -inf/-1 pads).
+
+        ``fetch=False`` returns the result as dispatched (a
+        ``PendingTopK`` still in flight, or host arrays where no device
+        work is left): unpacking it as ``(scores, ids)`` waits for it
+        and gives what ``fetch=True`` returns."""
         qs = np.asarray(qs, np.float32)
         Nq = len(qs)
         if self.n_docs == 0:
             return (np.full((Nq, k), -np.inf, np.float32),
                     np.full((Nq, k), -1, np.int64))
         scores, cand = self.scored_candidates(qs, q_mask)
-        return topk_with_pads(scores, cand, k)
+        out = topk_dispatch(scores, cand, k)
+        return out.fetch() if fetch else out
 
     def search(self, q: np.ndarray, k: int = 10
                ) -> Tuple[np.ndarray, np.ndarray]:

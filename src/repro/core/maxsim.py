@@ -108,36 +108,64 @@ def maxsim_all_docs(q, q_mask, d, d_mask):
     return out[:, :Nd]
 
 
-def topk_with_pads(scores, cand, k: int):
-    """Shared top-k epilogue for every batched search API.
+class PendingTopK:
+    """A batch's top-k as dispatched: the [Nq, kk] scores and the ids
+    (or, for host candidates, the winning columns) may still be in
+    flight on the device.
 
-    scores: [Nq, C] (-inf marks invalid slots); cand: [Nq, C] doc ids or
-    None when scores are corpus-wide (ids = column index). Returns
-    (scores [Nq, k] f32, ids [Nq, k] i64) padded with -inf/-1.
+    ``fetch()`` makes the one device-to-host copy and the host epilogue
+    (host-candidate gather, -inf slots to id -1, pads to k); later calls
+    return the same arrays. Unpacking a pending result as ``(scores,
+    ids)`` fetches it, so a caller that unpacks ``search_batch``'s result
+    reads the same either way.
     """
-    import numpy as np
-    with obs.span(obs.PLAID_TOPK) as sp:
-        kk = min(k, scores.shape[1])
-        top_s, top_i = jax.lax.top_k(scores, kk)
-        if isinstance(cand, jax.Array):
-            # device candidates: gather the winning ids on device so the
-            # ONLY host transfer after encode is this [Nq, k] result
-            top_s = np.asarray(top_s)
-            ids = np.asarray(jnp.take_along_axis(cand, top_i, axis=1))
-            sp.set_metadata(d2h_bytes=top_s.nbytes + ids.nbytes)
-            ids = ids.astype(np.int64)
-        else:
-            top_s, top_i = np.asarray(top_s), np.asarray(top_i)
-            sp.set_metadata(d2h_bytes=top_s.nbytes + top_i.nbytes)
-            ids = (top_i.astype(np.int64) if cand is None
-                   else np.take_along_axis(np.asarray(cand, np.int64),
-                                           top_i, axis=1))
-    ids = np.where(np.isfinite(top_s), ids, -1)
-    if kk < k:
-        top_s = np.pad(top_s, ((0, 0), (0, k - kk)),
-                       constant_values=-np.inf)
-        ids = np.pad(ids, ((0, 0), (0, k - kk)), constant_values=-1)
-    return top_s.astype(np.float32), ids.astype(np.int64)
+
+    def __init__(self, top_s, ids, cand, k: int):
+        self._dev = (top_s, ids)
+        self._cand = cand               # host ids to gather from, or None
+        self._k = k
+        self._out = None
+
+    def fetch(self):
+        import numpy as np
+        if self._out is None:
+            with obs.span(obs.PLAID_TOPK) as sp:
+                top_s, ids = jax.device_get(self._dev)
+                sp.set_metadata(d2h_bytes=top_s.nbytes + ids.nbytes)
+                ids = (ids.astype(np.int64) if self._cand is None
+                       else np.take_along_axis(
+                           np.asarray(self._cand, np.int64), ids, axis=1))
+            self._dev = None            # let the device buffers go
+            ids = np.where(np.isfinite(top_s), ids, -1)
+            pad = self._k - top_s.shape[1]
+            if pad > 0:
+                top_s = np.pad(top_s, ((0, 0), (0, pad)),
+                               constant_values=-np.inf)
+                ids = np.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
+            self._out = top_s.astype(np.float32), ids.astype(np.int64)
+        return self._out
+
+    def __iter__(self):
+        return iter(self.fetch())
+
+
+def topk_dispatch(scores, cand, k: int) -> PendingTopK:
+    """The shared top-k epilogue of every batched search API, device
+    half: dispatches the top-k and, for device candidates, the gather of
+    the winning ids, and waits for neither.
+
+    scores: [Nq, C] (-inf marks invalid slots); cand: [Nq, C] doc ids
+    (device or host) or None when scores are corpus-wide (ids = column
+    index). ``fetch()`` on the result gives (scores [Nq, k] f32, ids
+    [Nq, k] i64) padded with -inf/-1.
+    """
+    top_s, top_i = jax.lax.top_k(scores, min(k, scores.shape[1]))
+    if isinstance(cand, jax.Array):
+        # device candidates: gather the winning ids on device so the
+        # ONLY host transfer after encode is this [Nq, k] result
+        return PendingTopK(top_s, jnp.take_along_axis(cand, top_i, axis=1),
+                           None, k)
+    return PendingTopK(top_s, top_i, cand, k)
 
 
 def topk_shard(scores, cand, k: int, base: int = 0):
@@ -150,7 +178,7 @@ def topk_shard(scores, cand, k: int, base: int = 0):
     GLOBAL ids [Nq, kk] i32) with kk = min(k, C), both device-resident
     on ``scores``' device. Ids are ``cand``-gathered (or the column
     index) shifted by ``base``; slots whose score is -inf carry a
-    meaningless id — the final merge epilogue (``topk_with_pads``) maps
+    meaningless id — the final merge epilogue (``topk_dispatch``) maps
     non-finite slots to -1, exactly as the monolithic path does.
 
     Keeping per-shard top-k is lossless for a global top-k: any shard

@@ -59,7 +59,7 @@ from repro.core.docstore import (DocStore, pad_candidate_sets,
 from repro.core.ivf import (DeviceInvertedLists, InvertedLists,
                             build_device_inverted_lists,
                             build_inverted_lists)
-from repro.core.maxsim import _on_tpu, maxsim_rerank, topk_with_pads
+from repro.core.maxsim import _on_tpu, maxsim_rerank, topk_dispatch
 from repro.core.quantization import ResidualCodec, decode, encode
 from repro.kernels.plaid_probe.ref import fold_sum
 
@@ -708,7 +708,7 @@ def plaid_search_batch(index: PLAIDIndex, qs: np.ndarray, k: int = 10,
                 np.full((Nq, k), -1, np.int64))
     qm = jnp.ones(qs.shape[:2], bool)
     scores = maxsim_packed_rerank_store(index, qs, qm, cand, cmask)
-    return topk_with_pads(scores, cand, k)
+    return topk_dispatch(scores, cand, k).fetch()
 
 
 def plaid_search(index: PLAIDIndex, q: np.ndarray, k: int = 10,

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.index import MultiVectorIndex
-from repro.core.maxsim import maxsim_all_docs, topk_with_pads
+from repro.core.maxsim import PendingTopK, maxsim_all_docs, topk_dispatch
 from repro.core.sharded import ShardedIndex
 from repro.launch.mesh import (distinct_row, make_shard_mesh,
                                serve_device_table)
@@ -68,7 +68,7 @@ class _FlatPlan:
     ``serve_rules`` logical specs. The program computes each shard's
     dense scores + local top-k, shifts to global ids, and merges with a
     tiled ``all_gather`` (axis order = shard order); the host epilogue
-    (``topk_with_pads``) reduces the replicated [Nq, S*kk] block to the
+    (``topk_dispatch``) reduces the replicated [Nq, S*kk] block to the
     final [Nq, k] — identical math to the dispatch merge, one XLA
     dispatch instead of S.
     """
@@ -133,18 +133,18 @@ class _FlatPlan:
         self._fns[kk] = fn
         return fn
 
-    def search(self, qs: np.ndarray, q_mask, k: int
-               ) -> Tuple[np.ndarray, np.ndarray]:
+    def search(self, qs: np.ndarray, q_mask, k: int) -> PendingTopK:
         qs = jnp.asarray(np.asarray(qs, np.float32))
         qm = (jnp.ones(qs.shape[:2], bool) if q_mask is None
               else jnp.asarray(np.asarray(q_mask, bool)))
         kk = min(k, self.n_docs_padded)
         ts, gi = self._fn(kk)(self.d, self.m, self.live, self.base,
                               qs, qm)
-        # outputs are mesh-replicated; pull one copy to the merge device
-        # for the (single-device) final top-k epilogue
+        # outputs are mesh-replicated; pull one copy of each to the
+        # merge device for the (single-device) final top-k epilogue
         ts = jax.device_put(ts, self.merge_device)
-        return topk_with_pads(ts, np.asarray(gi), k)
+        gi = jax.device_put(gi, self.merge_device)
+        return topk_dispatch(ts, gi, k)
 
 
 class ReplicatedIndex:
@@ -344,24 +344,28 @@ class ReplicatedIndex:
 
     # ---------------------------------------------------------------- search
     def search_batch_on(self, replica: int, qs: np.ndarray, k: int = 10,
-                        q_mask: Optional[np.ndarray] = None
-                        ) -> Tuple[np.ndarray, np.ndarray]:
+                        q_mask: Optional[np.ndarray] = None, *,
+                        fetch: bool = True):
         """One replica lane's search — bitwise identical for every lane;
-        the router picks ``replica`` for throughput, not results."""
+        the router picks ``replica`` for throughput, not results.
+        ``fetch=False`` returns the result as dispatched, as
+        ``MultiVectorIndex.search_batch`` has it."""
         r = int(replica) % self.n_replicas
         plan = self._plan_for(r)
         if plan is not None:
-            return plan.search(qs, q_mask, k)
-        inner = self._inners[r]
-        with self._ctx(r):
-            return inner.search_batch(qs, k=k, q_mask=q_mask)
+            out = plan.search(qs, q_mask, k)
+        else:
+            with self._ctx(r):
+                out = self._inners[r].search_batch(qs, k=k, q_mask=q_mask,
+                                                   fetch=False)
+        return tuple(out) if fetch else out
 
     def search_batch(self, qs: np.ndarray, k: int = 10,
-                     q_mask: Optional[np.ndarray] = None
-                     ) -> Tuple[np.ndarray, np.ndarray]:
+                     q_mask: Optional[np.ndarray] = None, *,
+                     fetch: bool = True):
         """Parity surface: identical to the wrapped index's
         ``search_batch`` (routes through lane 0)."""
-        return self.search_batch_on(0, qs, k=k, q_mask=q_mask)
+        return self.search_batch_on(0, qs, k=k, q_mask=q_mask, fetch=fetch)
 
     def search(self, q: np.ndarray, k: int = 10
                ) -> Tuple[np.ndarray, np.ndarray]:
@@ -379,7 +383,7 @@ class ReplicatedIndex:
         for r in range(self.n_replicas):
             plan = self._plan_for(r)
             if plan is not None:
-                plan.search(qs, None, k)
+                plan.search(qs, None, k).fetch()
                 continue
             inner = self._inners[r]
             if id(inner) in warmed:

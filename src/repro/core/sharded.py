@@ -64,7 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.index import BACKENDS, PARAM_KEYS, MultiVectorIndex
-from repro.core.maxsim import topk_shard, topk_with_pads
+from repro.core.maxsim import topk_dispatch, topk_shard
 
 # shard construction knobs forwarded verbatim to MultiVectorIndex — the
 # same set the persistence manifest records (one definition for all
@@ -370,6 +370,15 @@ class ShardedIndex:
         each get their own list (the thread-safety contract
         ``last_probe_s`` alone could not provide).
         """
+        out, probe_s = self._search_dispatch(qs, k, q_mask)
+        S, I = out
+        return S, I, probe_s
+
+    def _search_dispatch(self, qs: np.ndarray, k: int,
+                         q_mask: Optional[np.ndarray]):
+        """The fan-out and the device merge, not fetched: (the result as
+        ``search_batch(fetch=False)`` returns it, per-shard probe
+        seconds)."""
         qs = np.asarray(qs, np.float32)
         Nq = len(qs)
         live = [(base, shard, self._shard_device(i))
@@ -389,7 +398,7 @@ class ShardedIndex:
             probe_s.append(0.0 if shard.n_docs == 0 else next(it)[2])
         if not parts:
             return (np.full((Nq, k), -np.inf, np.float32),
-                    np.full((Nq, k), -1, np.int64), probe_s)
+                    np.full((Nq, k), -1, np.int64)), probe_s
         if len(parts) == 1:
             top_s, top_i = parts[0][0], parts[0][1]
         else:
@@ -400,22 +409,22 @@ class ShardedIndex:
                   for p in parts]
             top_s = jnp.concatenate(ss, axis=1)
             top_i = jnp.concatenate(ii, axis=1)
-        S, I = topk_with_pads(top_s, top_i, k)
-        return S, I, probe_s
+        return topk_dispatch(top_s, top_i, k), probe_s
 
     def search_batch(self, qs: np.ndarray, k: int = 10,
-                     q_mask: Optional[np.ndarray] = None
-                     ) -> Tuple[np.ndarray, np.ndarray]:
+                     q_mask: Optional[np.ndarray] = None, *,
+                     fetch: bool = True):
         """qs [Nq, Lq, dim] -> (scores [Nq, k], ids [Nq, k]; -inf/-1 pads).
 
         See ``search_batch_with_stats`` for the fan-out/merge contract;
         this drops the probe stats, keeping only the ``last_probe_s``
         snapshot (one atomic assignment — safe to read, but concurrent
         callers needing *their* timings should use the stats variant).
+        ``fetch=False`` returns the merged result as dispatched, as
+        ``MultiVectorIndex.search_batch`` has it.
         """
-        S, I, probe_s = self.search_batch_with_stats(qs, k=k, q_mask=q_mask)
-        self.last_probe_s = probe_s
-        return S, I
+        out, self.last_probe_s = self._search_dispatch(qs, k, q_mask)
+        return tuple(out) if fetch else out
 
     def search(self, q: np.ndarray, k: int = 10
                ) -> Tuple[np.ndarray, np.ndarray]:

@@ -20,6 +20,12 @@ ColBERTv2/PLAID-class systems actually deploy:
   * The two pipeline stages overlap: the batcher thread encodes batch
     N+1 while the search thread reranks batch N (encode is host+device
     bound, rerank device bound — the classic two-stage pipeline).
+  * A search lane dispatches ahead: with batch N's device work in
+    flight and batch N+1 already staged, it dispatches N+1 before it
+    waits on N's [Nq, k] result (``search_batch(..., fetch=False)``),
+    so the device never idles through the host's copy-out and
+    bookkeeping between batches. With nothing staged it waits on N at
+    once, so light-load latency is unchanged.
   * Scale-out: with ``n_replicas > 1`` the engine wraps the served
     index in replica groups (core/replicated.py) and runs one search
     lane per group — each staged microbatch routes whole to a lane
@@ -282,6 +288,8 @@ class EngineStats:
         self.swaps = 0
         self.generations_seen: deque = deque(maxlen=self.WINDOW)
         self.replica_batches: dict = {}     # lane id -> batches served
+        # batches a lane dispatched while it still held an unfetched one
+        self.dispatched_ahead = 0
 
     def record_batch(self, n_real: int, bucket: int, reason: str,
                      waits: List[float], generation: int,
@@ -305,6 +313,10 @@ class EngineStats:
     def record_swap(self) -> None:
         with self._lock:
             self.swaps += 1
+
+    def record_ahead(self) -> None:
+        with self._lock:
+            self.dispatched_ahead += 1
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -333,6 +345,7 @@ class EngineStats:
                 "swaps": self.swaps,
                 "generations_seen": list(self.generations_seen),
                 "replica_batches": dict(self.replica_batches),
+                "dispatched_ahead": self.dispatched_ahead,
             }
 
 
@@ -425,6 +438,13 @@ class ServingEngine:
             # least one batch per lane or lanes starve behind admission
             self._staged_cap = max(self._staged_cap, self.n_replicas)
         self._inline = self._staged_cap == 1
+        # batches lanes hold dispatched beyond their first (under
+        # _staged_cond): each takes a staged slot, so dispatching ahead
+        # never lets more batches be popped than the pipeline depth
+        # allows; _idle_lanes counts lanes blocked waiting for a batch,
+        # which a staged batch goes to before any lane takes it ahead
+        self._ahead = 0
+        self._idle_lanes = 0
         self._stop = False
         self._abandon = False
         self._pending = 0       # batches popped but not yet resolved
@@ -718,8 +738,8 @@ class ServingEngine:
             # thread, so the room observed here cannot be stolen.)
             with self._staged_cond:
                 if not self._staged_cond.wait_for(
-                        lambda: len(self._staged) < self._staged_cap
-                        or self._stop, timeout=0.1):
+                        lambda: len(self._staged) + self._ahead
+                        < self._staged_cap or self._stop, timeout=0.1):
                     continue
                 if self._stop and not self._queue:
                     return
@@ -772,33 +792,57 @@ class ServingEngine:
     # -------------------------------------------------------------- search
     def _serve_staged(self, staged, replica: int = 0) -> None:
         """Run stage 2 for one encoded microbatch and resolve its
-        futures (called from a search lane thread, or inline from the
-        batcher at pipeline depth 1). ``replica`` picks the lane a
-        routed index serves this batch on — every lane is bitwise
-        identical, so routing is purely a throughput decision."""
+        futures: the inline path at pipeline depth 1."""
+        held = self._dispatch(staged, replica, ahead=False)
+        if held is not None:
+            self._resolve(held)
+
+    def _dispatch(self, staged, replica: int, ahead: bool):
+        """Dispatch stage 2 for one encoded microbatch on lane
+        ``replica`` without waiting for its result; returns what
+        ``_resolve`` needs, or None once a failed dispatch has failed
+        the batch's futures. ``ahead``: the lane still holds an
+        unfetched batch. The index handle stays acquired until the
+        batch is fetched, so a swap cannot retire the generation a
+        batch in flight reads. Every lane is bitwise identical, so
+        routing is purely a throughput decision."""
         enc, n, kk, batch, reason, waits, seq, t_staged = staged
         # from staging to this lane taking the batch (inline: ~0)
         staged_wait = time.perf_counter() - t_staged
-        try:
-            with obs.span(obs.ENGINE_SEARCH, batch=seq, replica=replica,
-                          staged_wait_us=int(staged_wait * 1e6)):
-                with self._handle_lock:
-                    handle = self._handle
-                    index = handle.acquire()
-                try:
-                    search_on = getattr(index, "search_batch_on", None)
-                    if search_on is not None:
-                        S, I = search_on(replica, enc, k=kk)
-                    else:
-                        S, I = index.search_batch(enc, k=kk)
-                except BaseException as e:      # noqa: BLE001
-                    for sl in batch:
-                        sl.future._fail(e)
-                    self.stats.record_failed(sum(sl.n for sl in batch))
-                    return
-                finally:
-                    handle.release()
-            with obs.span(obs.ENGINE_RESOLVE, batch=seq):
+        with obs.span(obs.ENGINE_SEARCH, batch=seq, replica=replica,
+                      staged_wait_us=int(staged_wait * 1e6),
+                      ahead=int(ahead)):
+            with self._handle_lock:
+                handle = self._handle
+                index = handle.acquire()
+            try:
+                search_on = getattr(index, "search_batch_on", None)
+                if search_on is not None:
+                    result = search_on(replica, enc, k=kk, fetch=False)
+                else:
+                    result = index.search_batch(enc, k=kk, fetch=False)
+            except BaseException as e:      # noqa: BLE001
+                handle.release()
+                self._fail_batch(batch, e)
+                return None
+        if ahead:
+            self.stats.record_ahead()
+        return result, handle, staged, replica, staged_wait
+
+    def _resolve(self, held) -> None:
+        """Wait for a dispatched batch's result, release its index
+        handle and resolve its futures."""
+        result, handle, staged, replica, staged_wait = held
+        enc, n, kk, batch, reason, waits, seq, _ = staged
+        with obs.span(obs.ENGINE_RESOLVE, batch=seq):
+            try:
+                S, I = result       # a dispatched result fetches here
+            except BaseException as e:      # noqa: BLE001
+                self._fail_batch(batch, e)
+                return
+            finally:
+                handle.release()
+            try:
                 S, I = np.asarray(S)[:n], np.asarray(I)[:n]
                 lo = 0
                 for sl in batch:
@@ -808,22 +852,51 @@ class ServingEngine:
                 self.stats.record_batch(n, len(enc), reason, waits,
                                         handle.generation, replica=replica,
                                         staged_wait=staged_wait)
-        finally:
-            self._batch_done()
+            finally:
+                self._batch_done()
+
+    def _fail_batch(self, batch, err: BaseException) -> None:
+        for sl in batch:
+            sl.future._fail(err)
+        self.stats.record_failed(sum(sl.n for sl in batch))
+        self._batch_done()
 
     def _search_loop(self, replica: int = 0) -> None:
+        """One search lane. Holding a dispatched batch, it takes the
+        next staged batch (unless an idle lane is there to take it),
+        dispatches that first, then resolves the one it held; with
+        nothing staged it resolves the held batch at once. So it never
+        blocks for work while it holds a batch, and a drain finds every
+        batch resolved."""
+        held = None
         while True:
             with self._staged_cond:
-                if not self._staged_cond.wait_for(
-                        lambda: self._staged or self._stop, timeout=0.1):
-                    continue
-                if not self._staged:
-                    if self._stop:
-                        return
-                    continue
-                staged = self._staged.popleft()
-                self._staged_cond.notify_all()
-            self._serve_staged(staged, replica=replica)
+                if held is None:
+                    self._idle_lanes += 1
+                    self._staged_cond.wait_for(
+                        lambda: self._staged or self._stop, timeout=0.1)
+                    self._idle_lanes -= 1
+                    if not self._staged:
+                        if self._stop:
+                            return
+                        continue
+                    staged = self._staged.popleft()
+                elif len(self._staged) > self._idle_lanes:
+                    staged = self._staged.popleft()
+                    self._ahead += 1        # holds its slot until resolved
+                else:
+                    staged = None
+                if staged is not None:
+                    self._staged_cond.notify_all()
+            nxt = (None if staged is None else
+                   self._dispatch(staged, replica, ahead=held is not None))
+            if held is not None:
+                self._resolve(held)
+                if staged is not None:
+                    with self._staged_cond:
+                        self._ahead -= 1
+                        self._staged_cond.notify_all()
+            held = nxt
 
 
 # ---------------------------------------------------------------------------

@@ -31,14 +31,17 @@ INDEXER_SHARD = "repro.indexer.shard"            # shard, docs, vectors
 PLAID_ADD = "repro.plaid.add"                    # h2d_bytes, d2h_bytes
 INDEXER_SHARD_SAVE = "repro.indexer.shard_save"  # shard
 INDEXER_SHARD_REOPEN = "repro.indexer.shard_reopen"  # shard
-# serving engine (launch/engine.py): batcher thread, then a search lane
+# serving engine (launch/engine.py): batcher thread, then a search lane;
+# search spans a batch's dispatch (ahead: 1 when the lane still held an
+# unfetched batch), resolve the wait for its result and its futures
 ENGINE_ENCODE = "repro.engine.encode"            # batch, n, bucket, reason
 ENGINE_SEARCH = "repro.engine.search"            # batch, replica,
-#                                                  staged_wait_us
+#                                                  staged_wait_us, ahead
 ENGINE_RESOLVE = "repro.engine.resolve"          # batch
 # query encoder (retrieval/searcher.py)
 ENCODER_QUERIES = "repro.encoder.queries"        # h2d_bytes, d2h_bytes
-# PLAID search (core/plaid.py, core/maxsim.py)
+# PLAID search (core/plaid.py, core/maxsim.py); topk is the fetch of
+# the [Nq, k] result, under the engine's resolve span
 PLAID_CANDIDATES = "repro.plaid.candidates"      # path, fallback,
 #                                                  h2d_bytes, d2h_bytes
 PLAID_RERANK = "repro.plaid.rerank"              # h2d_bytes

@@ -32,7 +32,7 @@ import numpy as np
 
 from repro.core.docstore import DocStore
 from repro.core.maxsim import (maxsim_all_docs, maxsim_rerank,
-                               topk_with_pads)
+                               topk_dispatch)
 
 
 @dataclass
@@ -101,9 +101,12 @@ class CascadeIndex:
             return
         self.search_batch(np.asarray(qs, np.float32), k=k)
 
-    def search_batch(self, qs: np.ndarray, k: int = 10
-                     ) -> Tuple[np.ndarray, np.ndarray]:
-        """qs [Nq, Lq, dim] -> (scores [Nq, k], ids [Nq, k]; -inf/-1 pads)."""
+    def search_batch(self, qs: np.ndarray, k: int = 10, *,
+                     fetch: bool = True):
+        """qs [Nq, Lq, dim] -> (scores [Nq, k], ids [Nq, k]; -inf/-1 pads).
+        ``fetch=False``: the result as dispatched, as
+        ``MultiVectorIndex.search_batch`` has it (stage 1's candidate
+        ids still come to the host for the stage-2 gather)."""
         qs = jnp.asarray(np.asarray(qs, np.float32))
         Nq = qs.shape[0]
         n = self._coarse.n_docs
@@ -120,7 +123,8 @@ class CascadeIndex:
         # stage 2: gathered exact rerank over the fine vectors
         fd, fm = self._fine.gather(cand)
         s2 = maxsim_rerank(qs, qm, fd, fm)                 # [Nq, C]
-        return topk_with_pads(s2, cand, k)
+        out = topk_dispatch(s2, cand, k)
+        return out.fetch() if fetch else out
 
     def search(self, q: np.ndarray, k: int = 10
                ) -> Tuple[np.ndarray, np.ndarray]:

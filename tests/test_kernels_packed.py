@@ -116,7 +116,7 @@ def test_packed_kernel_single_candidate():
 
 def test_packed_store_empty_candidate_rows():
     """A query whose candidate mask is all-False comes back -inf across
-    the row (the store-level contract topk_with_pads turns into -1 ids)."""
+    the row (the store-level contract the top-k fetch turns into -1 ids)."""
     from repro.core.index import MultiVectorIndex
     from repro.core.plaid import maxsim_packed_rerank_store
     rng = np.random.default_rng(0)

@@ -38,7 +38,7 @@ BUILD_ARGS = {
 SERVE_ARGS = {
     obs.ENGINE_ENCODE: {"batch", "n", "bucket", "reason"},
     obs.ENCODER_QUERIES: {"h2d_bytes", "d2h_bytes"},
-    obs.ENGINE_SEARCH: {"batch", "replica", "staged_wait_us"},
+    obs.ENGINE_SEARCH: {"batch", "replica", "staged_wait_us", "ahead"},
     obs.PLAID_CANDIDATES: {"path", "h2d_bytes", "d2h_bytes"},
     obs.PLAID_RERANK: {"h2d_bytes"},
     obs.PLAID_TOPK: {"d2h_bytes"},
@@ -252,7 +252,10 @@ def test_serve_spans_of_a_batch_share_its_number(served):
     assert len(set(nums)) == len(SIZES)
     for name in (obs.ENGINE_SEARCH, obs.ENGINE_RESOLVE):
         assert [a["batch"] for a in named(spans, name)] == nums, name
+    # each request waits for its answer: nothing is staged behind a
+    # batch, so none is dispatched ahead
     assert all(a["staged_wait_us"] >= 0 and a["replica"] == 0
+               and a["ahead"] == 0
                for a in named(spans, obs.ENGINE_SEARCH))
 
 
